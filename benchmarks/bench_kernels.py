@@ -30,15 +30,18 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common
-from repro.configs.base import HBM_BW, PEAK_FLOPS_BF16, FedConfig
+from repro.configs.base import FedConfig
 from repro.core import aggregation
 from repro.kernels import population_select
+from repro.launch.roofline import peaks
 from repro.kernels.flash_attention_ops import flash_attention
 from repro.kernels.robust_agg_ops import robust_aggregate_tree
 from repro.kernels.robust_pipeline import (fused_aggregate_tree,
                                            fused_aggregate_tree_flat)
 
 BENCH_JSON = os.environ.get("BENCH_KERNELS_JSON", "BENCH_kernels.json")
+# the modeled t_*_us terms are TPU v5e roofline bounds, not measurements
+V5E = peaks("TPU v5 lite")
 
 
 def _time(fn, *args, reps=5, warmup=1, **kw):
@@ -64,8 +67,8 @@ def flash_roofline(B, S, Hq, dh, window, blk=128):
     return {
         "flops": flops, "bytes": byts,
         "intensity": flops / byts,
-        "t_compute_us": 1e6 * flops / PEAK_FLOPS_BF16,
-        "t_memory_us": 1e6 * byts / HBM_BW,
+        "t_compute_us": 1e6 * flops / V5E["flops_bf16"],
+        "t_memory_us": 1e6 * byts / V5E["hbm_bw"],
         "vmem_tile_kb": (3 * blk * dh * 2 + blk * dh * 4) / 1024,
     }
 
@@ -100,7 +103,8 @@ def robust_pipeline_roofline(C, N, aggregator):
         "bytes_fused": fused[aggregator] * bytes_per_pass,
         # rank network: C^2 compares + C picks per coordinate, 2 sweeps
         "flops_fused": 2.0 * C * C * N + 4.0 * C * N,
-        "t_memory_fused_us": 1e6 * fused[aggregator] * bytes_per_pass / HBM_BW,
+        "t_memory_fused_us": 1e6 * fused[aggregator] * bytes_per_pass
+        / V5E["hbm_bw"],
     }
 
 

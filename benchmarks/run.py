@@ -11,6 +11,8 @@ import inspect
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHES = [
     ("table3_mnist", "benchmarks.bench_table3_mnist"),
     ("table5_xray", "benchmarks.bench_table5_xray"),
@@ -32,6 +34,7 @@ def main() -> None:
     ap.add_argument("--budget", default="small", choices=["small", "full"])
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     failed = []
     for name, module in BENCHES:

@@ -167,7 +167,7 @@ _KEY_DERIVE = {"random_split", "random_fold_in", "random_seed",
 # ops that spend a key's entropy: sampling from the same class twice
 # yields correlated streams
 _KEY_CONSUME = {"random_bits"}
-_CALL_LIKE = {"pjit", "closed_call", "core_call", "remat", "checkpoint",
+_CALL_LIKE = {"jit", "pjit", "closed_call", "core_call", "remat", "checkpoint",
               "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
               "scan", "while", "cond", "shard_map"}
 
@@ -408,9 +408,16 @@ def dtype_discipline(ctx: RuleContext) -> None:
 # 5. Pallas budget                                                      #
 # --------------------------------------------------------------------- #
 
+def _block_dims(bm) -> tuple:
+    """A BlockMapping's block shape as ints: a dim is an int or a
+    ``Blocked(block_size=...)``; a squeezed dim counts as 1."""
+    return tuple(d if isinstance(d, int)
+                 else int(getattr(d, "block_size", None) or 1)
+                 for d in getattr(bm, "block_shape", ()) or ())
+
+
 def _block_bytes(bm) -> int:
-    shape = tuple(d if isinstance(d, int) else 1
-                  for d in getattr(bm, "block_shape", ()) or ())
+    shape = _block_dims(bm)
     aval = getattr(bm, "block_aval", None)
     itemsize = 4
     for attr in ("dtype", "inner_aval"):
@@ -440,13 +447,13 @@ def pallas_budget(ctx: RuleContext) -> None:
             continue
         bms = list(getattr(gm, "block_mappings", ()) or ())
         working = 2 * sum(_block_bytes(bm) for bm in bms)
-        name = str(eqn.params.get("name_and_src_info", "pallas_call"))
+        name = str(eqn.params.get("name")
+                   or eqn.params.get("name_and_src_info", "pallas_call"))
         name = name.split(" ")[0]
         grid = tuple(getattr(gm, "grid", ()) or ())
         lane_minor = []
         for bm in bms:
-            shape = tuple(d if isinstance(d, int) else 1
-                          for d in getattr(bm, "block_shape", ()) or ())
+            shape = _block_dims(bm)
             if shape and 1 < shape[-1] < LANE:
                 lane_minor.append(shape)
         ctx.note(

@@ -12,7 +12,7 @@ from __future__ import annotations
 from typing import Iterator, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def subjaxprs_of(value) -> Iterator:
@@ -49,7 +49,7 @@ def eqn_provenance(eqn) -> str:
     jaxpr's recorded source_info; '?' when tracing stripped it."""
     try:
         from jax._src import source_info_util  # noqa: PLC0415
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
             return "?"
         return (f"{frame.file_name.rsplit('/', 1)[-1]}:{frame.start_line} "

@@ -8,8 +8,9 @@ after the block DMA:
 
   pass 1   streams int8 (C, blk) code blocks + (C, blk/qblk) scale
            blocks; dequantizes in VMEM (one multiply against the
-           broadcast scales) and feeds the SAME median-reference /
-           cosine-partial accumulation as the dense engine.
+           scales widened to (C, blk)) and feeds the SAME
+           median-reference / cosine-partial accumulation as the dense
+           engine.
   pass 2   same dequant load, same gated combine; per-leaf outputs in
            the caller's dtypes.
   krum     same dequant load into the blocked Gram accumulation.
@@ -27,6 +28,12 @@ Layout contract: every per-leaf streaming block ``seg.blk`` is a
 multiple of 128 (``make_segments``), so any ``qblk`` dividing 128 (or
 equal to it) tiles the block exactly; ``fusable`` checks the general
 condition and callers fall back to decode-then-aggregate when it fails.
+The wire's (C, nq) scale rows are relaid per leaf to (nblocks, C,
+blk/qblk) before the passes (``_scale_blocks``, 1/32 of the code bytes),
+so one grid step's scales are a whole trailing tile — a (C, blk/qblk)
+window of the (C, nq) row is not a block shape the TPU accepts.  In
+VMEM the (C, blk/qblk) scales widen to (C, blk) through a 0/1 expansion
+matmul at HIGHEST precision, which reproduces every scale exactly.
 Under ``shard_map`` (``fused_dequant_aggregate_sharded``) the flattened
 code axis shards over the mesh with its scale columns riding along
 (alignment guaranteed by the ``align=qblk`` leg of
@@ -44,19 +51,55 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.comm import codecs
 from repro.kernels import robust_pipeline as rp
+from repro.kernels.backend import resolve_interpret
+
+
+def _scale_blocks(s, seg, qblk):
+    """(G, C, nq) wire scales -> (G, nblocks, C, blk/qblk): grid step
+    ``j`` of the leaf reads the whole trailing (C, blk/qblk) tile
+    ``[:, j]``.  The padded columns sit past the leaf's last code and
+    only ever scale masked lanes."""
+    G, C, nq = s.shape
+    sb = seg.blk // qblk
+    s = jnp.pad(s, ((0, 0), (0, 0), (0, seg.nblocks * sb - nq)),
+                constant_values=1.0)
+    return s.reshape(G, C, seg.nblocks, sb).transpose(0, 2, 1, 3)
+
+
+def _scale_index_map(seg):
+    """Scale-tile twin of ``robust_pipeline._seg_index_map``."""
+    return lambda g, i, *_: (g, jnp.clip(i - seg.start, 0,
+                                         seg.nblocks - 1), 0, 0)
+
+
+def _scale_specs(segs, c, qblk):
+    return [pl.BlockSpec((1, 1, c, seg.blk // qblk), _scale_index_map(seg))
+            for seg in segs]
+
+
+def _widen(s, blk, qblk):
+    """(C, blk/qblk) scales -> (C, blk), each scale repeated over its
+    qblk lanes, by a matmul against the 0/1 block-membership matrix.
+    One nonzero term per output at HIGHEST precision: exact."""
+    sb = s.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (sb, blk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (sb, blk), 1)
+    member = ((lane >= row * qblk) & (lane < (row + 1) * qblk)).astype(
+        jnp.float32)
+    return jax.lax.dot_general(s, member, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _dq_block(q_refs, s_refs, l, seg, i, qblk):
     """Load leaf ``l``'s current int8 (C, blk) code block and its
-    (C, blk/qblk) scale block, dequantize in VMEM, and mask the ragged
+    (C, blk/qblk) scale tile, dequantize in VMEM, and mask the ragged
     tail (same contract as ``robust_pipeline._leaf_block``).  The
     multiply is the exact op ``codecs.quant_decode`` performs, so the
     fused path is bit-identical to decode-then-aggregate."""
     q = q_refs[l][0].astype(jnp.float32)                 # (C, blk)
-    s = s_refs[l][0].astype(jnp.float32)                 # (C, blk/qblk)
-    c = q.shape[0]
-    sb = seg.blk // qblk
-    x = (q.reshape(c, sb, qblk) * s[:, :, None]).reshape(c, seg.blk)
+    s = s_refs[l][0, 0].astype(jnp.float32)              # (C, blk/qblk)
+    x = q * _widen(s, seg.blk, qblk)
     if seg.n % seg.blk:
         valid = seg.n - (i - seg.start) * seg.blk
         col = jax.lax.broadcasted_iota(jnp.int32, (1, seg.blk), 1)
@@ -125,8 +168,9 @@ def _pass1_dq_body(n_ref, scale_ref, *refs, segs, total, c, qblk):
 def dequant_gate_partials(q_leaves, s_leaves, mask, *, qblk, blk,
                           leaf_scale, interpret=False):
     """Segment-table pass 1 over int8 code leaves [(G, C, n_l)] + scale
-    leaves [(G, C, nq_l)]: one ``pallas_call``, shared (C,) accumulators
-    across all segments — the dequant happens in VMEM per block."""
+    tiles [(G, nblocks_l, C, blk_l/qblk)] (``_scale_blocks``): one
+    ``pallas_call``, shared (C,) accumulators across all segments — the
+    dequant happens in VMEM per block."""
     G, C = q_leaves[0].shape[:2]
     sizes = tuple(int(l.shape[-1]) for l in q_leaves)
     segs, total = rp.make_segments(sizes, blk)
@@ -137,8 +181,7 @@ def dequant_gate_partials(q_leaves, s_leaves, mask, *, qblk, blk,
         grid=(G, total),
         in_specs=[pl.BlockSpec((1, C, seg.blk), rp._seg_index_map(seg))
                   for seg in segs]
-        + [pl.BlockSpec((1, C, seg.blk // qblk), rp._seg_index_map(seg))
-           for seg in segs]
+        + _scale_specs(segs, C, qblk)
         + [pl.BlockSpec((1, C, 1), lambda g, i, *_: (g, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, C), lambda g, i, *_: (g, 0)),
@@ -199,8 +242,7 @@ def dequant_gated_combine(q_leaves, s_leaves, gated_mask, weights, *, qblk,
         grid=(G, total),
         in_specs=[pl.BlockSpec((1, C, seg.blk), rp._seg_index_map(seg))
                   for seg in segs]
-        + [pl.BlockSpec((1, C, seg.blk // qblk), rp._seg_index_map(seg))
-           for seg in segs]
+        + _scale_specs(segs, C, qblk)
         + [pl.BlockSpec((1, C, 1), lambda g, i, *_: (g, 0, 0)),
            pl.BlockSpec((1, C, 1), lambda g, i, *_: (g, 0, 0))],
         out_specs=[pl.BlockSpec((1, 1, seg.blk), rp._seg_index_map(seg))
@@ -256,8 +298,7 @@ def dequant_pairwise_sq_dists(q_leaves, s_leaves, mask, *, qblk, blk,
         grid=(G, total),
         in_specs=[pl.BlockSpec((1, C, seg.blk), rp._seg_index_map(seg))
                   for seg in segs]
-        + [pl.BlockSpec((1, C, seg.blk // qblk), rp._seg_index_map(seg))
-           for seg in segs],
+        + _scale_specs(segs, C, qblk),
         out_specs=[
             pl.BlockSpec((1, C, C), lambda g, i, *_: (g, 0, 0)),
             pl.BlockSpec((1, C), lambda g, i, *_: (g, 0)),
@@ -297,13 +338,14 @@ def fused_dequant_pipeline_leafwise(q_leaves, s_leaves, weights, mask, *,
     decoded tree, without ever materialising it."""
     G, C = q_leaves[0].shape[:2]
     sizes = tuple(int(l.shape[-1]) for l in q_leaves)
-    if interpret is None:
-        interpret = not rp._on_tpu()
+    interpret = resolve_interpret(interpret)
     if blk is None:
         blk = rp.auto_blk(C, sizes)
     segs, _ = rp.make_segments(sizes, blk)
     assert all(seg.blk % qblk == 0 for seg in segs), \
         (qblk, [seg.blk for seg in segs])
+    s_leaves = [_scale_blocks(sl, seg, qblk)
+                for sl, seg in zip(s_leaves, segs)]
     if leaf_scale is None:
         leaf_scale = jnp.ones((len(q_leaves),), jnp.float32)
     if out_dtypes is None:
@@ -383,7 +425,6 @@ def fused_dequant_aggregate_sharded(enc, weights, mask, cfg, mesh, *, like,
     ``extent * qblk`` stay replicated (de-duplicated by the 0/1 per-leaf
     scale) — the ``align=qblk`` condition keeps each shard's scale
     columns exactly aligned with its code columns."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.sharding import specs as sh
@@ -425,10 +466,10 @@ def fused_dequant_aggregate_sharded(enc, weights, mask, cfg, mesh, *, like,
             out_dtypes=[l.dtype for l in like_leaves])
         return tuple(outs)
 
-    wrapped = shard_map(agg, mesh=mesh,
-                        in_specs=(P(None), P(None)) + tuple(q_specs)
-                        + tuple(s_specs),
-                        out_specs=out_specs, check_rep=False)
+    wrapped = jax.shard_map(agg, mesh=mesh,
+                            in_specs=(P(None), P(None)) + tuple(q_specs)
+                            + tuple(s_specs),
+                            out_specs=out_specs, check_vma=False)
     outs = wrapped(weights, mask, *q, *s)
     outs = [o.reshape(l.shape[1:]) for o, l in zip(outs, like_leaves)]
     return jax.tree_util.tree_unflatten(treedef, outs)

@@ -305,7 +305,6 @@ def aggregate_sharded(updates, weights, mask, cfg, mesh, axes=None):
     replicated; a 0/1 per-leaf scale keeps them from being double-counted
     in the psum.  Semantically equivalent to ``aggregate`` (parity atol
     ~1e-5 from the shard-local summation order)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import robust_pipeline as rp
@@ -345,9 +344,9 @@ def aggregate_sharded(updates, weights, mask, cfg, mesh, axes=None):
             out_dtypes=[l.dtype for l in leaves])
         return tuple(outs)
 
-    wrapped = shard_map(agg, mesh=mesh,
-                        in_specs=(P(None), P(None)) + tuple(in_specs),
-                        out_specs=out_specs, check_rep=False)
+    wrapped = jax.shard_map(agg, mesh=mesh,
+                            in_specs=(P(None), P(None)) + tuple(in_specs),
+                            out_specs=out_specs, check_vma=False)
     outs = wrapped(weights, mask, *flat)
     outs = [o.reshape(l.shape[1:]) for o, l in zip(outs, leaves)]
     return jax.tree_util.tree_unflatten(treedef, outs)

@@ -1,8 +1,10 @@
 """jit'd public wrapper for the flash attention kernel.
 
 Accepts the model's (B, S, H, dh) layout, transposes to the kernel's
-(B, H, S, dh), auto-selects interpret mode on CPU, and falls back to the
-ref for shapes the kernel can't tile (tiny smoke sizes).
+(B, H, S, dh), and runs the kernel compiled on the TPU or in interpret
+mode elsewhere (``kernels.backend``).  A shape the kernel cannot tile
+(S or dh not a multiple of 128) falls back to the ref only in interpret
+mode, for tiny smoke sizes; on the TPU it raises.
 """
 from __future__ import annotations
 
@@ -11,12 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.flash_attention_ref import flash_attention_ref
-
-
-def _on_tpu():
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
@@ -26,11 +25,14 @@ def flash_attention(q, k, v, *, causal=True, window=0, interpret=None):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
+    interpret = resolve_interpret(interpret)
     if S % 128 != 0 or dh % 128 != 0:
+        if not interpret:
+            raise ValueError(
+                f"flash_attention cannot tile S={S}, dh={dh} on the TPU "
+                "(both must be multiples of 128)")
         out = flash_attention_ref(qt, kt, vt, causal=causal, window=window)
     else:
-        if interpret is None:
-            interpret = not _on_tpu()
         out = flash_attention_fwd(qt, kt, vt, causal=causal, window=window,
                                   interpret=interpret)
     return out.transpose(0, 2, 1, 3)

@@ -1,8 +1,12 @@
 """Pallas flash-decode over a paged KV cache (the serving decode kernel).
 
 One query token per slot attends over that slot's pages, gathered
-straight from the (N, page, Hkv, dh) pool into VMEM via the per-slot
-page table — no contiguous K/V copy, so eviction never compacts.
+straight from the (N, Hkv, page, dh) pool into VMEM via the per-slot
+page table — no contiguous K/V copy, so eviction never compacts.  The
+pool is head-major so that each (page, dh) block is a whole trailing
+tile of the pool: the block the kernel fetches for (page, head) is the
+pool's own last two dims, which tiles on the TPU for any head count and
+any head dim.
 
 Grid ``(S, Hkv, maxp)`` with the page axis minor-most; the page table
 and per-slot visible-key counts ride scalar prefetch
@@ -15,10 +19,11 @@ across KV blocks; dead pages (``p*page >= lengths[s]``) are skipped with
 are 0, a valid pool index — but no FLOPs are spent).
 
 The int8 path fuses dequantization into the page loads: codes are
-fetched as int8 (quarter the bytes of f32) and multiplied by the
-per-(row, head) f32 scales in VMEM — the exact ``codecs.quant_decode``
-multiply, same trick as comm/kernels/comm_codecs.py — so the unquantized
-K/V never exist in HBM at all.
+fetched as int8 (quarter the bytes of f32) and the per-(row, head) f32
+scales, stored as one (1, page) row per (page, head), are applied to
+the rows where they act — the key scales to the (g, page) scores, the
+value scales to the probabilities before the PV product — so the
+unquantized K/V never exist in HBM or VMEM.
 
 Parity oracle: kernels/paged_decode_ref.py (contract documented there).
 """
@@ -31,16 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_decode_ref import paged_decode_ref
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
 
 
 def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
@@ -64,25 +62,24 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)        # (g, dh)
         q = q * (q.shape[-1] ** -0.5)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (page, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if int8:
-            # fused dequant: the exact quant_decode multiply, replayed
-            # on the VMEM-resident block (bit-identical to dequantizing
-            # in HBM first)
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32)        # (page, dh)
+        v = v_ref[0, 0].astype(jnp.float32)
         scores = jax.lax.dot_general(                # (g, page)
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if int8:
+            # fused dequant of K: row j's scale multiplies score column j
+            scores = scores * ks_ref[0, 0]           # (1, page) row
         kpos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         scores = jnp.where(kpos < n_keys, scores, NEG_INF)
         m_prev = m_ref[...]                          # (g, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         pexp = jnp.exp(scores - m_new)               # (g, page)
+        # fused dequant of V: row j's scale multiplies probability column j
+        pv = pexp * vs_ref[0, 0] if int8 else pexp
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=1,
                                                   keepdims=True)
@@ -99,37 +96,29 @@ def paged_flash_decode(q, kp, vp, table, lengths, *, k_scale=None,
                        v_scale=None, interpret=None):
     """Paged flash-decode; same contract as paged_decode_ref.
 
-    Routes to the Pallas kernel (interpret mode off-TPU, like every
-    kernel wrapper in this package); falls back to the dense reference
-    when the head dim can't tile the TPU lane width.
+    Compiled on the TPU, interpret mode elsewhere
+    (``kernels.backend.resolve_interpret``).  Every block is a pool's own
+    trailing (page, dh) or (1, page) tile, so there is no shape this
+    kernel cannot tile and no reference fallback.
     """
     s, hq, dh = q.shape
-    n, page, hkv, _ = kp.shape
+    n, hkv, page, _ = kp.shape
     g = hq // hkv
     maxp = table.shape[1]
-    if interpret is None:
-        interpret = not _on_tpu()
-    if not interpret and dh % 128 != 0:
-        return paged_decode_ref(q, kp, vp, table, lengths,
-                                k_scale=k_scale, v_scale=v_scale)
+    interpret = resolve_interpret(interpret)
     int8 = k_scale is not None
 
     q4 = q.reshape(s, hkv, g, dh)
+    page_map = lambda si, h, p, tab, ln: (tab[si, p], h, 0, 0)  # noqa: E731
     in_specs = [
         pl.BlockSpec((1, 1, g, dh), lambda si, h, p, tab, ln: (si, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, dh),
-                     lambda si, h, p, tab, ln: (tab[si, p], 0, h, 0)),
-        pl.BlockSpec((1, page, 1, dh),
-                     lambda si, h, p, tab, ln: (tab[si, p], 0, h, 0)),
+        pl.BlockSpec((1, 1, page, dh), page_map),
+        pl.BlockSpec((1, 1, page, dh), page_map),
     ]
     args = [table, lengths.astype(jnp.int32), q4, kp, vp]
     if int8:
-        in_specs += [
-            pl.BlockSpec((1, page, 1),
-                         lambda si, h, p, tab, ln: (tab[si, p], 0, h)),
-            pl.BlockSpec((1, page, 1),
-                         lambda si, h, p, tab, ln: (tab[si, p], 0, h)),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, 1, page), page_map),
+                     pl.BlockSpec((1, 1, 1, page), page_map)]
         args += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 METHODS = ("argsort", "segmented", "pallas")
 
 
@@ -82,25 +84,34 @@ def _block_topd_body(x_ref, v_ref, i_ref, *, d, blk):
     GLOBAL indices.  The (C,)-style running-accumulator layout of the
     robust pipeline is deliberately avoided: per-block candidates keep
     the kernel associative (a segmented reduction), so the merge can run
-    anywhere and the grid steps carry no cross-step state."""
-    x = x_ref[0, :].astype(jnp.float32)
+    anywhere and the grid steps carry no cross-step state.  Every step
+    is whole-vector work (max, first-index-of-max by a min over a lane
+    iota, a masked write), which is what the TPU vector unit lowers."""
+    x = x_ref[...].astype(jnp.float32)                    # (1, blk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
 
-    def step(carry, _):
-        a = jnp.argmax(carry)
-        val = carry[a]
-        return carry.at[a].set(-jnp.inf), (val, a.astype(jnp.int32))
+    def step(k, carry):
+        x, vs, ids = carry
+        val = jnp.max(x)
+        a = jnp.min(jnp.where(x == val, lane, blk))
+        x = jnp.where(lane == a, -jnp.inf, x)
+        vs = jnp.where(slot == k, val, vs)
+        ids = jnp.where(slot == k, a, ids)
+        return x, vs, ids
 
-    _, (vs, ids) = jax.lax.scan(step, x, None, length=d)
-    v_ref[0, :] = vs
-    i_ref[0, :] = ids + jnp.int32(pl.program_id(0) * blk)
+    _, vs, ids = jax.lax.fori_loop(
+        0, d, step, (x, jnp.full((1, d), -jnp.inf, jnp.float32),
+                     jnp.zeros((1, d), jnp.int32)))
+    v_ref[0] = vs
+    i_ref[0] = ids + pl.program_id(0) * blk
 
 
 def topd_pallas(g, d, *, blk=4096, interpret=None):
     """Stage-1 candidates from the blocked Pallas kernel, stage-2 merge
-    in XLA.  Off-TPU the kernel runs in interpret mode (repo test
-    convention)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    in XLA.  Each step's (d,) candidates are a (1, d) row of its own
+    (nb, 1, d) output slab, a whole trailing tile on the TPU."""
+    interpret = resolve_interpret(interpret)
     blk = max(int(blk), d)
     g, mp = _pad_neg_inf(g.astype(jnp.float32), blk)
     nb = mp // blk
@@ -108,10 +119,10 @@ def topd_pallas(g, d, *, blk=4096, interpret=None):
         functools.partial(_block_topd_body, d=d, blk=blk),
         grid=(nb,),
         in_specs=[pl.BlockSpec((1, blk), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, d), lambda i: (i, 0)),
-                   pl.BlockSpec((1, d), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, d), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, d), jnp.int32)],
+        out_specs=[pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, 1, d), jnp.int32)],
         interpret=interpret,
     )(g.reshape(1, mp))
     _, j = jax.lax.top_k(v.reshape(-1), d)

@@ -9,12 +9,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.robust_agg import robust_agg_fwd
-from repro.kernels.robust_agg_ref import robust_agg_ref
-
-
-def _on_tpu():
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit,
@@ -32,8 +28,7 @@ def robust_aggregate_tree(updates, mask, *, mode="trimmed", trim_frac=0.2,
     pad = (-N) % blk
     if pad:
         flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     agg = robust_agg_fwd(flat, mask.astype(jnp.float32), mode=mode,
                          trim_frac=trim_frac, blk=blk, interpret=interpret)
     agg = agg[:N]

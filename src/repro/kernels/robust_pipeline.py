@@ -70,11 +70,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import on_tpu, resolve_interpret
 from repro.kernels.robust_agg import _BIG, stable_ranks
-
-
-def _on_tpu():
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -118,6 +115,26 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
+# Scoped VMEM a Mosaic kernel may use on the TPU v5e (the compiler's
+# default limit), and the bytes each lane of a leaf block keeps live in
+# the pass-1/pass-2 bodies: the rank network's two (C, C) f32 compare
+# planes per coordinate (8 C^2), the double-buffered input block plus
+# its f32 working copies (24 C).  Fitted to what the v5e compiler
+# accepts (tests/test_tpu_compile.py): one C=16 leaf compiles at
+# blk=8192 and not at 10240, one C=32 leaf at 2048 and not at 3072.
+TPU_VMEM_BYTES = 16 * 2 ** 20
+
+
+def _vmem_per_lane(c):
+    return 8 * c * c + 24 * c
+
+
+def _body_lanes(sizes, blk):
+    """Lanes of every leaf block the kernel body holds at ``blk``: each
+    leaf's branch is compiled into the one body, so their stacks add."""
+    return sum(min(blk, _round_up(int(n), 128)) for n in sizes)
+
+
 def auto_blk(c, sizes, *, backend=None):
     """Pick the streaming block size from the backend + memory budget.
 
@@ -126,19 +143,21 @@ def auto_blk(c, sizes, *, backend=None):
     the last-level cache (~16 MB — measured 2x wall time when it spills)
     while staying large enough to amortise the per-step interpreter
     overhead: clamp to [2048, 32768] lanes, and never wider than the
-    longest leaf.  TPU: VMEM-sized tiles — each live (C, blk) f32 leaf
-    block is double-buffered and the rank network needs its (C, C, blk)
-    scratch, so blocks fit an ~8 MB budget, clamped to [512, 8192] lanes
-    (multiples of 128).
+    longest leaf.  TPU: the widest multiple of 128 (at most 8192) for
+    which every leaf block of the body, at ``_vmem_per_lane`` bytes per
+    lane, fits ``TPU_VMEM_BYTES``; 128 when even that does not fit.
     """
     if backend is None:
-        backend = jax.default_backend()
+        backend = "tpu" if on_tpu() else "cpu"
+    c = max(c, 8)
     if backend == "tpu":
-        budget = 8 * 2 ** 20
-        blk = budget // (4 * max(c, 8) * (max(c, 8) + 2))
-        return int(max(512, min(_round_up(blk, 128), 8192)))
+        lanes = TPU_VMEM_BYTES // _vmem_per_lane(c)
+        blk = min(8192, _round_up(max(sizes), 128))
+        while blk > 128 and _body_lanes(sizes, blk) > lanes:
+            blk -= 128
+        return int(blk)
     budget = 16 * 2 ** 20
-    blk = budget // (4 * max(c, 8) ** 2)
+    blk = budget // (4 * c ** 2)
     blk = max(2048, min(_round_up(blk, 128), 1 << 15))
     return int(min(blk, _round_up(max(sizes), 128)))
 
@@ -563,8 +582,7 @@ def fused_pipeline(x, weights, mask, *, aggregator="trimmed_mean",
     Returns the (G, N) aggregated rows.  Semantically equivalent to
     ``aggregation.aggregate_ref`` vmapped over G (parity-tested)."""
     G, C, N = x.shape
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     blk = min(blk, max(128, N))
     pad = (-N) % blk
     if pad:
@@ -630,8 +648,7 @@ def fused_pipeline_leafwise(leaves, weights, mask, *,
     replicated (non-divisible) leaves from being double-counted."""
     G, C = leaves[0].shape[:2]
     sizes = tuple(int(l.shape[-1]) for l in leaves)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     if blk is None:
         blk = auto_blk(C, sizes)
     if leaf_scale is None:

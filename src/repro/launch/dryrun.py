@@ -34,6 +34,10 @@ from repro.models.model import build
 from repro.optim import optimizers
 from repro.sharding import specs as sh
 
+# the production mesh these dry-runs model is built of TPU v5e chips;
+# the placeholder devices are host CPUs, so the kind is named, not read
+V5E = "TPU v5 lite"
+
 
 def _named(mesh, spec_tree):
     from jax.sharding import NamedSharding, PartitionSpec
@@ -217,7 +221,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod=False, verbose=True,
     else:
         cost = compiled.cost_analysis()
         coll = roof.parse_collectives(compiled.as_text())
-    terms = roof.roofline(cost, coll)
+    terms = roof.roofline(cost, coll, V5E)
     n_params = roof.count_params(params_s)
     mflops = roof.model_flops(cfg, n_params, shape, shape.kind)
     n_chips = mesh.size

@@ -14,7 +14,7 @@ from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import get_config
 from repro.launch import inputs as inputs_lib
 from repro.launch import roofline as roof
-from repro.launch.dryrun import _probe_costs
+from repro.launch.dryrun import V5E, _probe_costs
 from repro.launch.mesh import make_production_mesh
 
 PAIRS = {
@@ -51,7 +51,7 @@ def measure(pair, variant_name, json_path=None):
     shape = INPUT_SHAPES[shape_name]
     cost, coll = _probe_costs(cfg, shape_name, mesh, shape.kind,
                               lower_variant)
-    terms = roof.roofline(cost, coll)
+    terms = roof.roofline(cost, coll, V5E)
     res = {"pair": pair, "arch": arch, "shape": shape_name,
            "variant": variant_name, **terms}
     print(json.dumps(res, indent=1, default=float))

@@ -21,17 +21,43 @@ from repro.analysis.hlo import (COLLECTIVES as _COLL,  # noqa: F401
                                 SHAPE_RE as _SHAPE_RE,
                                 parse_collectives,
                                 shape_bytes as _shape_bytes)
-from repro.configs.base import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+# Published per-chip peaks, keyed by jax ``Device.device_kind``.
+# "TPU v5 lite" is the TPU v5e: Google Cloud documentation, "TPU v5e"
+# (cloud.google.com/tpu/docs/v5e) — 197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interchip interconnect
+# (200 GB/s over 4 links = 50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,         # FLOP/s
+        "ops_int8": 393e12,           # OP/s
+        "hbm_bytes": 16e9,            # B
+        "hbm_bw": 819e9,              # B/s
+        "ici_bw": 50e9,               # B/s per link
+    },
+}
 
 
-def roofline(cost: dict, coll_bytes: Dict[str, int]) -> dict:
+def peaks(device_kind: str) -> dict:
+    """The peak table row of one device kind; a kind with no published
+    row is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
+
+
+def roofline(cost: dict, coll_bytes: Dict[str, int],
+             device_kind: str) -> dict:
+    pk = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     cbytes = sum(v * (2 if k == "all-reduce" else 1)
                  for k, v in coll_bytes.items())
-    t_compute = flops / PEAK_FLOPS_BF16
-    t_memory = byts / HBM_BW
-    t_coll = cbytes / ICI_BW
+    t_compute = flops / pk["flops_bf16"]
+    t_memory = byts / pk["hbm_bw"]
+    t_coll = cbytes / pk["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dom = max(terms, key=terms.get)

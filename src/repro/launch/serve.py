@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 
 
@@ -116,6 +117,7 @@ def main():
     ap.add_argument("--trace", default=None, metavar="OUT_JSON")
     ap.add_argument("--telemetry-jsonl", default=None, metavar="OUT_JSONL")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

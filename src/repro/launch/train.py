@@ -24,6 +24,7 @@ from repro.configs.registry import get_config
 from repro.core import pod
 from repro.data import synthetic
 from repro.launch import inputs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 from repro.optim import optimizers
@@ -176,6 +177,7 @@ def main():
                          "--scenario cell through the async engine, like "
                          "--population")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if (args.population or args.async_deadline) and not args.scenario:
         ap.error("--population/--async-deadline drive the buffered-async "

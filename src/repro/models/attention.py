@@ -190,8 +190,9 @@ def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
     page budget instead).
 
     Cache contract (see init_paged_kv_cache):
-      kp, vp    (N, page, Hkv, dh)  shared page pools (f32 or int8 codes)
-      ks, vs    (N, page, Hkv) f32  per-(row, head) scales (int8 only)
+      kp, vp    (N, Hkv, page, dh)  shared head-major page pools (f32 or
+                                    int8 codes)
+      ks, vs    (N, Hkv, 1, page)   f32 per-(row, head) scales (int8 only)
       table     (A, maxp) int32     per-slot page table (unallocated = 0)
       length    (A,) int32          valid tokens already in the slot
       active    (A,) f32            1 = slot holds a live request
@@ -213,7 +214,7 @@ def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
     g = hq // hkv
     B, S = q.shape[0], q.shape[1]
     kp, vp, table = cache["kp"], cache["vp"], cache["table"]
-    n_pages, page = kp.shape[0], kp.shape[1]
+    n_pages, page = kp.shape[0], kp.shape[2]
     maxp = table.shape[1]
     int8 = "ks" in cache
     length, active = cache["length"], cache["active"]
@@ -238,14 +239,16 @@ def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
         if int8:
             kq, ks = _paged_quant(k_new)
             vq, vs = _paged_quant(v_new)
-            new_cache["ks"] = cache["ks"].at[dest, row].set(ks, mode="drop")
-            new_cache["vs"] = cache["vs"].at[dest, row].set(vs, mode="drop")
+            new_cache["ks"] = cache["ks"].at[dest, :, 0, row].set(
+                ks, mode="drop")
+            new_cache["vs"] = cache["vs"].at[dest, :, 0, row].set(
+                vs, mode="drop")
             k_cast, v_cast = kq, vq
         else:
             k_cast = k_new.astype(kp.dtype)
             v_cast = v_new.astype(vp.dtype)
-        new_cache["kp"] = kp.at[dest, row].set(k_cast, mode="drop")
-        new_cache["vp"] = vp.at[dest, row].set(v_cast, mode="drop")
+        new_cache["kp"] = kp.at[dest, :, row].set(k_cast, mode="drop")
+        new_cache["vp"] = vp.at[dest, :, row].set(v_cast, mode="drop")
         out = out.astype(dtype) @ params["wo"].astype(dtype)
         return out, new_cache
 
@@ -257,16 +260,18 @@ def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
     if int8:
         kq, ks = _paged_quant(k_new[:, 0])
         vq, vs = _paged_quant(v_new[:, 0])
-        new_cache["ks"] = cache["ks"].at[dest, row].set(ks, mode="drop")
-        new_cache["vs"] = cache["vs"].at[dest, row].set(vs, mode="drop")
+        new_cache["ks"] = cache["ks"].at[dest, :, 0, row].set(
+            ks, mode="drop")
+        new_cache["vs"] = cache["vs"].at[dest, :, 0, row].set(
+            vs, mode="drop")
         k_cast, v_cast = kq, vq
         k_scale, v_scale = new_cache["ks"], new_cache["vs"]
     else:
         k_cast = k_new[:, 0].astype(kp.dtype)
         v_cast = v_new[:, 0].astype(vp.dtype)
         k_scale = v_scale = None
-    kp = new_cache["kp"] = kp.at[dest, row].set(k_cast, mode="drop")
-    vp = new_cache["vp"] = vp.at[dest, row].set(v_cast, mode="drop")
+    kp = new_cache["kp"] = kp.at[dest, :, row].set(k_cast, mode="drop")
+    vp = new_cache["vp"] = vp.at[dest, :, row].set(v_cast, mode="drop")
     n_keys = jnp.where(active > 0, length + 1, 0)
     attend = paged_flash_decode if cfg.attn_impl == "pallas" \
         else paged_decode_ref
@@ -292,16 +297,19 @@ def init_paged_kv_cache(cfg, slots, num_pages, page_size, max_pages, *,
     shared across slots; the per-slot page table indexes into them
     (unallocated entries stay 0 — always a valid pool index, masked out
     by length/active).  ``int8`` stores codes + per-(row, head) f32
-    scales instead of raw K/V (see _paged_quant)."""
+    scales instead of raw K/V (see _paged_quant).  Pools are head-major,
+    (N, Hkv, page, dh), so one page of one head is a whole trailing tile
+    for kernels/paged_decode.py; the scales keep one (1, page) row per
+    (page, head) for the same reason."""
     hkv, dh = cfg.n_kv_heads, cfg.resolved_head_dim
     pool_dtype = jnp.int8 if int8 else dtype
-    z = jnp.zeros((num_pages, page_size, hkv, dh), pool_dtype)
+    z = jnp.zeros((num_pages, hkv, page_size, dh), pool_dtype)
     c = {"kp": z, "vp": z,
          "table": jnp.zeros((slots, max_pages), jnp.int32),
          "length": jnp.zeros((slots,), jnp.int32),
          "active": jnp.zeros((slots,), jnp.float32),
          "new_valid": jnp.zeros((slots,), jnp.int32)}
     if int8:
-        s = jnp.ones((num_pages, page_size, hkv), jnp.float32)
+        s = jnp.ones((num_pages, hkv, 1, page_size), jnp.float32)
         c["ks"], c["vs"] = s, s
     return c

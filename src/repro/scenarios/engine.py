@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,42 @@ def make_attack_fns(sc, fed_cfg, n_classes):
     return data_attack, update_attack
 
 
+class Cell(NamedTuple):
+    """Everything one scenario cell's rounds close over."""
+    fed_cfg: object
+    model: object
+    federation: object
+    server_test: dict
+    malicious: object            # (pop,) 0/1, or None without an attack
+    n_mal: int
+    data_attack: object
+    update_attack: object
+
+
+def build_cell(sc, *, n_clients, seed, kind, n, n_classes, sep,
+               dirichlet_alpha, arch=None) -> Cell:
+    """The federation, model, config and attack hooks of one cell — what
+    ``run_scenario`` drives, for callers that drive single rounds."""
+    fed_cfg = sc.fed_config(n_clients)
+    # async cells register a POPULATION of clients and sample the cohort
+    pop = (sc.population or 3 * n_clients) if sc.async_mode else n_clients
+    if sc.async_mode:
+        fed_cfg = dataclasses.replace(fed_cfg, population=pop)
+    model = build(ARCHS[arch or
+                        ("paper-cnn" if kind == "images" else "paper-mlp")])
+    federation, server_test = build_federation(
+        seed, kind=kind, n=n, n_clients=pop, batch_size=32,
+        n_classes=n_classes, sep=sep, dirichlet_alpha=dirichlet_alpha)
+
+    n_mal = max(int(round(sc.mal_frac * pop)), 1) \
+        if sc.attack != "none" else 0
+    malicious = jnp.zeros((pop,)).at[jnp.arange(n_mal)].set(1.0) \
+        if n_mal else None
+    data_attack, update_attack = make_attack_fns(sc, fed_cfg, n_classes)
+    return Cell(fed_cfg, model, federation, server_test, malicious, n_mal,
+                data_attack, update_attack)
+
+
 def run_scenario(scenario, *, n_clients=10, n_rounds=10, seed=0,
                  kind="tabular", n=1600, n_classes=10, sep=1.0,
                  dirichlet_alpha=1.0, arch=None, driver="scan",
@@ -112,22 +149,11 @@ def run_scenario(scenario, *, n_clients=10, n_rounds=10, seed=0,
             async_mode=True, population=population or sc.population,
             fed=sc.fed + ((("async_deadline", float(async_deadline)),)
                           if async_deadline else ()))
-    fed_cfg = sc.fed_config(n_clients)
-    # async cells register a POPULATION of clients and sample the cohort
-    pop = (sc.population or 3 * n_clients) if sc.async_mode else n_clients
-    if sc.async_mode:
-        fed_cfg = dataclasses.replace(fed_cfg, population=pop)
-    model = build(ARCHS[arch or
-                        ("paper-cnn" if kind == "images" else "paper-mlp")])
-    federation, server_test = build_federation(
-        seed, kind=kind, n=n, n_clients=pop, batch_size=32,
-        n_classes=n_classes, sep=sep, dirichlet_alpha=dirichlet_alpha)
-
-    n_mal = max(int(round(sc.mal_frac * pop)), 1) \
-        if sc.attack != "none" else 0
-    malicious = jnp.zeros((pop,)).at[jnp.arange(n_mal)].set(1.0) \
-        if n_mal else None
-    data_attack, update_attack = make_attack_fns(sc, fed_cfg, n_classes)
+    cell = build_cell(sc, n_clients=n_clients, seed=seed, kind=kind, n=n,
+                      n_classes=n_classes, sep=sep,
+                      dirichlet_alpha=dirichlet_alpha, arch=arch)
+    (fed_cfg, model, federation, server_test, malicious, n_mal,
+     data_attack, update_attack) = cell
 
     trig_test = {"x": attacks.stamp_trigger(server_test["x"],
                                             patch=sc.backdoor_patch),

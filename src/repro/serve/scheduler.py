@@ -51,7 +51,8 @@ class ServeConfig:
     eos_id: int = -1            # sampled token that evicts; -1 = never
     temperature: float = 0.0    # 0 = argmax decoding
     kv_int8: bool = False       # int8 page pools + per-row scales
-    attn: str = "ref"           # ref | pallas (paged flash-decode)
+    attn: str = "pallas"        # pallas (paged flash-decode) | ref (the
+                                # dense gather oracle)
 
     @property
     def pages_per_slot(self) -> int:

@@ -8,6 +8,7 @@ from repro.configs.base import FedConfig, TrainConfig
 from repro.configs.registry import ARCHS
 from repro.core import fedfits, pod
 from repro.data.pipeline import build_federation
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 from repro.models.model import build
 from repro.optim import optimizers
@@ -77,7 +78,7 @@ def test_zero1_matches_baseline_loss():
     batch = {"tokens": jax.random.randint(key, (4, 16), 0, 128),
              "targets": jax.random.randint(key, (4, 16), 0, 128)}
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     compute_sh = sh.named(mesh, sh.param_specs_tp(params, mesh=mesh))
     master_sh = sh.named(mesh, sh.param_specs(params, mesh=mesh))
 

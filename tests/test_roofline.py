@@ -2,7 +2,9 @@
 MODEL_FLOPS accounting)."""
 import numpy as np
 
-from repro.configs.base import INPUT_SHAPES, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+import pytest
+
+from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import ARCHS
 from repro.launch import roofline as roof
 
@@ -37,15 +39,21 @@ def test_parse_skips_done_halves():
 
 
 def test_roofline_terms_and_dominance():
-    cost = {"flops": PEAK_FLOPS_BF16, "bytes accessed": HBM_BW / 2}
-    coll = {"all-gather": 0, "all-reduce": ICI_BW, "reduce-scatter": 0,
+    pk = roof.peaks("TPU v5 lite")
+    cost = {"flops": pk["flops_bf16"], "bytes accessed": pk["hbm_bw"] / 2}
+    coll = {"all-gather": 0, "all-reduce": pk["ici_bw"], "reduce-scatter": 0,
             "all-to-all": 0, "collective-permute": 0}
-    r = roof.roofline(cost, coll)
+    r = roof.roofline(cost, coll, "TPU v5 lite")
     assert r["compute_s"] == 1.0
     assert r["memory_s"] == 0.5
     assert r["collective_s"] == 2.0        # all-reduce counts 2x
     assert r["dominant"] == "collective_s"
     assert r["bound_s"] == 2.0
+
+
+def test_roofline_refuses_unknown_device_kind():
+    with pytest.raises(ValueError, match="no published peaks"):
+        roof.roofline({"flops": 1.0}, {}, "cpu")
 
 
 def test_model_flops_train_vs_decode():

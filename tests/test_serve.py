@@ -39,8 +39,8 @@ def _rand_paged(seed, s, maxp, page, hq, hkv, dh, n_extra=3):
     n = s * maxp + n_extra
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (s, hq, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (n, page, hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (n, page, hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (n, hkv, page, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (n, hkv, page, dh), jnp.float32)
     table = jax.random.permutation(ks[3], n)[:s * maxp].reshape(s, maxp)
     # ragged: full pages, partial last page, single row, inactive (0)
     lengths = jax.random.randint(ks[4], (s,), 1, maxp * page + 1)
@@ -49,6 +49,14 @@ def _rand_paged(seed, s, maxp, page, hq, hkv, dh, n_extra=3):
     if s > 2:
         lengths = lengths.at[2].set(0)             # inactive slot
     return q, kp, vp, table.astype(jnp.int32), lengths.astype(jnp.int32)
+
+
+def _pool_quant(pool):
+    """int8 codes + (N, Hkv, 1, page) scales of an (N, Hkv, page, dh)
+    pool, by the append-time quantizer the serving cache uses."""
+    from repro.models.attention import _paged_quant
+    codes, scale = _paged_quant(pool)
+    return codes, scale[:, :, None, :]
 
 
 class TestPagedKernel:
@@ -76,10 +84,10 @@ class TestPagedKernel:
                 continue
             for h in range(4):
                 qs = np.asarray(q[si, h]) / np.sqrt(64)
-                logits = qs @ np.asarray(k[si, :L, h // g]).T
+                logits = qs @ np.asarray(k[si, h // g, :L]).T
                 p = np.exp(logits - logits.max())
                 p /= p.sum()
-                expect = p @ np.asarray(v[si, :L, h // g])
+                expect = p @ np.asarray(v[si, h // g, :L])
                 np.testing.assert_allclose(out[si, h], expect,
                                            atol=FP32_ATOL)
 
@@ -92,11 +100,10 @@ class TestPagedKernel:
         np.testing.assert_array_equal(np.asarray(out[2]), 0.0)
 
     def test_int8_kernel_matches_int8_oracle(self):
-        from repro.models.attention import _paged_quant
         q, kp, vp, table, lengths = _rand_paged(11, 4, 3, 8,
                                                 hq=4, hkv=2, dh=64)
-        kq, ksc = _paged_quant(kp)
-        vq, vsc = _paged_quant(vp)
+        kq, ksc = _pool_quant(kp)
+        vq, vsc = _pool_quant(vp)
         out = paged_flash_decode(q, kq, vq, table, lengths,
                                  k_scale=ksc, v_scale=vsc,
                                  interpret=True)
@@ -108,11 +115,10 @@ class TestPagedKernel:
                                    atol=INT8_QUANT_ATOL)
 
     def test_int8_vs_fp32_quantization_bound(self):
-        from repro.models.attention import _paged_quant
         q, kp, vp, table, lengths = _rand_paged(13, 4, 3, 8,
                                                 hq=4, hkv=2, dh=64)
-        kq, ksc = _paged_quant(kp)
-        vq, vsc = _paged_quant(vp)
+        kq, ksc = _pool_quant(kp)
+        vq, vsc = _pool_quant(vp)
         out8 = paged_flash_decode(q, kq, vq, table, lengths,
                                   k_scale=ksc, v_scale=vsc,
                                   interpret=True)
