@@ -189,17 +189,19 @@ def dequant_gate_partials(q_leaves, s_leaves, mask, *, qblk, blk,
             pl.BlockSpec((1, 1), lambda g, i, *_: (g, 0)),
         ],
     )
-    dots, sqn, refsq = pl.pallas_call(
-        functools.partial(_pass1_dq_body, segs=segs, total=total, c=C,
-                          qblk=qblk),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(n_sel, leaf_scale, *q_leaves, *s_leaves, mask.reshape(G, C, 1))
+    with jax.named_scope("dequant_pass1"):
+        dots, sqn, refsq = pl.pallas_call(
+            functools.partial(_pass1_dq_body, segs=segs, total=total, c=C,
+                              qblk=qblk),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, 1), jnp.float32),
+            ],
+            name="dequant_pass1",
+            interpret=interpret,
+        )(n_sel, leaf_scale, *q_leaves, *s_leaves, mask.reshape(G, C, 1))
     return dots, sqn, refsq
 
 
@@ -248,15 +250,17 @@ def dequant_gated_combine(q_leaves, s_leaves, gated_mask, weights, *, qblk,
         out_specs=[pl.BlockSpec((1, 1, seg.blk), rp._seg_index_map(seg))
                    for seg in segs],
     )
-    outs = pl.pallas_call(
-        functools.partial(_pass2_dq_body, segs=segs, total=total, c=C,
-                          qblk=qblk, mode=mode, trim_frac=trim_frac),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((G, 1, seg.n), dt)
-                   for seg, dt in zip(segs, out_dtypes)],
-        interpret=interpret,
-    )(n_sel, *q_leaves, *s_leaves, gated_mask.reshape(G, C, 1),
-      weights.reshape(G, C, 1))
+    with jax.named_scope("dequant_pass2"):
+        outs = pl.pallas_call(
+            functools.partial(_pass2_dq_body, segs=segs, total=total, c=C,
+                              qblk=qblk, mode=mode, trim_frac=trim_frac),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((G, 1, seg.n), dt)
+                       for seg, dt in zip(segs, out_dtypes)],
+            name="dequant_pass2",
+            interpret=interpret,
+        )(n_sel, *q_leaves, *s_leaves, gated_mask.reshape(G, C, 1),
+          weights.reshape(G, C, 1))
     return [o[:, 0] for o in outs]
 
 
@@ -304,16 +308,18 @@ def dequant_pairwise_sq_dists(q_leaves, s_leaves, mask, *, qblk, blk,
             pl.BlockSpec((1, C), lambda g, i, *_: (g, 0)),
         ],
     )
-    gram, sqn = pl.pallas_call(
-        functools.partial(_pairwise_dq_body, segs=segs, total=total, c=C,
-                          qblk=qblk),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((G, C, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-        ],
-        interpret=interpret,
-    )(leaf_scale, *q_leaves, *s_leaves)
+    with jax.named_scope("dequant_gram"):
+        gram, sqn = pl.pallas_call(
+            functools.partial(_pairwise_dq_body, segs=segs, total=total, c=C,
+                              qblk=qblk),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((G, C, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+            ],
+            name="dequant_gram",
+            interpret=interpret,
+        )(leaf_scale, *q_leaves, *s_leaves)
     if axis_name is not None:
         gram = jax.lax.psum(gram, axis_name)
         sqn = jax.lax.psum(sqn, axis_name)

@@ -62,8 +62,9 @@ import jax.numpy as jnp
 from repro.comm import codecs as comm_codecs
 from repro.core import aggregation, clientstore, driver as scan_driver, \
     fairness, faults as faults_mod, fitness
+from repro.core.fedfits import scoped_eval
 from repro.obs import counters as obs_counters
-from repro.obs.trace import annotate as obs_annotate
+from repro.obs.trace import annotate as obs_annotate, span
 
 _EPS = 1e-12
 
@@ -229,13 +230,15 @@ def make_async_round(model, fed_cfg, pop_data, *, batch_size=32,
             lambda w_k, w: w_k - w[None], locals_, state.params)
         att_carry = state.attacker
         if update_attack is not None:
-            if stateful_attack:
-                att_view = update_attack.gather(state.attacker, idx) \
-                    if hasattr(update_attack, "gather") else state.attacker
-                updates, att_carry = update_attack(
-                    updates, cmal, r_upd, att_view)
-            else:
-                updates = update_attack(updates, cmal, r_upd)
+            with obs_annotate("update_attack"):
+                if stateful_attack:
+                    att_view = update_attack.gather(state.attacker, idx) \
+                        if hasattr(update_attack, "gather") \
+                        else state.attacker
+                    updates, att_carry = update_attack(
+                        updates, cmal, r_upd, att_view)
+                else:
+                    updates = update_attack(updates, cmal, r_upd)
 
         # ---- fitness at COMPUTE time (a late delivery does not
         # re-evaluate; its score was recorded when the work ran) ---------
@@ -310,10 +313,11 @@ def make_async_round(model, fed_cfg, pop_data, *, batch_size=32,
         bad = jnp.maximum(gated, rejected)
         if stateful_attack:
             # the attacker only observes its own cohort rows' outcome
-            att_carry = update_attack.observe(
-                att_carry,
-                jnp.zeros((m,), jnp.float32).at[owner_safe].max(
-                    bad * mask_pre))
+            with obs_annotate("update_attack"):
+                att_carry = update_attack.observe(
+                    att_carry,
+                    jnp.zeros((m,), jnp.float32).at[owner_safe].max(
+                        bad * mask_pre))
         store = clientstore.record_gate_trust(
             store, owners, mask_pre, bad, fed_cfg.trust_decay)
         # aggregation-trust EWMA for the cohort (compute-time scores)
@@ -450,6 +454,7 @@ def run_async(model, fed_cfg, pop_data, n_rounds, rng, *, eval_fn=None,
     att = update_attack if getattr(update_attack, "stateful", False) \
         else None
     state = init_async_state(params, fed_cfg, r_run, attacker=att)
+    eval_fn = scoped_eval(eval_fn)
     if telemetry is not None:
         telemetry.bind_engine("async")
         if telemetry.counters:
@@ -463,22 +468,21 @@ def run_async(model, fed_cfg, pop_data, n_rounds, rng, *, eval_fn=None,
 
     if driver == "python":
         round_jit = jax.jit(round_fn)
+        eval_jit = jax.jit(eval_fn) if eval_fn is not None else None
+        rec = getattr(telemetry, "tracer", None)
         history = []
         for t in range(1, n_rounds + 1):
             w0 = telemetry.now_us() if telemetry is not None else 0.0
-            state, metrics = round_jit(state, {})
-            row = {k: jax.device_get(v) for k, v in metrics.items()}
-            if eval_fn is not None:
-                row.update(jax.device_get(eval_fn(state.params)))
+            # device_get syncs every round under this driver, so the
+            # span measures the whole round
+            with span("round", rec, round=t):
+                state, metrics = round_jit(state, {})
+                row = {k: jax.device_get(v) for k, v in metrics.items()}
+                if eval_jit is not None:
+                    row.update(jax.device_get(eval_jit(state.params)))
             row["round"] = t
             if telemetry is not None:
-                # device_get above synced, so the window is a real
-                # per-round host measurement under this driver —
-                # measured=True emits it as a real (non-attributed)
-                # round span alongside the attributed phase split
-                telemetry.observe_rows([row], w0,
-                                       telemetry.now_us() - w0,
-                                       measured=True)
+                telemetry.observe_rows([row], w0, telemetry.now_us() - w0)
             history.append(row)
         return state, history
     if driver != "scan":

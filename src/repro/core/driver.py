@@ -41,6 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.obs.trace import span
+
 
 def chunk_sharding(batch_sharding):
     """Lift a per-batch ``NamedSharding`` tree to the stacked
@@ -116,54 +118,51 @@ class ScanDriver:
         its step index under ``index_key``.  ``on_chunk(state, rows)``
         fires after every chunk (logging / checkpoint hook).
 
-        ``telemetry`` (an ``repro.obs.Telemetry``) observes the drained
-        rows at the same boundary and — when tracing — gets the host-
-        MEASURED per-chunk window (dispatch -> drain; the existing
-        ``device_get`` is the sync point, so tracing adds none)."""
+        Each host phase of a chunk is one ``obs.trace.span``, with the
+        chunk's first step as arg ``first``: ``driver.stage`` (the next
+        chunk's ``batch_fn`` calls, stacking and ``device_put``),
+        ``driver.dispatch`` (the scan call), ``driver.drain`` (the one
+        ``device_get`` per chunk and the rows) and ``driver.hooks``
+        (``telemetry.observe_rows`` and ``on_chunk``).  ``telemetry``
+        (an ``repro.obs.Telemetry``) gets the rows with the chunk's
+        host window, dispatch through drain; its ``tracer``, when set,
+        records the spans too."""
         end = t0 + n_steps
+        rec = getattr(telemetry, "tracer", None)
 
         def steps_of(s0):
             return list(range(s0, min(s0 + self.chunk_steps, end)))
 
+        def stage(s0):
+            with span("driver.stage", rec, first=s0):
+                ts = steps_of(s0)
+                return (ts, *self.stage(batch_fn, ts))
+
         history = []
-        if telemetry is not None:
-            telemetry.begin("stage")
-        pending = (steps_of(t0), *self.stage(batch_fn, steps_of(t0))) \
-            if n_steps >= 1 else None
-        if telemetry is not None:
-            telemetry.end("stage", steps=len(pending[0]) if pending else 0)
+        pending = stage(t0) if n_steps >= 1 else None
         next_t0 = t0 + self.chunk_steps
         while pending is not None:
             ts, ts_dev, stacked = pending
+            first = ts[0]
             w0 = telemetry.now_us() if telemetry is not None else 0.0
             # dispatch is async: the scan runs while the next chunk stages
-            state, mets = self._scan(state, ts_dev, stacked)
-            if telemetry is not None and next_t0 < end:
-                telemetry.begin("stage")
-            pending = (steps_of(next_t0),
-                       *self.stage(batch_fn, steps_of(next_t0))) \
-                if next_t0 < end else None
-            if telemetry is not None and pending is not None:
-                telemetry.end("stage", steps=len(pending[0]))
+            with span("driver.dispatch", rec, first=first):
+                state, mets = self._scan(state, ts_dev, stacked)
+            pending = stage(next_t0) if next_t0 < end else None
             next_t0 += self.chunk_steps
-            mets = jax.device_get(mets)            # one sync per chunk
-            w1 = telemetry.now_us() if telemetry is not None else 0.0
-            rows = []
-            for j, t in enumerate(ts):
-                row = {k: v[j] for k, v in mets.items()}
-                row[index_key] = t
-                rows.append(row)
-            if telemetry is not None:
-                # the measured chunk window: scan dispatch through metric
-                # drain; per-round phase spans inside it are attributed
-                # (see obs/trace.py)
-                if telemetry.tracer is not None:
-                    telemetry.tracer.span(
-                        "chunk", w0, w1 - w0, tid=0,
-                        steps=len(ts), first=ts[0], last=ts[-1])
-                telemetry.observe_rows(rows, w0, w1 - w0)
-            if on_chunk is not None:
-                on_chunk(state, rows)
+            with span("driver.drain", rec, first=first):
+                mets = jax.device_get(mets)            # one sync per chunk
+                rows = []
+                for j, t in enumerate(ts):
+                    row = {k: v[j] for k, v in mets.items()}
+                    row[index_key] = t
+                    rows.append(row)
+            with span("driver.hooks", rec, first=first):
+                if telemetry is not None:
+                    telemetry.observe_rows(rows, w0,
+                                           telemetry.now_us() - w0)
+                if on_chunk is not None:
+                    on_chunk(state, rows)
             history.extend(rows)
         return state, history
 

@@ -32,7 +32,7 @@ from repro.core import aggregation, attacks, clientstore, \
     driver as scan_driver, fairness, faults as faults_mod, fitness, \
     selection, slots
 from repro.obs import counters as obs_counters
-from repro.obs.trace import annotate as obs_annotate
+from repro.obs.trace import annotate as obs_annotate, span
 
 
 class FedState(NamedTuple):
@@ -197,19 +197,21 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
             locals_, (gl, ga, ll, la) = jax.vmap(
                 client_update, in_axes=(None, 0, 0, 0))(state.params, data,
                                                         keys, eff_epochs)
-        updates = jax.tree_util.tree_map(
-            lambda w_k, w: w_k - w[None], locals_, state.params)
+            updates = jax.tree_util.tree_map(
+                lambda w_k, w: w_k - w[None], locals_, state.params)
 
         att_carry = state.attacker
         if update_attack is not None:
-            if stateful_attack:
-                # cross-round adaptive attacker: reads last round's gate
-                # outcome from the carry, re-tunes its blend, and hands
-                # back the adapted carry (completed after the gate below)
-                updates, att_carry = update_attack(
-                    updates, mal, r_upd, state.attacker)
-            else:
-                updates = update_attack(updates, mal, r_upd)
+            with obs_annotate("update_attack"):
+                if stateful_attack:
+                    # cross-round adaptive attacker: reads last round's
+                    # gate outcome from the carry, re-tunes its blend,
+                    # and hands back the adapted carry (completed after
+                    # the gate below)
+                    updates, att_carry = update_attack(
+                        updates, mal, r_upd, state.attacker)
+                else:
+                    updates = update_attack(updates, mal, r_upd)
 
         # ---- client->server transport (repro/comm/) ---------------------
         # the codec runs CLIENT-side, after the attacker corrupted its own
@@ -217,36 +219,40 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
         # only its measured bytes are billed.  EF residuals re-inject last
         # round's compression error before encoding.
         enc, new_ef = None, state.ef
-        if codec is not None:
-            enc, dec, new_ef = error_feedback.compress(
-                codec, updates, state.ef,
-                # fold_in, not split: the existing rng streams (and with
-                # them the compress="none" histories) stay untouched
-                rng=jax.random.fold_in(r_upd, 7) if codec.stochastic
-                else None)
-            bytes_up_pc = comm_codecs.wire_bytes_per_client(enc)
-            updates = dec
-        else:
-            bytes_up_pc = comm_codecs.dense_bytes_per_client(updates)
-        bytes_down_pc = comm_codecs.param_bytes(state.params)
+        with obs_annotate("codec"):
+            if codec is not None:
+                enc, dec, new_ef = error_feedback.compress(
+                    codec, updates, state.ef,
+                    # fold_in, not split: the existing rng streams (and
+                    # with them the compress="none" histories) stay
+                    # untouched
+                    rng=jax.random.fold_in(r_upd, 7) if codec.stochastic
+                    else None)
+                bytes_up_pc = comm_codecs.wire_bytes_per_client(enc)
+                updates = dec
+            else:
+                bytes_up_pc = comm_codecs.dense_bytes_per_client(updates)
+            bytes_down_pc = comm_codecs.param_bytes(state.params)
 
-        # ---- fitness ----------------------------------------------------
-        q = fitness.data_quality(data["n"], avail)
-        th = jnp.where(t == 1, jnp.zeros((K,)), fitness.theta(gl, ga, ll, la))
-
-        alpha = jnp.where(
-            jnp.array(fed_cfg.dynamic_alpha),
-            fitness.dynamic_alpha(q, th, avail), jnp.float32(fed_cfg.alpha))
-        scores = fitness.score(q, th, alpha)
-        if fed_cfg.trust_in_fitness:
-            # dynamic client scoring: the cosine-gate trust EWMA scales
-            # the fitness score, so repeatedly-gated clients stop being
-            # elected.  gate_trust is exactly 1.0 until someone is gated,
-            # keeping the fold behavior-preserving on clean runs.
-            scores = scores * state.gate_trust
-
-        # ---- selection (only when h(t): FFA/NAT rounds) ------------------
+        # ---- fitness election (only when h(t): FFA/NAT rounds) -----------
         with obs_annotate("selection"):
+            q = fitness.data_quality(data["n"], avail)
+            th = jnp.where(t == 1, jnp.zeros((K,)),
+                           fitness.theta(gl, ga, ll, la))
+
+            alpha = jnp.where(
+                jnp.array(fed_cfg.dynamic_alpha),
+                fitness.dynamic_alpha(q, th, avail),
+                jnp.float32(fed_cfg.alpha))
+            scores = fitness.score(q, th, alpha)
+            if fed_cfg.trust_in_fitness:
+                # dynamic client scoring: the cosine-gate trust EWMA
+                # scales the fitness score, so repeatedly-gated clients
+                # stop being elected.  gate_trust is exactly 1.0 until
+                # someone is gated, keeping the fold behavior-preserving
+                # on clean runs.
+                scores = scores * state.gate_trust
+
             if fed_cfg.algorithm == "fedfits":
                 new_team = selection.fedfits_select(
                     scores, fed_cfg.beta, avail, r_sel,
@@ -299,14 +305,14 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
         rejected = jnp.zeros((K,), jnp.float32)
         g_nonfinite = g_norm = jnp.float32(0.0)
         if guard_on:
-            if state.tele is not None:
-                # guard rejections split by kind — shares the guard's own
-                # reductions (CSE), a pure readout
-                nf, nr = aggregation.rejection_kinds(
-                    updates, (part > 0).astype(jnp.float32),
-                    norm_mult=fed_cfg.guard_norm_mult)
-                g_nonfinite, g_norm = nf.sum(), nr.sum()
             with obs_annotate("sanitize"):
+                if state.tele is not None:
+                    # guard rejections split by kind — shares the guard's
+                    # own reductions (CSE), a pure readout
+                    nf, nr = aggregation.rejection_kinds(
+                        updates, (part > 0).astype(jnp.float32),
+                        norm_mult=fed_cfg.guard_norm_mult)
+                    g_nonfinite, g_norm = nf.sum(), nr.sum()
                 updates, _, rejected = aggregation.sanitize_updates(
                     updates, (part > 0).astype(jnp.float32),
                     norm_mult=fed_cfg.guard_norm_mult)
@@ -348,35 +354,37 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
             new_params = jax.tree_util.tree_map(
                 lambda p, u: p + u.astype(p.dtype), state.params, agg)
 
-        # ---- slot & trust state ------------------------------------------
-        theta_team = fitness.team_theta(th, team)
-        new_slot, h_next = slots.update(state.slot, theta_team, t,
-                                        fed_cfg.msl, fed_cfg.pft)
-        new_trust = aggregation.update_trust(state.trust, scores, team,
-                                             fed_cfg.trust_decay)
+            # ---- slot & trust state --------------------------------------
+            theta_team = fitness.team_theta(th, team)
+            new_slot, h_next = slots.update(state.slot, theta_team, t,
+                                            fed_cfg.msl, fed_cfg.pft)
+            new_trust = aggregation.update_trust(state.trust, scores, team,
+                                                 fed_cfg.trust_decay)
 
-        # gate-trust EWMA (dynamic client scoring): participants whose
-        # update points AWAY from the round's robust aggregate (cosine
-        # below the gate threshold — the same rejection the Eq.-11
-        # cosine gate applies in-kernel) see their trust decay toward 0;
-        # clean participants decay toward 1, non-participants hold.
-        cos = aggregation.cosine_to_ref(updates, agg)
-        gated = ((cos < fed_cfg.cosine_outlier_thresh)
-                 & (part > 0)).astype(jnp.float32)
-        # guard rejections count as gate failures too: the EWMA runs
-        # over PRE-rejection participants so a rejected delivery decays
-        # trust exactly like a cosine-gated one (bad == gated when no
-        # row was rejected, so clean histories are bit-identical)
-        bad = jnp.maximum(gated, rejected)
-        new_gate_trust = jnp.where(
-            part_pre > 0,
-            fed_cfg.trust_decay * state.gate_trust
-            + (1.0 - fed_cfg.trust_decay) * (1.0 - bad),
-            state.gate_trust)
+            # gate-trust EWMA (dynamic client scoring): participants whose
+            # update points AWAY from the round's robust aggregate (cosine
+            # below the gate threshold — the same rejection the Eq.-11
+            # cosine gate applies in-kernel) see their trust decay toward
+            # 0; clean participants decay toward 1, non-participants hold.
+            cos = aggregation.cosine_to_ref(updates, agg)
+            gated = ((cos < fed_cfg.cosine_outlier_thresh)
+                     & (part > 0)).astype(jnp.float32)
+            # guard rejections count as gate failures too: the EWMA runs
+            # over PRE-rejection participants so a rejected delivery
+            # decays trust exactly like a cosine-gated one (bad == gated
+            # when no row was rejected, so clean histories are
+            # bit-identical)
+            bad = jnp.maximum(gated, rejected)
+            new_gate_trust = jnp.where(
+                part_pre > 0,
+                fed_cfg.trust_decay * state.gate_trust
+                + (1.0 - fed_cfg.trust_decay) * (1.0 - bad),
+                state.gate_trust)
         if stateful_attack:
             # complete the adaptive attacker's carry: it reads THIS
             # round's gate outcome next round
-            att_carry = update_attack.observe(att_carry, bad)
+            with obs_annotate("update_attack"):
+                att_carry = update_attack.observe(att_carry, bad)
 
         # cost accounting: FFA rounds bill every available client, slot
         # rounds the present team — PLUS, in both, the stale catch-up
@@ -415,18 +423,21 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
             }
             new_tele = obs_counters.accumulate(state.tele, vals, "sync")
             obs_metrics = obs_counters.metric_keys(vals)
-        new_clients = state.clients._replace(
-            # fitness EWMA at compute time (the population-store prior;
-            # the sync selection path keeps using the fresh scores, so
-            # this column is bookkeeping, not a behavior change)
-            fitness=fed_cfg.trust_decay * state.clients.fitness
-            + (1.0 - fed_cfg.trust_decay) * scores,
-            trust=new_trust,
-            gate_trust=new_gate_trust,
-            staleness=jnp.where(part > 0, 0, state.clients.staleness + 1),
-            failures=state.clients.failures + rejected,
-            cum_selected=state.clients.cum_selected + team,
-            ef=new_ef)
+        with obs_annotate("writeback"):
+            new_clients = state.clients._replace(
+                # fitness EWMA at compute time (the population-store
+                # prior; the sync selection path keeps using the fresh
+                # scores, so this column is bookkeeping, not a behavior
+                # change)
+                fitness=fed_cfg.trust_decay * state.clients.fitness
+                + (1.0 - fed_cfg.trust_decay) * scores,
+                trust=new_trust,
+                gate_trust=new_gate_trust,
+                staleness=jnp.where(part > 0, 0,
+                                    state.clients.staleness + 1),
+                failures=state.clients.failures + rejected,
+                cum_selected=state.clients.cum_selected + team,
+                ef=new_ef)
         new_state = FedState(
             params=new_params, team=team, alpha=alpha,
             slot=new_slot, h=h_next, rng=rng, round=t + 1,
@@ -454,6 +465,19 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
     return round_fn
 
 
+def scoped_eval(eval_fn):
+    """``eval_fn`` with its ops under the device scope ``server_eval``
+    (None stays None)."""
+    if eval_fn is None:
+        return None
+
+    def scoped(params):
+        with obs_annotate("server_eval"):
+            return eval_fn(params)
+
+    return scoped
+
+
 def run(model, fed_cfg, data_fn, n_rounds, rng, *, eval_fn=None,
         data_attack=None, update_attack=None, malicious=None,
         faults=None, driver="scan", chunk_rounds=8, telemetry=None):
@@ -471,6 +495,7 @@ def run(model, fed_cfg, data_fn, n_rounds, rng, *, eval_fn=None,
     testing."""
     r_init, r_run = jax.random.split(rng)
     params = model.init(r_init)
+    eval_fn = scoped_eval(eval_fn)
     att = update_attack if getattr(update_attack, "stateful", False) else None
     state = init_state(params, fed_cfg.n_clients, fed_cfg, r_run,
                        attacker=att)
@@ -486,6 +511,8 @@ def run(model, fed_cfg, data_fn, n_rounds, rng, *, eval_fn=None,
 
     if driver == "python":
         round_jit = jax.jit(round_fn)
+        eval_jit = jax.jit(eval_fn) if eval_fn is not None else None
+        rec = getattr(telemetry, "tracer", None)
         history = []
         for t in range(1, n_rounds + 1):
             batch = dict(data_fn(t, jax.random.fold_in(rng, t)))
@@ -498,19 +525,16 @@ def run(model, fed_cfg, data_fn, n_rounds, rng, *, eval_fn=None,
                 a = a.at[0].set(1.0)               # never an empty round
                 batch["avail"] = a if t > 1 else jnp.ones((K,), jnp.float32)
             w0 = telemetry.now_us() if telemetry is not None else 0.0
-            state, metrics = round_jit(state, batch)
-            row = {k: jax.device_get(v) for k, v in metrics.items()}
-            if eval_fn is not None:
-                row.update(jax.device_get(eval_fn(state.params)))
+            # device_get syncs every round under this driver, so the
+            # span measures the whole round
+            with span("round", rec, round=t):
+                state, metrics = round_jit(state, batch)
+                row = {k: jax.device_get(v) for k, v in metrics.items()}
+                if eval_jit is not None:
+                    row.update(jax.device_get(eval_jit(state.params)))
             row["round"] = t
             if telemetry is not None:
-                # device_get above synced, so the window is a real
-                # per-round host measurement under this driver —
-                # measured=True emits it as a real (non-attributed)
-                # round span alongside the attributed phase split
-                telemetry.observe_rows([row], w0,
-                                       telemetry.now_us() - w0,
-                                       measured=True)
+                telemetry.observe_rows([row], w0, telemetry.now_us() - w0)
             history.append(row)
         return state, history
     if driver != "scan":

@@ -44,6 +44,7 @@ from repro.comm import codecs as comm_codecs, error_feedback
 from repro.core import aggregation, driver as scan_driver, fitness, \
     selection, slots
 from repro.models import transformer
+from repro.obs.trace import span
 from repro.optim import optimizers
 
 
@@ -355,14 +356,16 @@ def run(state, train_step, batch_fn, n_rounds, *, driver="scan",
     if driver == "python":
         step_jit = jax.jit(train_step, donate_argnums=(0,))
         put_sharding = batch_sharding
+        rec = getattr(telemetry, "tracer", None)
         history = []
         for t in range(t0, t0 + n_rounds):
             batch = dict(batch_fn(t))
             if put_sharding is not None:
                 batch = jax.device_put(batch, put_sharding)
             w0 = telemetry.now_us() if telemetry is not None else 0.0
-            state, metrics = step_jit(state, batch)
-            row = {k: jax.device_get(v) for k, v in metrics.items()}
+            with span("round", rec, round=t):
+                state, metrics = step_jit(state, batch)
+                row = {k: jax.device_get(v) for k, v in metrics.items()}
             row["step"] = t
             if telemetry is not None:
                 telemetry.observe_rows([row], w0, telemetry.now_us() - w0)

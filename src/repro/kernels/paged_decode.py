@@ -131,10 +131,12 @@ def paged_flash_decode(q, kp, vp, table, lengths, *, k_scale=None,
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32)],
     )
-    out = pl.pallas_call(
-        functools.partial(_kernel, page=page, maxp=maxp, int8=int8),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hkv, g, dh), jnp.float32),
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("paged_decode"):
+        out = pl.pallas_call(
+            functools.partial(_kernel, page=page, maxp=maxp, int8=int8),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s, hkv, g, dh), jnp.float32),
+            name="paged_decode",
+            interpret=interpret,
+        )(*args)
     return out.reshape(s, hq, dh)

@@ -256,16 +256,18 @@ def cosine_gate_partials(x, mask, *, blk=4096, interpret=False):
             pl.BlockSpec((1, 1), lambda g, i, n: (g, 0)),
         ],
     )
-    dots, sqn, refsq = pl.pallas_call(
-        functools.partial(_pass1_body, c=C),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(n_sel, x, mask.reshape(G, C, 1))
+    with jax.named_scope("robust_pass1"):
+        dots, sqn, refsq = pl.pallas_call(
+            functools.partial(_pass1_body, c=C),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, 1), jnp.float32),
+            ],
+            name="robust_pass1",
+            interpret=interpret,
+        )(n_sel, x, mask.reshape(G, C, 1))
     return dots, sqn, refsq
 
 
@@ -320,16 +322,18 @@ def cosine_gate_partials_leafwise(leaves, mask, *, blk, leaf_scale,
             pl.BlockSpec((1, 1), lambda g, i, *_: (g, 0)),
         ],
     )
-    dots, sqn, refsq = pl.pallas_call(
-        functools.partial(_pass1_leaf_body, segs=segs, total=total, c=C),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(n_sel, leaf_scale, *leaves, mask.reshape(G, C, 1))
+    with jax.named_scope("robust_pass1"):
+        dots, sqn, refsq = pl.pallas_call(
+            functools.partial(_pass1_leaf_body, segs=segs, total=total, c=C),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, 1), jnp.float32),
+            ],
+            name="robust_pass1",
+            interpret=interpret,
+        )(n_sel, leaf_scale, *leaves, mask.reshape(G, C, 1))
     return dots, sqn, refsq
 
 
@@ -385,12 +389,15 @@ def gated_combine(x, gated_mask, weights, *, mode, trim_frac=0.2, blk=4096,
         ],
         out_specs=pl.BlockSpec((1, 1, blk), lambda g, i, n: (g, 0, i)),
     )
-    out = pl.pallas_call(
-        functools.partial(_pass2_body, c=C, mode=mode, trim_frac=trim_frac),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, 1, N), jnp.float32),
-        interpret=interpret,
-    )(n_sel, x, gated_mask.reshape(G, C, 1), weights.reshape(G, C, 1))
+    with jax.named_scope("robust_pass2"):
+        out = pl.pallas_call(
+            functools.partial(_pass2_body, c=C, mode=mode,
+                              trim_frac=trim_frac),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((G, 1, N), jnp.float32),
+            name="robust_pass2",
+            interpret=interpret,
+        )(n_sel, x, gated_mask.reshape(G, C, 1), weights.reshape(G, C, 1))
     return out[:, 0]
 
 
@@ -434,14 +441,17 @@ def gated_combine_leafwise(leaves, gated_mask, weights, *, mode,
         out_specs=[pl.BlockSpec((1, 1, seg.blk), _seg_index_map(seg))
                    for seg in segs],
     )
-    outs = pl.pallas_call(
-        functools.partial(_pass2_leaf_body, segs=segs, total=total, c=C,
-                          mode=mode, trim_frac=trim_frac),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((G, 1, seg.n), dt)
-                   for seg, dt in zip(segs, out_dtypes)],
-        interpret=interpret,
-    )(n_sel, *leaves, gated_mask.reshape(G, C, 1), weights.reshape(G, C, 1))
+    with jax.named_scope("robust_pass2"):
+        outs = pl.pallas_call(
+            functools.partial(_pass2_leaf_body, segs=segs, total=total, c=C,
+                              mode=mode, trim_frac=trim_frac),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((G, 1, seg.n), dt)
+                       for seg, dt in zip(segs, out_dtypes)],
+            name="robust_pass2",
+            interpret=interpret,
+        )(n_sel, *leaves, gated_mask.reshape(G, C, 1),
+          weights.reshape(G, C, 1))
     return [o[:, 0] for o in outs]
 
 
@@ -469,20 +479,22 @@ def pairwise_sq_dists_blocked(x, mask, *, blk=4096, interpret=False):
     contract as ``aggregation.pairwise_sq_dists``)."""
     G, C, N = x.shape
     assert N % blk == 0, (N, blk)
-    gram, sqn = pl.pallas_call(
-        functools.partial(_pairwise_body, c=C),
-        grid=(G, N // blk),
-        in_specs=[pl.BlockSpec((1, C, blk), lambda g, i: (g, 0, i))],
-        out_specs=[
-            pl.BlockSpec((1, C, C), lambda g, i: (g, 0, 0)),
-            pl.BlockSpec((1, C), lambda g, i: (g, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((G, C, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x)
+    with jax.named_scope("robust_gram"):
+        gram, sqn = pl.pallas_call(
+            functools.partial(_pairwise_body, c=C),
+            grid=(G, N // blk),
+            in_specs=[pl.BlockSpec((1, C, blk), lambda g, i: (g, 0, i))],
+            out_specs=[
+                pl.BlockSpec((1, C, C), lambda g, i: (g, 0, 0)),
+                pl.BlockSpec((1, C), lambda g, i: (g, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((G, C, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+            ],
+            name="robust_gram",
+            interpret=interpret,
+        )(x)
     d = sqn[:, :, None] + sqn[:, None, :] - 2.0 * gram
     big = _BIG * (1.0 - mask[:, :, None] * mask[:, None, :])
     return jnp.maximum(d, 0.0) + big
@@ -529,15 +541,18 @@ def pairwise_sq_dists_leafwise(leaves, mask, *, blk, leaf_scale,
             pl.BlockSpec((1, C), lambda g, i, *_: (g, 0)),
         ],
     )
-    gram, sqn = pl.pallas_call(
-        functools.partial(_pairwise_leaf_body, segs=segs, total=total, c=C),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((G, C, C), jnp.float32),
-            jax.ShapeDtypeStruct((G, C), jnp.float32),
-        ],
-        interpret=interpret,
-    )(leaf_scale, *leaves)
+    with jax.named_scope("robust_gram"):
+        gram, sqn = pl.pallas_call(
+            functools.partial(_pairwise_leaf_body, segs=segs, total=total,
+                              c=C),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((G, C, C), jnp.float32),
+                jax.ShapeDtypeStruct((G, C), jnp.float32),
+            ],
+            name="robust_gram",
+            interpret=interpret,
+        )(leaf_scale, *leaves)
     if axis_name is not None:
         gram = jax.lax.psum(gram, axis_name)
         sqn = jax.lax.psum(sqn, axis_name)

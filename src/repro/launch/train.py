@@ -157,10 +157,11 @@ def main():
                          "--scenario")
     ap.add_argument("--trace", default=None, metavar="OUT_JSON",
                     help="write a Chrome/Perfetto trace-event JSON for "
-                         "the run (repro/obs/trace.py): measured driver "
-                         "spans at chunk granularity plus attributed "
-                         "per-round phase spans carrying each round's "
-                         "counter values. Load in ui.perfetto.dev; "
+                         "the run (repro/obs/trace.py): the measured host "
+                         "spans (driver.stage/dispatch/drain/hooks per "
+                         "chunk, or one round span per round under "
+                         "--driver python) on the profiler's clock, and "
+                         "counter tracks. Load in ui.perfetto.dev; "
                          "validate with python -m repro.obs.check")
     ap.add_argument("--telemetry-jsonl", default=None, metavar="OUT_JSONL",
                     help="stream the obs metric rows + drift-monitor "

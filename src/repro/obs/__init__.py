@@ -8,9 +8,10 @@
     column + ``obs/`` metric keys (see obs/counters.py) — a pure
     readout, bit-parity preserving;
   * the **trace recorder** (``trace_path=...``): Perfetto trace-event
-    JSON with measured driver spans and attributed per-round phase
-    spans (see obs/trace.py), plus the ``profiler_dir`` escape hatch
-    wrapping the run in ``jax.profiler.trace``;
+    JSON with the measured host spans the drivers and the serving loop
+    open through ``obs.trace.span`` and the gauges' counter tracks (see
+    obs/trace.py), plus ``profiler_dir``, which wraps the run in
+    ``jax.profiler.trace``;
   * the **sink stream + drift monitors**: every drained row becomes a
     ``kind="metrics"`` record, every monitor trip a ``kind="warning"``
     record, fanned to the configured sinks (see obs/sinks.py,
@@ -21,22 +22,21 @@ telemetry adds zero host syncs and zero device ops that feed the model.
 """
 from __future__ import annotations
 
-import time
-from typing import List, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 from repro.obs import counters, monitors as monitors_mod, sinks as sinks_mod
 from repro.obs.counters import METRIC_PREFIX
 from repro.obs.monitors import Monitor, MonitorBank, default_monitors
 from repro.obs.sinks import (JsonlSink, MemorySink, MultiSink, Sink,
                              StdoutSink, jsonable)
-from repro.obs.trace import (PHASE_NAMES, TraceRecorder, annotate,
-                             phase_weights, profiler_session)
+from repro.obs.trace import (TraceRecorder, annotate, now_us,
+                             profiler_session, span)
 
 __all__ = [
     "Telemetry", "Monitor", "MonitorBank", "default_monitors",
     "Sink", "JsonlSink", "MemorySink", "MultiSink", "StdoutSink",
-    "TraceRecorder", "annotate", "profiler_session", "jsonable",
-    "PHASE_NAMES", "METRIC_PREFIX", "counters",
+    "TraceRecorder", "annotate", "span", "profiler_session", "jsonable",
+    "METRIC_PREFIX", "counters",
 ]
 
 
@@ -64,30 +64,26 @@ class Telemetry:
         self.tracer: Optional[TraceRecorder] = (
             TraceRecorder() if trace_path else None)
         self.rows_seen = 0
+        self._open: Dict[str, span] = {}
         self._finished = False
 
     # -- engine hooks --------------------------------------------------
     def bind_engine(self, engine: str) -> "Telemetry":
-        """Called by the consuming run(): fixes the engine's phase
-        weights and counter slice."""
+        """Called by the consuming run(): names the engine in records
+        and in the trace."""
         self.engine = engine
         if self.tracer is not None:
             self.tracer.engine = engine
-            self.tracer._weights = phase_weights(engine)
         return self
 
     def observe_rows(self, rows: Sequence[dict],
                      window_start_us: Optional[float] = None,
-                     window_dur_us: Optional[float] = None, *,
-                     measured: bool = False,
-                     phases: bool = True) -> None:
-        """Drain boundary: one call per chunk (scan) or round (python).
-        Emits metrics records, runs monitors, and — when tracing —
-        attributes the measured window across rounds and phases.
-        ``measured=True`` marks the window as one real host measurement
-        per row (python driver, serving engine): each round gets a
-        measured ``round`` span; ``phases=False`` skips the attributed
-        phase split (see TraceRecorder.emit_rounds)."""
+                     window_dur_us: Optional[float] = None) -> None:
+        """Drain boundary: one call per chunk (scan), round (python) or
+        decode step (serving), with the host window (on :meth:`now_us`'s
+        clock) that ended in the drain.  Emits metrics records, runs
+        monitors, and — when tracing — stamps the rows' gauges as
+        counter events at the drain."""
         rows = list(rows)
         if not rows:
             return
@@ -103,26 +99,22 @@ class Telemetry:
                 w["run"] = self.run_name
                 self.sink.emit(w)
         if self.tracer is not None:
-            if window_dur_us is None:
-                # no measured window handed in (python driver emits per
-                # round); synthesize a zero-cost marker window
-                window_start_us = self.tracer.now_us()
-                window_dur_us = float(len(rows))
-            self.tracer.emit_rounds(window_start_us, window_dur_us, rows,
-                                    measured=measured, phases=phases)
+            drain = (window_start_us + window_dur_us
+                     if window_dur_us is not None else now_us())
+            self.tracer.counters(rows, drain)
 
-    # driver-measured spans pass straight through to the recorder
-    def begin(self, name: str) -> None:
-        if self.tracer is not None:
-            self.tracer.begin(name)
+    # host spans opened and closed by name, through obs.trace.span
+    def begin(self, name: str, **args) -> None:
+        self._open[name] = sp = span(name, self.tracer, **args)
+        sp.__enter__()
 
-    def end(self, name: str, **args) -> None:
-        if self.tracer is not None:
-            self.tracer.end(name, **args)
+    def end(self, name: str) -> None:
+        sp = self._open.pop(name, None)
+        if sp is not None:
+            sp.__exit__(None, None, None)
 
     def now_us(self) -> float:
-        return self.tracer.now_us() if self.tracer is not None else \
-            time.perf_counter() * 1e6
+        return now_us()
 
     # -- lifecycle -----------------------------------------------------
     def profiled(self):

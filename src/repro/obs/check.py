@@ -2,15 +2,16 @@
 gate, also used by tests/test_obs.py).
 
   PYTHONPATH=src python -m repro.obs.check \
-      --trace out.json --jsonl metrics.jsonl [--min-phases 5] \
+      --trace out.json --jsonl metrics.jsonl \
+      [--spans driver.stage,driver.dispatch,driver.drain,driver.hooks] \
       [--require-obs] [--engine async]
 
 Validates that
 
   * the trace file is Chrome/Perfetto-loadable trace-event JSON (a
-    ``traceEvents`` list of complete "X" events with name/ts/dur), and
-    that every round on the round track carries at least
-    ``--min-phases`` DISTINCT phase spans (the acceptance bar is 5);
+    ``traceEvents`` list of complete "X" events with name/ts/dur and
+    counter "C" events), and that every name in ``--spans`` appears as
+    a span;
   * the JSONL stream is one JSON object per line with a known ``kind``
     (metrics | warning | summary), metrics rows carry a round/step
     index, and — with ``--require-obs`` — the registered counters of
@@ -23,15 +24,14 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 from repro.obs import counters as obs_counters
-from repro.obs.trace import PHASE_NAMES
 
 KINDS = {"metrics", "warning", "summary"}
 
 
-def check_trace(trace, *, min_phases: int = 5) -> List[str]:
+def check_trace(trace, *, spans: Sequence[str] = ()) -> List[str]:
     """Validate a trace-event dict (or path); returns finding strings."""
     errs: List[str] = []
     if isinstance(trace, str):
@@ -43,35 +43,23 @@ def check_trace(trace, *, min_phases: int = 5) -> List[str]:
     evs = trace.get("traceEvents")
     if not isinstance(evs, list) or not evs:
         return ["trace: no traceEvents list"]
-    per_round: dict = {}
-    measured_rounds: set = set()
+    seen: set = set()
     for i, e in enumerate(evs):
         for field in ("name", "ph", "ts", "pid", "tid"):
             if field not in e:
                 errs.append(f"trace: event {i} missing {field!r}")
                 break
         else:
-            if e["ph"] == "X" and ("dur" not in e or e["dur"] <= 0):
-                errs.append(
-                    f"trace: event {i} ({e['name']}) X-phase without "
-                    "positive dur")
-            args = e.get("args", {})
-            if e["name"] in PHASE_NAMES and "round" in args:
-                per_round.setdefault(args["round"], set()).add(e["name"])
-            elif e["name"] == "round" and "round" in args:
-                # measured per-round span (python driver / serving
-                # engine) — counts as round coverage without a phase
-                # split
-                measured_rounds.add(args["round"])
-    if not per_round and not measured_rounds:
-        errs.append("trace: no per-round spans (expected phase names "
-                    f"from {list(PHASE_NAMES)} or measured 'round' "
-                    "spans)")
-    for rnd, names in sorted(per_round.items()):
-        if len(names) < min_phases:
-            errs.append(
-                f"trace: round {rnd} has {len(names)} distinct phase "
-                f"spans ({sorted(names)}), need >= {min_phases}")
+            if e["ph"] == "X":
+                if "dur" not in e or e["dur"] <= 0:
+                    errs.append(
+                        f"trace: event {i} ({e['name']}) X-phase without "
+                        "positive dur")
+                seen.add(e["name"])
+    missing = [n for n in spans if n not in seen]
+    if missing:
+        errs.append(f"trace: no measured span named {missing} (spans "
+                    f"present: {sorted(seen)})")
     return errs
 
 
@@ -130,7 +118,9 @@ def main(argv=None) -> int:
         description="Schema-check telemetry trace/JSONL artifacts")
     ap.add_argument("--trace", default=None)
     ap.add_argument("--jsonl", default=None)
-    ap.add_argument("--min-phases", type=int, default=5)
+    ap.add_argument("--spans", default="",
+                    help="comma-separated span names that must appear "
+                         "in the trace as measured spans")
     ap.add_argument("--require-obs", action="store_true",
                     help="metrics rows must carry every registered "
                          "counter of --engine")
@@ -141,7 +131,8 @@ def main(argv=None) -> int:
         ap.error("nothing to check: pass --trace and/or --jsonl")
     errs: List[str] = []
     if args.trace:
-        errs += check_trace(args.trace, min_phases=args.min_phases)
+        errs += check_trace(args.trace, spans=[
+            n for n in args.spans.split(",") if n])
     if args.jsonl:
         errs += check_jsonl(args.jsonl, require_obs=args.require_obs,
                             engine=args.engine)

@@ -1,68 +1,49 @@
-"""Phase-level trace emitter: Chrome/Perfetto trace-event JSON.
+"""Spans, scopes and the Chrome/Perfetto trace-event recorder.
 
-Rounds run *inside* one jitted ``lax.scan`` chunk, so the host cannot
-clock individual phases without breaking the 1-host-sync-per-chunk
-contract.  The emitter is therefore two-tier and honest about which
-tier is which:
+Every span here is a measurement of the host or a scope on the device.
 
-  * **measured spans** — the ScanDriver (and the python-driver loops)
-    wall-clock what the host can actually see: per-chunk ``stage`` /
-    ``compute`` / ``drain`` spans, and — under the python driver and
-    the serving engine, which both sync once per round/step — a
-    measured per-round ``round`` span (``emit_rounds(measured=True)``;
-    no ``attributed`` flag, the boundaries are real ``perf_counter``
-    timestamps).
-  * **attributed spans** — inside a chunk, each round's window is split
-    into the engine's phase sequence (selection → client_update →
-    delivery → sanitize → aggregate → writeback) by the static weight
-    tables below.  The span BOUNDARIES are attribution, not
-    measurement — ``args.attributed`` marks them — but each span's
-    ``args`` carry that round's REAL drained counter values
-    (``obs/...`` metrics), so the trace still answers "what did the
-    gate/buffer/aggregator do in round t".
+  * :func:`annotate` is ``jax.named_scope`` alone.  Inside jitted round
+    bodies it names the ops of one phase (``client_update``,
+    ``update_attack``, ``codec``, ``selection``, ``sanitize``,
+    ``aggregate``, ``writeback``, ``server_eval``), so the device plane
+    of a ``jax.profiler`` trace, the lowered HLO's ``op_name`` and the
+    analysis linter all carry the phase.
+  * :class:`span` times one host phase of a driver loop
+    (``driver.stage`` / ``.dispatch`` / ``.drain`` / ``.hooks``,
+    ``serve.admit`` / ``.decode`` / ``.bookkeep``, a python driver's
+    ``round``).  It always opens a ``jax.profiler.TraceAnnotation``, so
+    a running profiler puts the span on the host plane of its
+    ``.xplane.pb`` beside the device ops; handed a
+    :class:`TraceRecorder`, it also records the span there.  With no
+    profiler running a span costs about a microsecond and never syncs.
+  * :class:`TraceRecorder` writes ``{"traceEvents": [...]}`` for
+    ``--trace``: the spans recorded through :class:`span` and the
+    registered scalar gauges as counter ("C") events.  Its timestamps
+    are :func:`now_us`, the profiler's host clock, so a recorded span
+    lands where the profiler put the same span (a profile's times are
+    offsets from its ``profile_start_time``, on the same clock).
 
-For ground-truth device timings use the escape hatch: pass
-``profiler_dir`` to :class:`Telemetry` (``--profile-dir`` on the
-launcher) and the whole run is wrapped in ``jax.profiler.trace`` —
-XLA-level timelines, at XLA-level volume.
-
-Inside jit, :func:`annotate` stacks ``jax.named_scope`` (names the ops
-in jaxprs/HLO, so profiler traces and the analysis linter see phase
-names) with ``jax.profiler.TraceAnnotation`` when a profiler is active.
+For device timings pass ``profiler_dir`` to ``Telemetry``
+(``--profile-dir`` on the launchers): the run is wrapped in
+``jax.profiler.trace``.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
 import jax
 
-# The canonical phase sequence (name, sync weight, async weight).
-# Weights are the static attribution split of a round's window; they
-# are documentation-grade estimates (client_update dominates: it is the
-# vmapped local-epochs loop), not measurements — see module docstring.
-PHASES: Tuple[Tuple[str, float, float], ...] = (
-    ("selection", 0.05, 0.08),
-    ("client_update", 0.60, 0.52),
-    ("delivery", 0.05, 0.12),
-    ("sanitize", 0.05, 0.05),
-    ("aggregate", 0.15, 0.13),
-    ("writeback", 0.10, 0.10),
-)
 
-PHASE_NAMES: Tuple[str, ...] = tuple(p[0] for p in PHASES)
+def now_us() -> float:
+    """The profiler's host clock (the wall clock, which stamps the host
+    and device planes of a ``jax.profiler`` trace), in microseconds."""
+    return time.time_ns() / 1e3
 
 
-def phase_weights(engine: str) -> Dict[str, float]:
-    col = 1 if engine == "sync" else 2
-    w = {p[0]: p[col] for p in PHASES}
-    total = sum(w.values())
-    return {k: v / total for k, v in w.items()}
-
-
-def counter_tracks() -> Tuple[str, ...]:
+def counter_tracks():
     """The registered scalar gauges exported as Perfetto counter ("C")
     tracks: the async buffer occupancy plus every serve/* gauge."""
     from repro.obs import counters as obs_counters
@@ -72,117 +53,81 @@ def counter_tracks() -> Tuple[str, ...]:
         and (n == "buffer/occupancy" or n.startswith("serve/")))
 
 
-@contextlib.contextmanager
 def annotate(name: str):
-    """Phase annotation inside jitted round bodies: names the ops for
+    """Phase scope inside jitted round bodies: names the ops for
     jaxpr/HLO/profiler consumers.  Pure metadata — no ops are added, so
     telemetry-on stays bit-identical."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.named_scope(name)
+
+
+class span:
+    """``with span(name, recorder, **args):`` — one measured host span.
+
+    Opens ``jax.profiler.TraceAnnotation(name, **args)`` (the args become
+    the event's stats in the profile) and, when ``recorder`` is not
+    None, records the same span there on :func:`now_us`'s clock."""
+
+    __slots__ = ("_ann", "_rec", "_name", "_args", "_t0")
+
+    def __init__(self, name: str, recorder: Optional["TraceRecorder"] = None,
+                 **args):
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+        self._rec = recorder
+        self._name = name
+        self._args = args
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = now_us()
+        return self
+
+    def __exit__(self, *exc):
+        if self._rec is not None:
+            t1 = now_us()
+            self._rec.record(self._name, self._t0, t1 - self._t0,
+                             **self._args)
+        return self._ann.__exit__(*exc)
 
 
 class TraceRecorder:
-    """Collects trace events and writes ``{"traceEvents": [...]}``.
-
-    Events use the Chrome trace-event "X" (complete) phase with
-    microsecond timestamps; ``pid`` groups engines, ``tid`` separates
-    the driver track (0) from the round track (1).
-    """
-
-    DRIVER_TID = 0
-    ROUND_TID = 1
+    """Collects trace events and writes ``{"traceEvents": [...]}``:
+    complete ("X") events for measured spans and counter ("C") events,
+    with microsecond timestamps on the profiler's host clock."""
 
     def __init__(self, engine: str = "sync"):
         self.engine = engine
         self.events: List[dict] = []
-        self._t0 = time.perf_counter()
-        self._weights = phase_weights(engine)
-        self._open: Dict[str, float] = {}
 
-    # -- measured spans (host wall clock) -----------------------------
-    def now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
-
-    def begin(self, name: str) -> None:
-        self._open[name] = self.now_us()
-
-    def end(self, name: str, **args) -> None:
-        start = self._open.pop(name, None)
-        if start is None:
-            return
-        self.span(name, start, self.now_us() - start,
-                  tid=self.DRIVER_TID, **args)
-
-    def span(self, name: str, ts_us: float, dur_us: float, *,
-             tid: int = 0, **args) -> None:
+    def record(self, name: str, ts_us: float, dur_us: float,
+               **args) -> None:
         self.events.append({
-            "name": name, "ph": "X", "pid": 0, "tid": tid,
-            "ts": ts_us, "dur": max(dur_us, 0.01),
-            "args": args,
+            "name": name, "ph": "X", "pid": 0, "tid": 0,
+            "ts": ts_us, "dur": max(dur_us, 0.01), "args": args,
         })
 
-    # -- per-round spans (measured and/or attributed) -----------------
-    def emit_rounds(self, window_start_us: float, window_dur_us: float,
-                    rows: Sequence[dict], *, measured: bool = False,
-                    phases: bool = True) -> None:
-        """Split a measured window (one chunk, or one python-driver
-        round) across its rounds and each round across the engine's
-        phases.  ``rows`` are the drained history rows; each phase span
-        carries the round's real ``obs/`` counters in ``args``.
-
-        measured=True: the window IS one real host measurement per row
-        (python driver, serving engine), so each round additionally
-        gets a measured ``round`` span — real timestamps, no
-        ``attributed`` flag.  phases=False drops the attributed phase
-        split entirely (the serving engine has no FL phase sequence).
-        Scalar gauges from :func:`counter_tracks` are always exported
-        as Perfetto counter ("C") events at each round's start."""
-        if not rows:
-            return
+    def counters(self, rows: Sequence[dict], ts_us: float) -> None:
+        """The drained rows' scalar gauges (:func:`counter_tracks`) as
+        counter events, stamped at ``ts_us``: the drain that made the
+        rows visible to the host."""
         tracks = counter_tracks()
-        per_round = window_dur_us / len(rows)
-        for j, row in enumerate(rows):
-            r0 = window_start_us + j * per_round
-            rnd = row.get("round", row.get("step", j))
-            obs = {k: _num(v) for k, v in row.items()
-                   if isinstance(k, str) and k.startswith("obs/")}
-            if measured:
-                self.span("round", r0, per_round, tid=self.ROUND_TID,
-                          round=_num(rnd), **obs)
+        for row in rows:
             for name in tracks:
-                v = obs.get("obs/" + name)
-                if isinstance(v, (int, float)):
+                v = row.get("obs/" + name)
+                if v is not None:
                     self.events.append({
-                        "name": name, "ph": "C", "pid": 0,
-                        "tid": self.ROUND_TID, "ts": r0,
-                        "args": {"value": v}})
-            if not phases:
-                continue
-            off = 0.0
-            for name in PHASE_NAMES:
-                dur = per_round * self._weights[name]
-                self.span(name, r0 + off, dur, tid=self.ROUND_TID,
-                          round=_num(rnd), attributed=True, **obs)
-                off += dur
+                        "name": name, "ph": "C", "pid": 0, "tid": 1,
+                        "ts": ts_us, "args": {"value": float(v)}})
 
     def to_json(self) -> dict:
         return {"traceEvents": list(self.events),
                 "displayTimeUnit": "ms",
-                "otherData": {"engine": self.engine,
-                              "phase_weights": self._weights}}
+                "otherData": {"engine": self.engine}}
 
     def save(self, path: str) -> str:
         with open(path, "w") as f:
             json.dump(self.to_json(), f)
         return path
-
-
-def _num(v):
-    try:
-        f = float(v)
-    except (TypeError, ValueError):
-        return str(v)
-    return int(f) if f == int(f) else f
 
 
 @contextlib.contextmanager

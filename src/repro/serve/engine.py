@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.models import attention as attn_lib
 from repro.models import transformer
 from repro.obs import counters as obs_counters
+from repro.obs.trace import span
 from repro.serve import scheduler as sched
 from repro.serve.scheduler import (HostLedger, Request, ServeConfig,
                                    SlotState)
@@ -246,6 +247,7 @@ class ServeEngine:
         steps = 0
         total_emitted = 0
         admitted_since = 0
+        rec = getattr(telemetry, "tracer", None)
         t0 = time.perf_counter()
         while pending or ledger.n_active > 0:
             group_open = ledger.n_active == 0
@@ -257,57 +259,61 @@ class ServeEngine:
                 if not continuous and not group_open:
                     break
                 pending.pop(0)
-                want_slot = ledger.next_slot()
-                prompt = jnp.zeros((scfg.prompt_pad,), jnp.int32) \
-                    .at[:len(r.tokens)].set(jnp.asarray(r.tokens,
-                                                        jnp.int32))
-                cache, st, out = self._admit(
-                    self.params, cache, st, prompt,
-                    jnp.int32(len(r.tokens)), jnp.int32(r.max_new),
-                    jnp.int32(r.req_id))
-                out = jax.device_get(out)
-                if not bool(out["ok"]) or int(out["slot"]) != want_slot:
-                    raise RuntimeError(
-                        f"scheduler mirror diverged on req {r.req_id}: "
-                        f"device ok={bool(out['ok'])} "
-                        f"slot={int(out['slot'])}, host slot={want_slot}")
-                results[r.req_id].append(int(out["tok0"]))
-                total_emitted += 1
-                admitted_since += 1
-                if r.max_new >= 2:
-                    ledger.admit_at(want_slot, need)
+                with span("serve.admit", rec, req_id=r.req_id):
+                    want_slot = ledger.next_slot()
+                    prompt = jnp.zeros((scfg.prompt_pad,), jnp.int32) \
+                        .at[:len(r.tokens)].set(jnp.asarray(r.tokens,
+                                                            jnp.int32))
+                    cache, st, out = self._admit(
+                        self.params, cache, st, prompt,
+                        jnp.int32(len(r.tokens)), jnp.int32(r.max_new),
+                        jnp.int32(r.req_id))
+                    out = jax.device_get(out)
+                    if not bool(out["ok"]) or int(out["slot"]) != want_slot:
+                        raise RuntimeError(
+                            f"scheduler mirror diverged on req {r.req_id}: "
+                            f"device ok={bool(out['ok'])} "
+                            f"slot={int(out['slot'])}, host slot={want_slot}")
+                    results[r.req_id].append(int(out["tok0"]))
+                    total_emitted += 1
+                    admitted_since += 1
+                    if r.max_new >= 2:
+                        ledger.admit_at(want_slot, need)
             if ledger.n_active == 0:
                 if pending:
                     raise RuntimeError("scheduler stalled with pending "
                                        "requests (pool too small?)")
                 break
             w0 = telemetry.now_us() if telemetry is not None else 0.0
-            ts = time.perf_counter()
-            cache, st, out = self._decode(self.params, cache, st)
-            out = jax.device_get(out)
-            dt = time.perf_counter() - ts
+            with span("serve.decode", rec, step=steps + 1):
+                ts = time.perf_counter()
+                cache, st, out = self._decode(self.params, cache, st)
+                out = jax.device_get(out)
+                dt = time.perf_counter() - ts
             steps += 1
-            ntok = 0
-            for i in range(scfg.max_slots):
-                if out["emitted"][i] > 0:
-                    results[int(out["req"][i])].append(int(out["next"][i]))
-                    ntok += 1
-                if out["finished"][i] > 0:
-                    ledger.evict(i)
-            total_emitted += ntok
-            occupancy_trail.append(int(out["vals"]["serve/slot_occupancy"]))
-            if telemetry is not None:
-                row = {"round": steps}
-                row.update({obs_counters.METRIC_PREFIX + k: float(v)
-                            for k, v in out["vals"].items()})
-                row[obs_counters.METRIC_PREFIX + "serve/admitted"] = \
-                    float(admitted_since)
-                row[obs_counters.METRIC_PREFIX + "serve/tokens_per_s"] = \
-                    ntok / max(dt, 1e-9)
-                telemetry.observe_rows([row], w0,
-                                       telemetry.now_us() - w0,
-                                       measured=True, phases=False)
-            admitted_since = 0
+            with span("serve.bookkeep", rec, step=steps):
+                ntok = 0
+                for i in range(scfg.max_slots):
+                    if out["emitted"][i] > 0:
+                        results[int(out["req"][i])].append(
+                            int(out["next"][i]))
+                        ntok += 1
+                    if out["finished"][i] > 0:
+                        ledger.evict(i)
+                total_emitted += ntok
+                occupancy_trail.append(
+                    int(out["vals"]["serve/slot_occupancy"]))
+                if telemetry is not None:
+                    row = {"round": steps}
+                    row.update({obs_counters.METRIC_PREFIX + k: float(v)
+                                for k, v in out["vals"].items()})
+                    row[obs_counters.METRIC_PREFIX + "serve/admitted"] = \
+                        float(admitted_since)
+                    row[obs_counters.METRIC_PREFIX
+                        + "serve/tokens_per_s"] = ntok / max(dt, 1e-9)
+                    telemetry.observe_rows([row], w0,
+                                           telemetry.now_us() - w0)
+                admitted_since = 0
         wall = time.perf_counter() - t0
         stats = {
             "engine": "continuous" if continuous else "fixed",

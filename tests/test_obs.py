@@ -21,7 +21,7 @@ from repro.obs import (JsonlSink, MemorySink, MultiSink, Telemetry,
 from repro.obs.check import check_jsonl, check_trace
 from repro.obs.monitors import Monitor, MonitorBank
 from repro.obs.sinks import jsonable
-from repro.obs.trace import PHASE_NAMES, TraceRecorder
+from repro.obs.trace import TraceRecorder, span
 
 _LATE = FaultConfig(straggler_frac=0.3, straggler_delay=3.0,
                     base_delay=0.3)
@@ -276,34 +276,49 @@ def test_multi_and_memory_sinks_fan_out():
 # trace + artifact checks                                               #
 # --------------------------------------------------------------------- #
 
+DRIVER_SPANS = ("driver.stage", "driver.dispatch", "driver.drain",
+                "driver.hooks")
+
+
 def _fake_row(t):
     return {"round": t, "obs/gate/cosine_rejected": 0.0,
-            "obs/select/team_size": 4.0}
+            "obs/buffer/occupancy": float(t)}
 
 
 def test_trace_recorder_emits_checkable_phase_spans(tmp_path):
+    """Spans opened through ``obs.trace.span`` land in the recorder as
+    measured "X" events with their args; drained gauges land as counter
+    events at the drain; the schema check asks for named spans."""
     rec = TraceRecorder("sync")
-    rec.begin("stage")
-    rec.end("stage", steps=2)
-    rec.emit_rounds(0.0, 1000.0, [_fake_row(1), _fake_row(2)])
+    for name in DRIVER_SPANS:
+        with span(name, rec, first=1):
+            pass
+    rec.counters([_fake_row(1), _fake_row(2)], ts_us=1234.0)
     trace = rec.to_json()
     assert trace["displayTimeUnit"] == "ms"
-    names = {e["name"] for e in trace["traceEvents"]}
-    assert set(PHASE_NAMES) <= names            # >= 5 distinct phases
-    assert not check_trace(trace, min_phases=5)
+    xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    assert [e["name"] for e in xs] == list(DRIVER_SPANS)
+    assert all(e["args"] == {"first": 1} and e["dur"] > 0 for e in xs)
+    assert all(b["ts"] >= a["ts"] + a["dur"] for a, b in zip(xs, xs[1:]))
+    cs = [e for e in trace["traceEvents"] if e["ph"] == "C"]
+    assert [(e["name"], e["ts"], e["args"]["value"]) for e in cs] == [
+        ("buffer/occupancy", 1234.0, 1.0), ("buffer/occupancy", 1234.0,
+                                            2.0)]
+    assert not check_trace(trace, spans=DRIVER_SPANS)
     path = tmp_path / "t.json"
     rec.save(str(path))
-    assert not check_trace(str(path), min_phases=5)
-    # mutation twin: strip the phase spans -> the check fires
+    assert not check_trace(str(path), spans=DRIVER_SPANS)
+    # mutation twin: drop one span -> the check names it
     trace["traceEvents"] = [e for e in trace["traceEvents"]
-                            if e["name"] not in PHASE_NAMES]
-    assert check_trace(trace, min_phases=5)
+                            if e["name"] != "driver.drain"]
+    errs = check_trace(trace, spans=DRIVER_SPANS)
+    assert len(errs) == 1 and "driver.drain" in errs[0]
 
 
 def test_run_artifacts_pass_schema_checks(tmp_path):
     """A real scan-driver run: the JSONL stream and the Perfetto trace
     both pass the CI schema checks, with every registered counter
-    present and >= 5 distinct phase spans per round."""
+    present and one measured span of each driver phase per chunk."""
     model, fed = _setup(6, m=6, n=240)
     cfg = _sync_cfg()
     jsonl = str(tmp_path / "obs.jsonl")
@@ -314,7 +329,11 @@ def test_run_artifacts_pass_schema_checks(tmp_path):
     summary = tele.finish()
     assert summary["rows"] == 3
     assert not check_jsonl(jsonl, require_obs=True, engine="sync")
-    assert not check_trace(tr, min_phases=5)
+    assert not check_trace(tr, spans=DRIVER_SPANS)
+    evs = json.load(open(tr))["traceEvents"]
+    for name in DRIVER_SPANS:
+        assert sorted(e["args"]["first"] for e in evs
+                      if e["name"] == name) == [1, 3]      # two chunks
     # mutation twin: a stream with no summary record fails the check
     bad = str(tmp_path / "bad.jsonl")
     with open(jsonl) as f, open(bad, "w") as g:

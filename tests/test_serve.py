@@ -291,17 +291,24 @@ class TestServeTelemetry:
         reqs = draw_requests(4, 6, 2, 10, cfg.vocab_size, seed=0)
         engine.run(reqs, telemetry=tel, continuous=True)
         tel.finish()
-        assert check_trace(trace_p) == []
+        spans = ("serve.admit", "serve.decode", "serve.bookkeep")
+        assert check_trace(trace_p, spans=spans) == []
         assert check_jsonl(jsonl_p, require_obs=True,
                            engine="serve") == []
         with open(trace_p) as f:
             evs = json.load(f)["traceEvents"]
-        rounds = [e for e in evs if e["name"] == "round"
-                  and e["ph"] == "X"]
+        xs = [e for e in evs if e["ph"] == "X"]
         counters = [e for e in evs if e.get("ph") == "C"]
-        assert rounds, "no measured round spans"
-        assert all("attributed" not in e.get("args", {})
-                   for e in rounds)
+        # one admission span per request, one decode and one bookkeeping
+        # span per step, each carrying its request or step
+        assert sorted(e["args"]["req_id"] for e in xs
+                      if e["name"] == "serve.admit") == \
+            sorted(r.req_id for r in reqs)
+        steps = [e["args"]["step"] for e in xs if e["name"] == "serve.decode"]
+        assert steps == list(range(1, len(steps) + 1))
+        assert [e["args"]["step"] for e in xs
+                if e["name"] == "serve.bookkeep"] == steps
+        assert {e["name"] for e in xs} == set(spans)
         tracks = {e["name"] for e in counters}
         assert "serve/slot_occupancy" in tracks
         assert "serve/pages_in_use" in tracks
