@@ -291,7 +291,7 @@ class CrossRoundGateAware:
                           blend + self.lr * (1.0 - blend),
                           blend * (1.0 - self.lr))
         flat, leaves, treedef = _flatten_clients(updates)
-        v, ref, lo, hi, trims = _gate_aware_targets(
+        _, v, ref, lo, hi, trims = _gate_aware_targets(
             flat, malicious, self.cfg, scale=self.scale)
         crafted = (1.0 - blend) * v + blend * ref
         if trims:
@@ -313,31 +313,40 @@ class CrossRoundGateAware:
 
 
 def _gate_aware_targets(flat, malicious, cfg, *, scale=100.0):
-    """The poison corner v, gate reference ref and trim window (lo, hi)
-    shared by ``gate_aware`` (analytic blend) and ``CrossRoundGateAware``
-    (probed blend)."""
-    _, _, h, nh = _honest_stats(flat, malicious)
-    mu = (flat * h[:, None]).sum(0) / nh
+    """The honest mean mu, poison corner v, gate reference ref and trim
+    window (lo, hi) shared by ``gate_aware`` (analytic blend) and
+    ``CrossRoundGateAware`` (probed blend).
+
+    The honest order statistics are rows of ONE ascending sort with the
+    malicious rows at +inf, which puts the nh honest values first.  Each
+    statistic sits at a scalar row index, so it is read as a dynamic
+    slice: a ``take_along_axis`` with a broadcast index lowers to a
+    per-element gather, which costs the TPU milliseconds per row."""
+    mu, _, h, nh = _honest_stats(flat, malicious)
     k = flat.shape[0]
     trims = cfg.aggregator != "fedavg"
     asc = jnp.sort(jnp.where(h[:, None] > 0, flat, jnp.inf), axis=0)
-    t = jnp.floor(cfg.trim_frac * nh).astype(jnp.int32)
-    take = lambda s, i: jnp.take_along_axis(
-        s, jnp.broadcast_to(i, (1, flat.shape[1])).astype(jnp.int32), 0)[0]
-    lo = take(asc, t)
-    desc = jnp.sort(jnp.where(h[:, None] > 0, flat, -jnp.inf), axis=0)
-    hi = take(desc, k - 1 - t)
+    row = lambda i: jax.lax.dynamic_index_in_dim(asc, i, 0, keepdims=False)
     nh_i = nh.astype(jnp.int32)
-    ref = 0.5 * (take(asc, (nh_i - 1) // 2) + take(asc, nh_i // 2))
+    # trim window: the t-th honest value from each end; with no honest
+    # row it is empty (lo = +inf, hi = -inf)
+    t = jnp.floor(cfg.trim_frac * nh).astype(jnp.int32)
+    lo = row(t)
+    hi = jnp.where(h.sum() > 0, row(nh_i - 1 - t), -jnp.inf)
+    ref = 0.5 * (row((nh_i - 1) // 2) + row(nh_i // 2))
     if not trims:
+        # anticipated contaminated median: the m crafted values land
+        # BELOW every honest value where mu > 0 (the boosted direction
+        # is -scale*mu) and ABOVE where mu < 0, shifting the all-updates
+        # median onto one of two honest order statistics per coordinate
         m_cnt = k - nh_i
-        side = (mu > 0).astype(jnp.int32)
-        lo_r = jnp.clip((k - 1) // 2 - m_cnt * side, 0, nh_i - 1)
-        hi_r = jnp.clip(k // 2 - m_cnt * side, 0, nh_i - 1)
-        ref = 0.5 * (take(asc, lo_r) + take(asc, hi_r))
+        side = mu > 0                               # crafted on low side
+        at = lambda i: row(jnp.clip(i, 0, nh_i - 1))
+        pick = lambda r: jnp.where(side, at(r - m_cnt), at(r))
+        ref = 0.5 * (pick((k - 1) // 2) + pick(k // 2))
         lo, hi = jnp.full_like(lo, -jnp.inf), jnp.full_like(hi, jnp.inf)
     v = jnp.clip(-scale * mu, lo, hi)
-    return v, ref, lo, hi, trims
+    return mu, v, ref, lo, hi, trims
 
 
 def gate_aware(updates, malicious, cfg, *, margin=0.1, scale=100.0,
@@ -368,35 +377,8 @@ def gate_aware(updates, malicious, cfg, *, margin=0.1, scale=100.0,
          applies.
     """
     flat, leaves, treedef = _flatten_clients(updates)
-    mu, _, h, nh = _honest_stats(flat, malicious)
-    k = flat.shape[0]
-    trims = cfg.aggregator != "fedavg"
-
-    # honest order statistics: ascending sort with malicious rows at +inf
-    # puts the nh honest values first; t-th row is the lower trim bound
-    asc = jnp.sort(jnp.where(h[:, None] > 0, flat, jnp.inf), axis=0)
-    t = jnp.floor(cfg.trim_frac * nh).astype(jnp.int32)
-    take = lambda s, i: jnp.take_along_axis(
-        s, jnp.broadcast_to(i, (1, flat.shape[1])).astype(jnp.int32), 0)[0]
-    lo = take(asc, t)
-    # descending bound: malicious at -inf pushes honest rows to the END
-    desc = jnp.sort(jnp.where(h[:, None] > 0, flat, -jnp.inf), axis=0)
-    hi = take(desc, k - 1 - t)
-    nh_i = nh.astype(jnp.int32)
-    ref = 0.5 * (take(asc, (nh_i - 1) // 2) + take(asc, nh_i // 2))
-    if not trims:
-        # anticipated contaminated median: the m crafted values land
-        # BELOW every honest value where mu > 0 (the boosted direction
-        # is -scale*mu) and ABOVE where mu < 0, shifting the all-updates
-        # median onto a known honest order statistic per coordinate
-        m_cnt = k - nh_i
-        side = (mu > 0).astype(jnp.int32)           # crafted on low side
-        lo_r = jnp.clip((k - 1) // 2 - m_cnt * side, 0, nh_i - 1)
-        hi_r = jnp.clip(k // 2 - m_cnt * side, 0, nh_i - 1)
-        ref = 0.5 * (take(asc, lo_r) + take(asc, hi_r))
-        lo, hi = jnp.full_like(lo, -jnp.inf), jnp.full_like(hi, jnp.inf)
-
-    v = jnp.clip(-scale * mu, lo, hi)               # trim-window corner
+    mu, v, ref, lo, hi, trims = _gate_aware_targets(flat, malicious, cfg,
+                                                    scale=scale)
     target = jnp.float32(cfg.cosine_outlier_thresh + margin)
     rn = jnp.sqrt(jnp.sum(ref * ref))
 

@@ -4,6 +4,7 @@ contract."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import attacks
 
@@ -236,3 +237,146 @@ def test_gate_aware_unbounded_against_plain_mean():
     cfg = FedConfig(n_clients=k, aggregator="fedavg")
     out = np.asarray(attacks.gate_aware(upd, mal, cfg)["w"])
     assert np.linalg.norm(out[0]) > 5.0 * np.linalg.norm(out[3:], axis=1).max()
+
+
+# ---- gate-aware order statistics: parity with the gather formula ----
+def _frozen_targets(flat, malicious, cfg, scale=100.0):
+    """The gate-aware targets as first written: order statistics through
+    ``take_along_axis`` with a broadcast index, and a second (descending)
+    sort for the upper trim bound.  Kept to pin the row-slice rewrite."""
+    mu, _, h, nh = attacks._honest_stats(flat, malicious)
+    k = flat.shape[0]
+    trims = cfg.aggregator != "fedavg"
+    asc = jnp.sort(jnp.where(h[:, None] > 0, flat, jnp.inf), axis=0)
+    t = jnp.floor(cfg.trim_frac * nh).astype(jnp.int32)
+    take = lambda s, i: jnp.take_along_axis(
+        s, jnp.broadcast_to(i, (1, flat.shape[1])).astype(jnp.int32), 0)[0]
+    lo = take(asc, t)
+    desc = jnp.sort(jnp.where(h[:, None] > 0, flat, -jnp.inf), axis=0)
+    hi = take(desc, k - 1 - t)
+    nh_i = nh.astype(jnp.int32)
+    ref = 0.5 * (take(asc, (nh_i - 1) // 2) + take(asc, nh_i // 2))
+    if not trims:
+        m_cnt = k - nh_i
+        side = (mu > 0).astype(jnp.int32)
+        lo_r = jnp.clip((k - 1) // 2 - m_cnt * side, 0, nh_i - 1)
+        hi_r = jnp.clip(k // 2 - m_cnt * side, 0, nh_i - 1)
+        ref = 0.5 * (take(asc, lo_r) + take(asc, hi_r))
+        lo, hi = jnp.full_like(lo, -jnp.inf), jnp.full_like(hi, jnp.inf)
+    v = jnp.clip(-scale * mu, lo, hi)
+    return mu, v, ref, lo, hi, trims
+
+
+def _frozen_gate_aware(updates, malicious, cfg, margin=0.1, scale=100.0,
+                       n_iters=20):
+    flat, leaves, treedef = attacks._flatten_clients(updates)
+    mu, v, ref, lo, hi, trims = _frozen_targets(flat, malicious, cfg, scale)
+    target = jnp.float32(cfg.cosine_outlier_thresh + margin)
+    rn = jnp.sqrt(jnp.sum(ref * ref))
+
+    def cos_w(w):
+        u = (1.0 - w) * v + w * ref
+        un = jnp.sqrt(jnp.sum(u * u))
+        return jnp.sum(u * ref) / jnp.maximum(un * rn, attacks._EPS)
+
+    def body(_, bounds):
+        lo_w, hi_w = bounds
+        mid = 0.5 * (lo_w + hi_w)
+        ok = cos_w(mid) >= target
+        return jnp.where(ok, lo_w, mid), jnp.where(ok, mid, hi_w)
+
+    _, w = jax.lax.fori_loop(
+        0, n_iters, body, (jnp.float32(0.0), jnp.float32(1.0)))
+    w = jnp.where(cos_w(jnp.float32(0.0)) >= target, jnp.float32(0.0), w)
+    crafted = (1.0 - w) * v + w * ref
+    if trims:
+        crafted = jnp.clip(crafted, lo, hi)
+    else:
+        cn = jnp.sqrt(jnp.sum(crafted * crafted))
+        crafted = crafted * (scale * jnp.sqrt(jnp.sum(mu * mu))
+                             / jnp.maximum(cn, attacks._EPS))
+    return attacks._unflatten_clients(
+        attacks._replace_malicious(flat, malicious, crafted), leaves, treedef)
+
+
+def _frozen_cross_round(updates, malicious, cfg, carry, scale=100.0,
+                        lr=0.5):
+    blend, prev_gated = carry
+    caught = (prev_gated * malicious).sum() > 0
+    blend = jnp.where(caught, blend + lr * (1.0 - blend), blend * (1.0 - lr))
+    flat, leaves, treedef = attacks._flatten_clients(updates)
+    _, v, ref, lo, hi, trims = _frozen_targets(flat, malicious, cfg, scale)
+    crafted = (1.0 - blend) * v + blend * ref
+    if trims:
+        crafted = jnp.clip(crafted, lo, hi)
+    out = attacks._unflatten_clients(
+        attacks._replace_malicious(flat, malicious, crafted), leaves, treedef)
+    return out, blend
+
+
+_PK = 10
+_MASKS = {
+    "leading3": [0, 1, 2],
+    "scattered3": [1, 4, 8],
+    "none": [],
+    "all_but_one": list(range(1, _PK)),
+    "all": list(range(_PK)),
+}
+
+
+def _parity_inputs(mask):
+    key = jax.random.PRNGKey(11)
+    upd = {"w": jax.random.normal(key, (_PK, 33, 7)) * 0.5 + 0.1,
+           "b": jax.random.normal(jax.random.fold_in(key, 1), (_PK, 129))}
+    mal = jnp.zeros((_PK,)).at[jnp.asarray(_MASKS[mask], jnp.int32)].set(1.0)
+    return upd, mal
+
+
+def _assert_bits_equal(a, b):
+    for la, lb in zip(jax.tree_util.tree_leaves(a),
+                      jax.tree_util.tree_leaves(b)):
+        la, lb = np.asarray(la), np.asarray(lb)
+        assert la.dtype == lb.dtype and la.shape == lb.shape
+        np.testing.assert_array_equal(la.view(np.uint32), lb.view(np.uint32))
+
+
+@pytest.mark.parametrize("aggregator", ["trimmed_mean", "krum", "fedavg"])
+@pytest.mark.parametrize("mask", list(_MASKS))
+def test_gate_aware_bit_parity_with_gather_formula(mask, aggregator):
+    from repro.configs.base import FedConfig
+    upd, mal = _parity_inputs(mask)
+    cfg = FedConfig(n_clients=_PK, aggregator=aggregator, trim_frac=0.3)
+    new = jax.jit(lambda u, m: attacks.gate_aware(u, m, cfg))(upd, mal)
+    old = jax.jit(lambda u, m: _frozen_gate_aware(u, m, cfg))(upd, mal)
+    _assert_bits_equal(new, old)
+
+
+@pytest.mark.parametrize("aggregator", ["trimmed_mean", "krum", "fedavg"])
+@pytest.mark.parametrize("mask", list(_MASKS))
+def test_cross_round_gate_aware_bit_parity_with_gather_formula(mask,
+                                                               aggregator):
+    from repro.configs.base import FedConfig
+    upd, mal = _parity_inputs(mask)
+    cfg = FedConfig(n_clients=_PK, aggregator=aggregator, trim_frac=0.3)
+    att = attacks.CrossRoundGateAware(cfg)
+    # one round that caught a colluder, so the blend moves off blend0
+    carry = (jnp.float32(0.5), jnp.zeros((_PK,)).at[1].set(1.0))
+    new = jax.jit(lambda u, m, c: att(u, m, None, c))(upd, mal, carry)
+    old = jax.jit(lambda u, m, c: _frozen_cross_round(u, m, cfg, c))(
+        upd, mal, carry)
+    _assert_bits_equal(new, old)
+
+
+@pytest.mark.parametrize("aggregator", ["trimmed_mean", "fedavg"])
+def test_gate_aware_compiles_to_one_sort_and_no_gather(aggregator):
+    """The order statistics are row slices of one sort: the compiled
+    attacker holds no gather (a per-element gather per order statistic
+    on the TPU) and no second sort."""
+    from repro.configs.base import FedConfig
+    cfg = FedConfig(n_clients=10, aggregator=aggregator)
+    upd = {"w": jnp.zeros((10, 4096), jnp.float32)}
+    mal = jnp.zeros((10,), jnp.float32)
+    hlo = jax.jit(lambda u, m: attacks.gate_aware(u, m, cfg)).lower(
+        upd, mal).compile().as_text()
+    assert hlo.count(" gather(") == 0
+    assert hlo.count(" sort(") == 1

@@ -94,11 +94,13 @@ def test_round_jaxpr_names_attack_codec_and_server_eval():
     for name in ("update_attack", "codec", "server_eval", "client_update",
                  "selection", "sanitize", "aggregate", "writeback"):
         assert name in under, name
-    # the attacker's gathers and sorts, and the codec's absmax, are in
-    # their scopes, not beside them
+    # the attacker's sort and its order-statistic row slices, and the
+    # codec's absmax, are in their scopes, not beside them
     calls = {e.params.get("name") for e in under["update_attack"]
              if e.primitive.name == "jit"}
-    assert {"take_along_axis", "sort"} <= calls
+    assert "sort" in calls and "take_along_axis" not in calls
+    assert "dynamic_slice" in {e.primitive.name
+                               for e in under["update_attack"]}
     assert "reduce_max" in {e.primitive.name for e in under["codec"]}
 
 
