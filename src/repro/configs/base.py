@@ -34,6 +34,12 @@ class ModelConfig:
     head_dim: int = 0                 # 0 -> d_model // n_heads
     qkv_bias: bool = False
     rope_theta: float = 1.0e4
+    # YaRN on the full-attention layers (HF ``rope_type: yarn`` with its
+    # default betas 32/1 and attention factor 0.1 ln(factor) + 1); a
+    # factor of 0 keeps default RoPE there.  Window layers always use
+    # default RoPE at rope_theta.
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
     norm_eps: float = 1.0e-5
     tie_embeddings: bool = False
     # --- MoE ---
@@ -63,6 +69,11 @@ class ModelConfig:
     embed_inputs: bool = True         # False: input_specs provides embeddings
     # --- attention ---
     sliding_window: int = 0           # 0 = full attention
+    layer_types: Tuple[str, ...] = () # per-layer attention kind as the
+                                      # published config lists it:
+                                      # "sliding_attention" (windowed)
+                                      # or "full_attention"; () -> every
+                                      # layer windowed iff sliding_window
     attn_impl: str = "xla"            # xla | pallas  (pallas = flash kernel)
     # --- numerics / memory ---
     dtype: str = "bfloat16"
@@ -71,6 +82,10 @@ class ModelConfig:
     loss_chunk: int = 0               # chunk seq dim of the LM loss
     # --- provenance ---
     source: str = ""                  # citation of the public config
+
+    def __post_init__(self):
+        # JSON overrides hand lists; the config stays hashable
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
 
     # ------------------------------------------------------------------
     @property
@@ -116,11 +131,26 @@ class ModelConfig:
             )
         raise ValueError(f"unknown arch_type {self.arch_type}")
 
+    @property
+    def attn_types(self) -> Tuple[str, ...]:
+        """Per-layer attention kind: ``layer_types`` where given, else
+        every layer windowed when ``sliding_window`` is set."""
+        if self.layer_types:
+            assert len(self.layer_types) == self.n_layers
+            return self.layer_types
+        kind = "sliding_attention" if self.sliding_window else "full_attention"
+        return (kind,) * self.n_layers
+
+    def window_of(self, attn_type: str) -> int:
+        """Attention window of a layer of this kind (0 = full)."""
+        return self.sliding_window if attn_type == "sliding_attention" else 0
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test variant: 2 layers, d_model<=512, <=4 experts."""
+        """Smoke-test variant: 2 layers (one period of ``layer_types``
+        where given), d_model<=512, <=4 experts."""
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4)
         n_kv = max(1, min(self.n_kv_heads, 2))
@@ -137,7 +167,15 @@ class ModelConfig:
             block_pattern=(),
             remat=False,
             dtype="float32",
+            param_dtype="float32",
         )
+        if self.layer_types:
+            # one whole period of the published layer kinds
+            kinds = self.layer_types
+            period = next(c for c in range(1, len(kinds) + 1)
+                          if kinds == kinds[:c] * (len(kinds) // c))
+            kw["n_layers"] = period
+            kw["layer_types"] = kinds[:period]
         if self.n_experts:
             kw["n_experts"] = min(self.n_experts, 4)
             kw["top_k"] = min(self.top_k, 2)

@@ -1,4 +1,4 @@
-"""Registry of the 10 assigned architectures + the paper's own models.
+"""Registry of the assigned architectures + the paper's own models.
 
 Each entry cites its public source config in ``source``.
 """
@@ -17,6 +17,7 @@ from repro.configs.llama32_vision_90b import CONFIG as _llama32_vision_90b
 from repro.configs.internlm2_20b import CONFIG as _internlm2_20b
 from repro.configs.dbrx_132b import CONFIG as _dbrx_132b
 from repro.configs.xlstm_350m import CONFIG as _xlstm_350m
+from repro.configs.mellum2_12b import CONFIG as _mellum2_12b
 from repro.configs.paper_models import CNN_CONFIG, MLP_CONFIG, TINY_LM
 
 ARCHS = {
@@ -30,6 +31,7 @@ ARCHS = {
     "internlm2-20b": _internlm2_20b,
     "dbrx-132b": _dbrx_132b,
     "xlstm-350m": _xlstm_350m,
+    "mellum2-12b-a2.5b": _mellum2_12b,
     # the paper's own model scale (healthcare FL experiments)
     "paper-cnn": CNN_CONFIG,
     "paper-mlp": MLP_CONFIG,

@@ -21,6 +21,13 @@ Contract shared with the kernel:
                               placement: qblk = dh, one scale per cache
                               row per head), one (1, page) row per
                               (page, head)
+  window   int > 0            a window layer: kp, vp are per-slot rings
+                              (S, R, Hkv, page, dh), scales
+                              (S, R, Hkv, 1, page), table is None;
+                              absolute page a of slot s is ring page
+                              a % R, and only keys
+                              max(0, lengths - window) <= j < lengths are
+                              visible
 """
 from __future__ import annotations
 
@@ -46,20 +53,34 @@ def dequant_pool(codes, scale):
 
 
 def paged_decode_ref(q, kp, vp, table, lengths, *, k_scale=None,
-                     v_scale=None):
+                     v_scale=None, window=0):
     """Returns (S, Hq, dh) f32 attention outputs (see module contract)."""
     s, hq, dh = q.shape
-    hkv = kp.shape[1]
+    hkv, page = kp.shape[-3], kp.shape[-2]
     g = hq // hkv
     if k_scale is not None:
         kp = dequant_pool(kp, k_scale)
         vp = dequant_pool(vp, v_scale)
-    k = gather_pages(kp, table).astype(jnp.float32)   # (S, Hkv, T, dh)
-    v = gather_pages(vp, table).astype(jnp.float32)
-    t = k.shape[2]
+    if window:
+        ring = kp.shape[1]
+        lo = jnp.maximum(lengths - window, 0)
+        pages = (lo // page)[:, None] + jnp.arange(ring)[None, :]  # (S, R)
+        slot = jnp.arange(s)[:, None]
+
+        def gather(pool):
+            r = pool[slot, pages % ring]             # (S, R, Hkv, page, dh)
+            return r.transpose(0, 2, 1, 3, 4).reshape(s, hkv, ring * page, dh)
+
+        k, v = gather(kp), gather(vp)
+        kpos = (pages[:, :, None] * page + jnp.arange(page)).reshape(s, -1)
+        visible = (kpos < lengths[:, None]) & (kpos >= lo[:, None])
+    else:
+        k = gather_pages(kp, table)                  # (S, Hkv, T, dh)
+        v = gather_pages(vp, table)
+        visible = jnp.arange(k.shape[2])[None, :] < lengths[:, None]
+    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
     qg = q.reshape(s, hkv, g, dh).astype(jnp.float32) * dh ** -0.5
     scores = jnp.einsum("shgd,shtd->shgt", qg, k)
-    visible = jnp.arange(t)[None, :] < lengths[:, None]          # (S, T)
     scores = jnp.where(visible[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("shgt,shtd->shgd", probs, v)

@@ -10,14 +10,34 @@ Three execution modes per layer:
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init
+from repro.models.layers import apply_rope, dense_init, yarn_freqs
 
 NEG_INF = -1e30
+
+
+def rope_of(cfg, attn_type):
+    """RoPE of a layer kind as ``(freqs, scale)`` for ``apply_rope``:
+    YaRN on full-attention layers where ``cfg.yarn_factor`` is set,
+    ``None`` (the default frequencies of ``cfg.rope_theta``) otherwise."""
+    if attn_type != "full_attention" or not cfg.yarn_factor:
+        return None
+    factor = cfg.yarn_factor
+    freqs = yarn_freqs(cfg.resolved_head_dim, cfg.rope_theta, factor,
+                       cfg.yarn_original_max_pos)
+    return freqs, 0.1 * math.log(factor) + 1.0
+
+
+def ring_pages(window, page_size, max_pages):
+    """Pages of one slot's ring on a window layer: the keys a query sees,
+    (i - window, i], span at most ceil(window / page) + 1 pages; a slot
+    never holds more than ``max_pages``."""
+    return min(-(-window // page_size) + 1, max_pages)
 
 
 def init_attention(key, cfg, cross: bool = False):
@@ -65,17 +85,19 @@ def causal_mask(s, t_offset=0, window=0):
     return m
 
 
-def attention_fwd(params, x, cfg, positions, *, window=0, cache=None,
-                  kv_source=None, layer_idx=0):
+def attention_fwd(params, x, cfg, positions, *, window=0, rope=None,
+                  cache=None, kv_source=None, layer_idx=0):
     """Returns (out, new_cache).
 
     x: (B, S, d).  kv_source: (B, T, d) for cross-attention (no rope/causal).
+    rope: ``rope_of(cfg, kind)``; None -> default RoPE at cfg.rope_theta.
     cache:
       None                     -> train/prefill, no cache returned
       {"k","v","length"}       -> full cache decode/prefill-fill
       {"k","v","pos"} (ring)   -> sliding-window ring cache decode
       {"kp","vp","table",...}  -> paged pool cache (serving; see
-                                  init_paged_kv_cache)
+                                  init_paged_kv_cache); with a window,
+                                  {"kp","vp","slot",...} per-slot rings
       {"ck","cv"}              -> frozen cross-attention KV
     """
     dtype = x.dtype
@@ -103,9 +125,11 @@ def attention_fwd(params, x, cfg, positions, *, window=0, cache=None,
         out = out.reshape(B, S, hq * dh).astype(dtype) @ params["wo"].astype(dtype)
         return out, new_cache
 
-    q = apply_rope(q, positions, cfg.rope_theta)
+    freqs, scale = rope if rope is not None else (None, 1.0)
+    q = apply_rope(q, positions, cfg.rope_theta, freqs=freqs, scale=scale)
     k_new = _proj(params, "k", x, hkv, dh, dtype)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta)
+    k_new = apply_rope(k_new, positions, cfg.rope_theta, freqs=freqs,
+                       scale=scale)
     v_new = _proj(params, "v", x, hkv, dh, dtype)
 
     if cache is None:                              # ---- train / prefill ----
@@ -121,7 +145,7 @@ def attention_fwd(params, x, cfg, positions, *, window=0, cache=None,
         out = out.astype(dtype).reshape(B, S, hq * dh) @ params["wo"].astype(dtype)
         return out, None
 
-    if "table" in cache:                           # ---- paged pool cache ----
+    if "kp" in cache:                              # ---- paged pool cache ----
         return _paged_fwd(params, cache, q, k_new, v_new, cfg, window)
 
     if "pos" in cache and S > 1:                   # ---- ring-cache prefill ----
@@ -185,9 +209,7 @@ def _paged_quant(x):
 
 
 def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
-    """Paged-pool branch of attention_fwd (serving; sliding windows are
-    not supported here — the serving configs cap sequence length at the
-    page budget instead).
+    """Paged-pool branch of attention_fwd (serving).
 
     Cache contract (see init_paged_kv_cache):
       kp, vp    (N, Hkv, page, dh)  shared head-major page pools (f32 or
@@ -199,9 +221,17 @@ def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
       new_valid (A,) int32          prefill only: valid rows of x to
                                     scatter (pad rows are dropped)
 
-    Prefill (S > 1) scatters rows [0, new_valid) into the slot's pages;
-    decode (S == 1) appends one row at position ``length`` per active
-    slot and attends over the pages via the flash-decode kernel
+    A window layer (``window > 0``) holds a fixed ring of R pages per
+    slot instead of the shared pool and its table: kp, vp
+    (A, R, Hkv, page, dh), ks, vs (A, R, Hkv, 1, page), and ``slot``
+    (B,) int32, the slot each row of x belongs to.  Absolute page a of a
+    slot lives at ring page a % R, reused in place: the keys a query at
+    position i sees, (i - window, i], span at most R pages.
+
+    Prefill (S > 1) scatters rows [0, new_valid) into the slot's pages
+    (on a window layer only the rows the window keeps, the last
+    ``window``); decode (S == 1) appends one row at position ``length``
+    per active slot and attends over the pages via the flash-decode kernel
     (cfg.attn_impl == 'pallas') or the dense gather reference.  The
     returned cache echoes the context leaves unchanged — the serving
     engine owns length/active advancement and eviction.
@@ -213,70 +243,71 @@ def _paged_fwd(params, cache, q, k_new, v_new, cfg, window):
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     g = hq // hkv
     B, S = q.shape[0], q.shape[1]
-    kp, vp, table = cache["kp"], cache["vp"], cache["table"]
-    n_pages, page = kp.shape[0], kp.shape[2]
-    maxp = table.shape[1]
+    kp, vp = cache["kp"], cache["vp"]
+    page = kp.shape[-2]
+    if window:
+        n_slots, ring = kp.shape[0], kp.shape[1]
+    else:
+        table = cache["table"]
+        n_pages, maxp = kp.shape[0], table.shape[1]
     int8 = "ks" in cache
     length, active = cache["length"], cache["active"]
     new_cache = dict(cache)
 
+    def write(lead, row, k_rows, v_rows):
+        """Scatter K/V rows at pool index ``lead + (:, row)``; an
+        out-of-range leading index drops the row."""
+        if int8:
+            k_rows, ks = _paged_quant(k_rows)
+            v_rows, vs = _paged_quant(v_rows)
+            at = (*lead, slice(None), 0, row)
+            new_cache["ks"] = cache["ks"].at[at].set(ks, mode="drop")
+            new_cache["vs"] = cache["vs"].at[at].set(vs, mode="drop")
+        at = (*lead, slice(None), row)
+        new_cache["kp"] = kp.at[at].set(k_rows.astype(kp.dtype), mode="drop")
+        new_cache["vp"] = vp.at[at].set(v_rows.astype(vp.dtype), mode="drop")
+
     if S > 1:
         # ---- prefill: causal attention over the (padded) prompt, then
         # scatter the valid rows into the slot's pages.  Pad rows are
-        # dropped (dest = n_pages); rows beyond the prompt are garbage in
-        # the output and the engine only reads position new_valid-1.
+        # dropped; rows beyond the prompt are garbage in the output and
+        # the engine only reads position new_valid-1.
         qg = q.reshape(B, S, hkv, g, dh)
         mask = causal_mask(S, window=window)[None, None, None]
         out = _sdpa(qg, k_new, v_new, mask)
         out = out.reshape(B, S, hq * dh)
         pos = jnp.arange(S)
         valid = pos[None, :] < cache["new_valid"][:, None]       # (B, S)
-        prow = jnp.clip(pos // page, 0, maxp - 1)
-        pg = jnp.take_along_axis(table, jnp.broadcast_to(prow[None],
-                                                         (B, S)), axis=1)
-        dest = jnp.where(valid, pg, n_pages)       # n_pages = drop
-        row = jnp.broadcast_to(pos % page, (B, S))
-        if int8:
-            kq, ks = _paged_quant(k_new)
-            vq, vs = _paged_quant(v_new)
-            new_cache["ks"] = cache["ks"].at[dest, :, 0, row].set(
-                ks, mode="drop")
-            new_cache["vs"] = cache["vs"].at[dest, :, 0, row].set(
-                vs, mode="drop")
-            k_cast, v_cast = kq, vq
+        if window:
+            valid &= pos[None, :] >= cache["new_valid"][:, None] - window
+            lead = (jnp.where(valid, cache["slot"][:, None], n_slots),
+                    jnp.broadcast_to((pos // page) % ring, (B, S)))
         else:
-            k_cast = k_new.astype(kp.dtype)
-            v_cast = v_new.astype(vp.dtype)
-        new_cache["kp"] = kp.at[dest, :, row].set(k_cast, mode="drop")
-        new_cache["vp"] = vp.at[dest, :, row].set(v_cast, mode="drop")
+            prow = jnp.clip(pos // page, 0, maxp - 1)
+            pg = jnp.take_along_axis(table, jnp.broadcast_to(prow[None],
+                                                             (B, S)), axis=1)
+            lead = (jnp.where(valid, pg, n_pages),)    # n_pages = drop
+        write(lead, jnp.broadcast_to(pos % page, (B, S)), k_new, v_new)
         out = out.astype(dtype) @ params["wo"].astype(dtype)
         return out, new_cache
 
     # ---- decode: append one row at position ``length`` per active slot
-    prow = jnp.clip(length // page, 0, maxp - 1)
-    pg = jnp.take_along_axis(table, prow[:, None], axis=1)[:, 0]
-    dest = jnp.where(active > 0, pg, n_pages)
-    row = length % page
-    if int8:
-        kq, ks = _paged_quant(k_new[:, 0])
-        vq, vs = _paged_quant(v_new[:, 0])
-        new_cache["ks"] = cache["ks"].at[dest, :, 0, row].set(
-            ks, mode="drop")
-        new_cache["vs"] = cache["vs"].at[dest, :, 0, row].set(
-            vs, mode="drop")
-        k_cast, v_cast = kq, vq
-        k_scale, v_scale = new_cache["ks"], new_cache["vs"]
+    if window:
+        lead = (jnp.where(active > 0, jnp.arange(B), n_slots),
+                (length // page) % ring)
     else:
-        k_cast = k_new[:, 0].astype(kp.dtype)
-        v_cast = v_new[:, 0].astype(vp.dtype)
-        k_scale = v_scale = None
-    kp = new_cache["kp"] = kp.at[dest, :, row].set(k_cast, mode="drop")
-    vp = new_cache["vp"] = vp.at[dest, :, row].set(v_cast, mode="drop")
+        prow = jnp.clip(length // page, 0, maxp - 1)
+        pg = jnp.take_along_axis(table, prow[:, None], axis=1)[:, 0]
+        lead = (jnp.where(active > 0, pg, n_pages),)
+    write(lead, length % page, k_new[:, 0], v_new[:, 0])
+    kp, vp = new_cache["kp"], new_cache["vp"]
+    k_scale = new_cache["ks"] if int8 else None
+    v_scale = new_cache["vs"] if int8 else None
     n_keys = jnp.where(active > 0, length + 1, 0)
     attend = paged_flash_decode if cfg.attn_impl == "pallas" \
         else paged_decode_ref
-    out3 = attend(q[:, 0], kp, vp, table, n_keys,
-                  k_scale=k_scale, v_scale=v_scale)
+    out3 = attend(q[:, 0], kp, vp, None if window else table, n_keys,
+                  k_scale=k_scale, v_scale=v_scale, window=window)
     out = out3.reshape(B, 1, hq * dh)
     out = out.astype(dtype) @ params["wo"].astype(dtype)
     return out, new_cache
@@ -292,7 +323,7 @@ def init_kv_cache(cfg, batch, max_len, *, ring=False, dtype=jnp.bfloat16):
 
 
 def init_paged_kv_cache(cfg, slots, num_pages, page_size, max_pages, *,
-                        int8=False, dtype=jnp.float32):
+                        int8=False, dtype=jnp.float32, window=0):
     """One attention layer's paged pool cache (serving).  Pools are
     shared across slots; the per-slot page table indexes into them
     (unallocated entries stay 0 — always a valid pool index, masked out
@@ -300,16 +331,23 @@ def init_paged_kv_cache(cfg, slots, num_pages, page_size, max_pages, *,
     scales instead of raw K/V (see _paged_quant).  Pools are head-major,
     (N, Hkv, page, dh), so one page of one head is a whole trailing tile
     for kernels/paged_decode.py; the scales keep one (1, page) row per
-    (page, head) for the same reason."""
+    (page, head) for the same reason.  A window layer (``window > 0``)
+    gets a ring of ``ring_pages`` pages per slot in place of the pool and
+    the table (see _paged_fwd); ``num_pages`` is then unused."""
     hkv, dh = cfg.n_kv_heads, cfg.resolved_head_dim
     pool_dtype = jnp.int8 if int8 else dtype
-    z = jnp.zeros((num_pages, hkv, page_size, dh), pool_dtype)
+    lead = ((slots, ring_pages(window, page_size, max_pages)) if window
+            else (num_pages,))
+    z = jnp.zeros(lead + (hkv, page_size, dh), pool_dtype)
     c = {"kp": z, "vp": z,
-         "table": jnp.zeros((slots, max_pages), jnp.int32),
          "length": jnp.zeros((slots,), jnp.int32),
          "active": jnp.zeros((slots,), jnp.float32),
          "new_valid": jnp.zeros((slots,), jnp.int32)}
+    if window:
+        c["slot"] = jnp.arange(slots, dtype=jnp.int32)
+    else:
+        c["table"] = jnp.zeros((slots, max_pages), jnp.int32)
     if int8:
-        s = jnp.ones((num_pages, hkv, 1, page_size), jnp.float32)
+        s = jnp.ones(lead + (hkv, 1, page_size), jnp.float32)
         c["ks"], c["vs"] = s, s
     return c

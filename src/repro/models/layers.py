@@ -5,6 +5,8 @@ Parameters are plain nested dicts of jnp arrays; every layer is an
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -51,15 +53,43 @@ def rope_freqs(head_dim, theta):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta):
-    """x: (B, S, H, dh); positions: (B, S) or (S,) int32."""
+def yarn_freqs(head_dim, theta, factor, original_max_pos):
+    """YaRN inverse frequencies, as HF transformers'
+    ``_compute_yarn_parameters`` makes them (``truncate`` on, its default
+    betas 32 and 1): a linear ramp over the frequency indices [low, high]
+    blends the default frequencies (kept below ``low``) with the same
+    divided by ``factor`` (taken above ``high``)."""
+    beta_fast, beta_slow = 32.0, 1.0
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max_pos
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extrapolation = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extrapolation / factor * ramp + extrapolation * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta, *, freqs=None, scale=1.0):
+    """x: (B, S, H, dh); positions: (B, S) or (S,) int32.  ``freqs``
+    replaces the default frequencies of ``theta`` (YaRN), and ``scale``
+    multiplies cos and sin (YaRN's attention factor)."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)                       # (dh/2,)
+    if freqs is None:
+        freqs = rope_freqs(dh, theta)                   # (dh/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B,S,dh/2)|(S,dh/2)
     if angles.ndim == 2:                                # (S, dh/2) -> (1,S,dh/2)
         angles = angles[None]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -29,14 +29,32 @@ from repro.models.layers import (dense_init, embed_init, init_mlp,
                                  init_rms_norm, mlp_fwd, rms_norm)
 
 
-def layer_cycle(cfg):
-    """The repeating unit of cfg.layers; (cycle, n_units)."""
-    pattern = cfg.layers
+def _unit(cfg):
+    """The repeating unit of (block kind, attention kind) pairs over the
+    layers; (unit, n_units)."""
+    pattern = tuple(zip(cfg.layers, cfg.attn_types))
     n = len(pattern)
     for c in range(1, n + 1):
         if n % c == 0 and pattern == pattern[:c] * (n // c):
             return pattern[:c], n // c
     return pattern, 1
+
+
+def layer_cycle(cfg):
+    """The repeating unit of cfg.layers (and of cfg.attn_types with it);
+    (cycle of block kinds, n_units)."""
+    unit, n_units = _unit(cfg)
+    return tuple(kind for kind, _ in unit), n_units
+
+
+def unit_attn_types(cfg):
+    """Attention kind of each block of the scan unit."""
+    return tuple(t for _, t in _unit(cfg)[0])
+
+
+def unit_windows(cfg):
+    """Attention window of each block of the scan unit (0 = full)."""
+    return tuple(cfg.window_of(t) for t in unit_attn_types(cfg))
 
 
 # ----------------------------------------------------------------------
@@ -100,19 +118,23 @@ def init_transformer(key, cfg):
     if not cfg.tie_embeddings or not cfg.embed_inputs:
         params["lm_head"] = dense_init(keys[-2],
                                        (cfg.d_model, cfg.padded_vocab))
+    pdt = jnp.dtype(cfg.param_dtype)
+    if pdt != jnp.float32:
+        params = jax.tree_util.tree_map(lambda x: x.astype(pdt), params)
     return params
 
 
 # ----------------------------------------------------------------------
 # per-block forward
 # ----------------------------------------------------------------------
-def _block_fwd(bp, kind, x, cfg, positions, cache, image_embeds, window):
+def _block_fwd(bp, kind, x, cfg, positions, cache, image_embeds, window,
+               rope):
     dtype = x.dtype
     eps = cfg.norm_eps
     if kind in ("attn", "moe"):
         h, new_cache = attn_lib.attention_fwd(
             bp["attn"], rms_norm(x, bp["ln1"]["scale"], eps), cfg, positions,
-            window=window, cache=cache)
+            window=window, rope=rope, cache=cache)
         x = x + h
         y = rms_norm(x, bp["ln2"]["scale"], eps)
         if kind == "moe":
@@ -125,7 +147,8 @@ def _block_fwd(bp, kind, x, cfg, positions, cache, image_embeds, window):
         a_cache = cache["attn"] if cache is not None else None
         m_cache = cache["mamba"] if cache is not None else None
         ha, na = attn_lib.attention_fwd(bp["attn"], y, cfg, positions,
-                                        window=window, cache=a_cache)
+                                        window=window, rope=rope,
+                                        cache=a_cache)
         hm, nm = ssm_lib.mamba_fwd(bp["mamba"], y, cfg, state=m_cache)
         h = 0.5 * (rms_norm(ha, bp["lna"]["scale"], eps)
                    + rms_norm(hm, bp["lnm"]["scale"], eps))
@@ -157,10 +180,10 @@ def _block_fwd(bp, kind, x, cfg, positions, cache, image_embeds, window):
 def init_cache(cfg, batch, max_len, *, ring=False, dtype=jnp.bfloat16):
     """Stacked (n_units-leading) cache pytree matching the layer scan."""
     cycle, n_units = layer_cycle(cfg)
-    # ring caches bound memory at the sliding window size
-    W = min(max_len, cfg.sliding_window) if (ring and cfg.sliding_window) else max_len
 
-    def one(kind):
+    def one(kind, window):
+        # ring caches bound memory at the layer's window size
+        W = min(max_len, window) if (ring and window) else max_len
         if kind in ("attn", "moe"):
             return attn_lib.init_kv_cache(cfg, batch, W, ring=ring, dtype=dtype)
         if kind == "hybrid":
@@ -189,7 +212,8 @@ def init_cache(cfg, batch, max_len, *, ring=False, dtype=jnp.bfloat16):
                     "m": jnp.full((batch, H, dh), -1e30, jnp.float32), "h": z}
         raise ValueError(kind)
 
-    unit = {f"b{i}": one(kind) for i, kind in enumerate(cycle)}
+    unit = {f"b{i}": one(kind, w)
+            for i, (kind, w) in enumerate(zip(cycle, unit_windows(cfg)))}
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n_units,) + x.shape), unit)
 
@@ -212,7 +236,7 @@ def forward(params, cfg, *, tokens=None, embeds=None, image_embeds=None,
     if positions is None:
         positions = jnp.arange(S)[None, :]
     cycle, n_units = layer_cycle(cfg)
-    window = cfg.sliding_window
+    kinds = unit_attn_types(cfg)
 
     def unit_fwd(x, unit_params, unit_cache):
         new_cache = {} if unit_cache is not None else None
@@ -220,7 +244,9 @@ def forward(params, cfg, *, tokens=None, embeds=None, image_embeds=None,
         for i, kind in enumerate(cycle):
             c_in = None if unit_cache is None else unit_cache[f"b{i}"]
             x, c_out, a = _block_fwd(unit_params[f"b{i}"], kind, x, cfg,
-                                     positions, c_in, image_embeds, window)
+                                     positions, c_in, image_embeds,
+                                     cfg.window_of(kinds[i]),
+                                     attn_lib.rope_of(cfg, kinds[i]))
             if new_cache is not None:
                 new_cache[f"b{i}"] = c_out
             aux = aux + a

@@ -164,6 +164,12 @@ _r("serve/pages_in_use", KIND_GAUGE,
 _r("serve/tokens_per_s", KIND_GAUGE,
    "measured decode throughput (host wall clock, filled at drain)",
    engines=("serve",), unit="tok/s")
+_r("serve/kv_rows_full", KIND_COUNTER,
+   "KV rows one full-attention layer attended this step, over active "
+   "slots", engines=("serve",), unit="rows")
+_r("serve/kv_rows_window", KIND_COUNTER,
+   "KV rows one window layer attended this step, over active slots",
+   engines=("serve",), unit="rows")
 
 
 def age_hist_len(fed_cfg) -> int:

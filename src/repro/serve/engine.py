@@ -15,6 +15,11 @@ transformer's n_units-leading layer scan axis.  The scheduler context
 broadcast into the per-call cache view (``_with_ctx``) — so the donated
 pools alias in place while the tiny context rides the slot carry.
 
+Two page budgets: full-attention layers share one pool through the
+scheduler's page tables (full reservation at admission); window layers
+hold a static ring of ``attention.ring_pages`` pages per slot, reused in
+place, which needs no allocator traffic.
+
 ``run(requests, continuous=False)`` is the fixed-batch baseline for the
 BENCH comparison: identical admit/decode programs, but admission only
 happens when every slot is empty (classic batch-until-slowest-finishes
@@ -38,33 +43,37 @@ from repro.serve.scheduler import (HostLedger, Request, ServeConfig,
                                    SlotState)
 
 POOL_KEYS = ("kp", "vp", "ks", "vs")
-CTX_KEYS = ("table", "length", "active", "new_valid")
 
 
 def init_paged_cache(cfg, scfg: ServeConfig):
     """Stacked page pools for the layer scan (pools only — the
-    scheduler context is injected per call by _with_ctx)."""
+    scheduler context is injected per call by _with_ctx): the shared
+    pool on full-attention blocks, per-slot rings on window blocks."""
     cycle, n_units = transformer.layer_cycle(cfg)
     if any(k not in ("attn", "moe") for k in cycle):
         raise ValueError(
-            "paged serving supports homogeneous attn/moe stacks, got "
+            "paged serving supports attn/moe stacks, got "
             f"{cycle}")
-    one = attn_lib.init_paged_kv_cache(
-        cfg, scfg.max_slots, scfg.total_pages, scfg.page_size,
-        scfg.pages_per_slot, int8=scfg.kv_int8, dtype=jnp.float32)
-    unit = {f"b{i}": {k: v for k, v in one.items() if k in POOL_KEYS}
-            for i in range(len(cycle))}
+    unit = {}
+    for i, window in enumerate(transformer.unit_windows(cfg)):
+        one = attn_lib.init_paged_kv_cache(
+            cfg, scfg.max_slots, scfg.total_pages, scfg.page_size,
+            scfg.pages_per_slot, int8=scfg.kv_int8, dtype=jnp.float32,
+            window=window)
+        unit[f"b{i}"] = {k: v for k, v in one.items() if k in POOL_KEYS}
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n_units,) + x.shape), unit)
 
 
-def _with_ctx(pools, table, length, active, new_valid):
+def _with_ctx(pools, windows, table, length, active, new_valid, slot):
     """Cache view for one forward call: pools + scheduler context
-    replicated across the stacked layer units."""
-    ctx = {"table": table, "length": length, "active": active,
-           "new_valid": new_valid}
+    replicated across the stacked layer units.  Full-attention blocks
+    read the page table, window blocks the slot of each row."""
     out = {}
     for name, block in pools.items():
+        ring = windows[int(name[1:])] > 0
+        ctx = {"slot" if ring else "table": slot if ring else table,
+               "length": length, "active": active, "new_valid": new_valid}
         n_units = block["kp"].shape[0]
         b = dict(block)
         for k, v in ctx.items():
@@ -83,14 +92,32 @@ def _strip_ctx(cache):
 def kv_bytes_read(cfg, scfg: ServeConfig, pages_in_use: float) -> float:
     """KV bytes one decode step streams from the pools (all layers):
     live pages x rows x heads x head-dim x itemsize x {k, v}, plus the
-    f32 scale planes on the int8 path.  This is the measured-bytes
-    mirror of the BENCH serve rows."""
-    cycle, n_units = transformer.layer_cycle(cfg)
+    f32 scale planes on the int8 path; a window layer reads at most its
+    slots' rings.  This is the measured-bytes mirror of the BENCH serve
+    rows."""
+    _, n_units = transformer.layer_cycle(cfg)
     hkv, dh = cfg.n_kv_heads, cfg.resolved_head_dim
-    rows = pages_in_use * scfg.page_size
     item = 1 if scfg.kv_int8 else 4
-    per_layer = 2.0 * rows * hkv * (dh * item + (4 if scfg.kv_int8 else 0))
-    return per_layer * n_units * len(cycle)
+    row_bytes = 2.0 * hkv * (dh * item + (4 if scfg.kv_int8 else 0))
+    pages = 0.0
+    for window in transformer.unit_windows(cfg):
+        pages += pages_in_use if not window else min(
+            pages_in_use, scfg.max_slots * attn_lib.ring_pages(
+                window, scfg.page_size, scfg.pages_per_slot))
+    return row_bytes * scfg.page_size * pages * n_units
+
+
+def kv_rows(windows, length, active):
+    """The decode step's KV rows attended per layer of each kind, summed
+    over active slots: ``serve/kv_rows_full`` (every row of the slot,
+    with the one appended this step) and ``serve/kv_rows_window`` (the
+    last ``window`` of them); 0 for a kind the model does not have."""
+    n_keys = jnp.where(active > 0, length + 1, 0)
+    window = max(windows)
+    full = n_keys.sum() if 0 in windows else 0
+    win = jnp.minimum(n_keys, window).sum() if window else 0
+    return {"serve/kv_rows_full": jnp.asarray(full, jnp.float32),
+            "serve/kv_rows_window": jnp.asarray(win, jnp.float32)}
 
 
 class ServeEngine:
@@ -117,11 +144,13 @@ class ServeEngine:
     def _make_decode(self):
         cfg, scfg = self.cfg, self.scfg
         s, n, maxp = scfg.max_slots, scfg.total_pages, scfg.pages_per_slot
+        windows = transformer.unit_windows(cfg)
 
         def decode(params, pools, st: SlotState):
             key, sub = jax.random.split(st.key)
-            view = _with_ctx(pools, st.table, st.length, st.active,
-                             jnp.zeros((s,), jnp.int32))
+            view = _with_ctx(pools, windows, st.table, st.length, st.active,
+                             jnp.zeros((s,), jnp.int32),
+                             jnp.arange(s, dtype=jnp.int32))
             logits, new_cache, _ = transformer.forward(
                 params, cfg, tokens=st.tok,
                 positions=st.length[:, None], cache=view)
@@ -149,6 +178,7 @@ class ServeEngine:
                 "serve/tokens": act.sum(),
                 "serve/pages_in_use": n - free.sum(),
                 "serve/tokens_per_s": jnp.float32(0.0),
+                **kv_rows(windows, st.length, act),
             }
             st2 = st._replace(
                 tok=nxt[:, None], length=new_len, active=new_active,
@@ -166,6 +196,7 @@ class ServeEngine:
         cfg, scfg = self.cfg, self.scfg
         s, n, maxp = scfg.max_slots, scfg.total_pages, scfg.pages_per_slot
         pmax = scfg.prompt_pad
+        windows = transformer.unit_windows(cfg)
 
         def admit(params, pools, st: SlotState, prompt, plen, max_new,
                   req_id):
@@ -181,10 +212,10 @@ class ServeEngine:
             # overwrite before any mask exposes them)
             free3 = jnp.where(live, free2, st.free)
             row = jnp.where(ok, pages, 0)
-            view = _with_ctx(pools, row[None],
+            view = _with_ctx(pools, windows, row[None],
                              jnp.zeros((1,), jnp.int32),
                              jnp.ones((1,), jnp.float32),
-                             jnp.where(ok, plen, 0)[None])
+                             jnp.where(ok, plen, 0)[None], slot[None])
             hidden, new_cache, _ = transformer.forward(
                 params, cfg, tokens=prompt[None],
                 positions=jnp.arange(pmax)[None], cache=view,
@@ -206,6 +237,8 @@ class ServeEngine:
                 "serve/tokens": ok.astype(jnp.float32),
                 "serve/pages_in_use": n - free3.sum(),
                 "serve/tokens_per_s": jnp.float32(0.0),
+                "serve/kv_rows_full": jnp.float32(0.0),
+                "serve/kv_rows_window": jnp.float32(0.0),
             }
             st2 = st._replace(
                 tok=st.tok.at[sl].set(tok0[None], mode="drop"),

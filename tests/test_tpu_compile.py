@@ -120,6 +120,27 @@ def test_paged_decode_compiles_at_granite_shapes(one_chip, int8):
             q, k, v, t, l, interpret=False), *args)
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_paged_window_decode_compiles_at_mellum_shapes(one_chip, int8):
+    """mellum2-12b-a2.5b's window layers: 32 query heads over 4 kv heads
+    of 128, 64 slots, rings of ceil(1024/16)+1 = 65 pages; the ring index
+    is scalar arithmetic in the BlockSpec index maps."""
+    slots, ring, page = 64, 65, 16
+    hq, hkv, dh = 32, 4, 128
+    pool = _sd(one_chip, (slots, ring, hkv, page, dh),
+               jnp.int8 if int8 else jnp.float32)
+    args = [_sd(one_chip, (slots, hq, dh)), pool, pool,
+            _sd(one_chip, (slots,), jnp.int32)]
+    if int8:
+        scale = _sd(one_chip, (slots, ring, hkv, 1, page))
+        _compile(lambda q, k, v, l, ks, vs: paged_flash_decode(
+            q, k, v, None, l, k_scale=ks, v_scale=vs, window=1024,
+            interpret=False), *args, scale, scale)
+    else:
+        _compile(lambda q, k, v, l: paged_flash_decode(
+            q, k, v, None, l, window=1024, interpret=False), *args)
+
+
 def test_topd_pallas_compiles_at_population_scale(one_chip):
     _compile(lambda g: topd_pallas(g, 64, interpret=False),
              _sd(one_chip, (1 << 20,)))
