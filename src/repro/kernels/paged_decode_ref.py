@@ -4,7 +4,10 @@ Gathers every slot's pages into a contiguous (S, Hkv, T, dh) K/V block
 via the page table, then runs plain fp32 softmax attention — the same
 shape of oracle as kernels/flash_attention_ref.py.  The Pallas kernel
 (kernels/paged_decode.py) must match this bit-for-bit up to fp32
-accumulation order (tests/test_serve.py pins the atol).
+accumulation order (tests/test_serve.py pins the atol): it sums over
+keys block by block, P pages at a time with an online softmax across
+blocks, where this oracle takes one softmax over every key at once, so
+the two may differ in the order of their fp32 sums and in nothing else.
 
 Contract shared with the kernel:
   q        (S, Hq, dh)        one query token per slot (GQA: Hq = g*Hkv)

@@ -160,13 +160,28 @@ def _rings(seed, s, ring, page, hkv, dh, hq):
 LENGTHS = [0, 3, 14, 15, 47, 90]
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
-def test_window_kernel_matches_oracle(int8):
+# window 200 over pages of 16 rings 14 pages, which the kernel takes in
+# blocks of 8 (the last one part-empty): inactive, inside the first
+# page, shorter than the window, exactly the window, one past it, and
+# two contexts whose live pages wrap the ring
+BLOCK_LENGTHS = [0, 3, 100, 200, 201, 500, 1000]
+
+
+@pytest.mark.parametrize("int8,window,page,heads,lengths", [
+    (int8, *case) for case in [
+        (14, 4, (4, 2, 64), LENGTHS),
+        (200, 16, (4, 2, 64), BLOCK_LENGTHS),
+        (200, 16, (16, 2, 128), BLOCK_LENGTHS)]
+    for int8 in (False, True)],
+    ids=["f32", "int8", "f32-blocks_g2_dh64", "int8-blocks_g2_dh64",
+         "f32-blocks_g8_dh128", "int8-blocks_g8_dh128"])
+def test_window_kernel_matches_oracle(int8, window, page, heads, lengths):
     """fp32 kernel against the oracle: fp32 op order (~3e-7 measured); the
     int8 kernel against the int8 oracle: the same dequant, fp32 order."""
-    window, page, ring = 14, 4, 5
-    q, kp, vp = _rings(int8, len(LENGTHS), ring, page, 2, 64, 4)
-    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    hq, hkv, dh = heads
+    ring = attention.ring_pages(window, page, 1 << 20)
+    q, kp, vp = _rings(int8, len(lengths), ring, page, hkv, dh, hq)
+    lengths = jnp.asarray(lengths, jnp.int32)
     kw = {}
     if int8:
         kp, ks = attention._paged_quant(kp)
@@ -226,11 +241,12 @@ def _grid(window):
 
 
 @pytest.mark.parametrize("window,grid,name", [
-    (1024, (64, 4, 65), "paged_decode_window"),
-    (0, (64, 4, 224), "paged_decode")])
+    (1024, (64, 9), "paged_decode_window"),
+    (0, (64, 28), "paged_decode")])
 def test_kernel_grid_page_axis(window, grid, name):
-    """A window layer's grid walks its ring's ceil(1024/16)+1 = 65 pages,
-    not max_len/page = 224; the full layer's grid is unchanged."""
+    """A grid step takes a block of 8 pages of all 4 kv heads: a window
+    layer's grid walks its ring's ceil(1024/16)+1 = 65 pages in 9 blocks,
+    not max_len/page = 224 pages in 28, as the full layer's does."""
     assert _grid(window) == (grid, name)
 
 

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
-from repro.kernels.paged_decode import paged_flash_decode
+from repro.kernels.paged_decode import block_pages, paged_flash_decode
 from repro.kernels.paged_decode_ref import (dequant_pool, gather_pages,
                                             paged_decode_ref)
 from repro.launch.serve import draw_requests, make_decode_step
@@ -33,8 +33,9 @@ INT8_KERNEL_ATOL = 2e-5
 INT8_QUANT_ATOL = 5e-2
 
 
-def _rand_paged(seed, s, maxp, page, hq, hkv, dh, n_extra=3):
-    """Random pool + table + ragged lengths (incl. one inactive slot)."""
+def _rand_paged(seed, s, maxp, page, hq, hkv, dh, n_extra=3, lengths=None):
+    """Random pool + table + ragged lengths (incl. one inactive slot);
+    ``lengths`` overrides the leading slots' lengths."""
     key = jax.random.PRNGKey(seed)
     n = s * maxp + n_extra
     ks = jax.random.split(key, 5)
@@ -43,12 +44,24 @@ def _rand_paged(seed, s, maxp, page, hq, hkv, dh, n_extra=3):
     vp = jax.random.normal(ks[2], (n, hkv, page, dh), jnp.float32)
     table = jax.random.permutation(ks[3], n)[:s * maxp].reshape(s, maxp)
     # ragged: full pages, partial last page, single row, inactive (0)
-    lengths = jax.random.randint(ks[4], (s,), 1, maxp * page + 1)
-    lengths = lengths.at[0].set(maxp * page)       # every page full
-    lengths = lengths.at[1].set(page + 1)          # ragged last page
+    rand = jax.random.randint(ks[4], (s,), 1, maxp * page + 1)
+    rand = rand.at[0].set(maxp * page)             # every page full
+    rand = rand.at[1].set(page + 1)                # ragged last page
     if s > 2:
-        lengths = lengths.at[2].set(0)             # inactive slot
-    return q, kp, vp, table.astype(jnp.int32), lengths.astype(jnp.int32)
+        rand = rand.at[2].set(0)                   # inactive slot
+    if lengths is not None:
+        rand = rand.at[:len(lengths)].set(jnp.asarray(lengths))
+    return q, kp, vp, table.astype(jnp.int32), rand.astype(jnp.int32)
+
+
+def _block_lengths(page, maxp):
+    """Every page full, a ragged last page, an inactive slot, a length
+    on the kernel's block boundary, one inside a block and one inside
+    the first page, clipped to the slot's capacity."""
+    cap = maxp * page
+    blk = block_pages(maxp, page) * page
+    return [cap, min(page + 1, cap), 0, min(blk, cap),
+            min(blk + page // 2 + 1, cap), min(3, cap)]
 
 
 def _pool_quant(pool):
@@ -60,14 +73,24 @@ def _pool_quant(pool):
 
 
 class TestPagedKernel:
-    @pytest.mark.parametrize("page,maxp", [(4, 6), (8, 3), (16, 2)])
-    def test_parity_vs_ref_across_page_sizes(self, page, maxp):
-        q, kp, vp, table, lengths = _rand_paged(page, 4, maxp, page,
-                                                hq=4, hkv=2, dh=64)
+    # page 16 takes blocks of 8 pages: maxp 11 is not a multiple of the
+    # block, maxp 5 clamps the block to 5; page 32 takes blocks of 4.
+    # g 2 / dh 64 is granite's head shape, g 8 / dh 128 Mellum's.
+    @pytest.mark.parametrize("page,maxp,hq,hkv,dh", [
+        (4, 6, 4, 2, 64), (8, 3, 4, 2, 64), (16, 2, 4, 2, 64),
+        (16, 11, 4, 2, 64), (16, 5, 4, 2, 64), (32, 6, 4, 2, 64),
+        (16, 11, 16, 2, 128), (16, 5, 16, 2, 128)],
+        ids=["4-6", "8-3", "16-2", "16-11", "16-5", "32-6",
+             "g8_dh128-16-11", "g8_dh128-16-5"])
+    def test_parity_vs_ref_across_page_sizes(self, page, maxp, hq, hkv, dh):
+        q, kp, vp, table, lengths = _rand_paged(
+            page, 7, maxp, page, hq=hq, hkv=hkv, dh=dh,
+            lengths=_block_lengths(page, maxp))
         out = paged_flash_decode(q, kp, vp, table, lengths,
                                  interpret=True)
         ref = paged_decode_ref(q, kp, vp, table, lengths)
         np.testing.assert_allclose(out, ref, atol=FP32_ATOL)
+        np.testing.assert_array_equal(np.asarray(out[2]), 0.0)
 
     def test_parity_vs_plain_sdpa(self):
         page, maxp, s = 8, 4, 3
@@ -99,9 +122,11 @@ class TestPagedKernel:
         assert int(lengths[2]) == 0
         np.testing.assert_array_equal(np.asarray(out[2]), 0.0)
 
-    def test_int8_kernel_matches_int8_oracle(self):
-        q, kp, vp, table, lengths = _rand_paged(11, 4, 3, 8,
-                                                hq=4, hkv=2, dh=64)
+    @pytest.mark.parametrize("page,maxp", [(8, 3), (16, 11)])
+    def test_int8_kernel_matches_int8_oracle(self, page, maxp):
+        q, kp, vp, table, lengths = _rand_paged(
+            11, 6, maxp, page, hq=4, hkv=2, dh=64,
+            lengths=_block_lengths(page, maxp))
         kq, ksc = _pool_quant(kp)
         vq, vsc = _pool_quant(vp)
         out = paged_flash_decode(q, kq, vq, table, lengths,

@@ -97,13 +97,18 @@ def test_fused_int8_dequant_compiles(one_chip, agg):
         enc, _sd(one_chip, (c,)), _sd(one_chip, (c,)))
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
-def test_paged_decode_compiles_at_granite_shapes(one_chip, int8):
+@pytest.mark.parametrize("int8,slots,maxp,n", [
+    (False, 8, 12, 96), (True, 8, 12, 96),
+    (False, 32, 128, 2048), (True, 32, 128, 4096)],
+    ids=["f32", "int8", "f32-cell", "int8-cell"])
+def test_paged_decode_compiles_at_granite_shapes(one_chip, int8, slots,
+                                                 maxp, n):
     """granite-moe-1b-a400m: 16 query heads over 8 kv heads, dh=64 — the
-    GQA, sub-128 head dim layout that the pool must tile for."""
+    GQA, sub-128 head dim layout that the pool must tile for; at a small
+    table (maxp 12: blocks of 8 pages, the last part-empty) and at the
+    long cells' 32 slots of 128 pages over 2048 f32 or 4096 int8 pages."""
     cfg = get_config("granite-moe-1b-a400m")
-    slots, page, maxp = 8, 16, 12
-    n = slots * maxp
+    page = 16
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     pool = _sd(one_chip, (n, hkv, page, dh), jnp.int8 if int8 else
                jnp.float32)
@@ -120,25 +125,31 @@ def test_paged_decode_compiles_at_granite_shapes(one_chip, int8):
             q, k, v, t, l, interpret=False), *args)
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
-def test_paged_window_decode_compiles_at_mellum_shapes(one_chip, int8):
-    """mellum2-12b-a2.5b's window layers: 32 query heads over 4 kv heads
-    of 128, 64 slots, rings of ceil(1024/16)+1 = 65 pages; the ring index
-    is scalar arithmetic in the BlockSpec index maps."""
-    slots, ring, page = 64, 65, 16
+@pytest.mark.parametrize("int8,window", [
+    (False, 1024), (True, 1024), (False, 0)],
+    ids=["f32", "int8", "f32-full"])
+def test_paged_window_decode_compiles_at_mellum_shapes(one_chip, int8,
+                                                       window):
+    """mellum2-12b-a2.5b: 32 query heads over 4 kv heads of 128, 64
+    slots; the window layers' rings of ceil(1024/16)+1 = 65 pages, whose
+    ring index is scalar arithmetic in the fetch table, and the full
+    layer's 224-page tables over a pool of 14,336 pages."""
+    slots, ring, page, maxp = 64, 65, 16, 224
     hq, hkv, dh = 32, 4, 128
-    pool = _sd(one_chip, (slots, ring, hkv, page, dh),
+    lead = (slots, ring) if window else (slots * maxp,)
+    pool = _sd(one_chip, lead + (hkv, page, dh),
                jnp.int8 if int8 else jnp.float32)
-    args = [_sd(one_chip, (slots, hq, dh)), pool, pool,
+    table = None if window else _sd(one_chip, (slots, maxp), jnp.int32)
+    args = [_sd(one_chip, (slots, hq, dh)), pool, pool, table,
             _sd(one_chip, (slots,), jnp.int32)]
     if int8:
-        scale = _sd(one_chip, (slots, ring, hkv, 1, page))
-        _compile(lambda q, k, v, l, ks, vs: paged_flash_decode(
-            q, k, v, None, l, k_scale=ks, v_scale=vs, window=1024,
+        scale = _sd(one_chip, lead + (hkv, 1, page))
+        _compile(lambda q, k, v, t, l, ks, vs: paged_flash_decode(
+            q, k, v, t, l, k_scale=ks, v_scale=vs, window=window,
             interpret=False), *args, scale, scale)
     else:
-        _compile(lambda q, k, v, l: paged_flash_decode(
-            q, k, v, None, l, window=1024, interpret=False), *args)
+        _compile(lambda q, k, v, t, l: paged_flash_decode(
+            q, k, v, t, l, window=window, interpret=False), *args)
 
 
 def test_topd_pallas_compiles_at_population_scale(one_chip):
