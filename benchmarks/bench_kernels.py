@@ -6,14 +6,15 @@ roofline (FLOPs, bytes, arithmetic intensity per VMEM tile).
 (kernels/robust_pipeline.py) against the multi-pass XLA reference
 (aggregation.aggregate_ref) and accounts HBM passes analytically.
 ``robust_pipeline/leafwise`` times the segment-table leaf-streaming
-engine against the PR-1 flatten path on a multi-leaf tree, and
-``robust_pipeline/sharded`` the shard_map'd per-client path against the
+engine on a multi-leaf tree, and ``robust_pipeline/sharded`` the
+shard_map'd path (``aggregation.aggregate(..., mesh=)``) against the
 replicated one on however many devices exist (the CI multi-device job
 forces 4 host devices), recording the parity gap vs the XLA oracle.
 ``comm/*`` records the compressed-transport subsystem (repro/comm):
 per-codec encode+decode wall and measured bytes-on-wire per round vs the
-dense uplink, and the int8 fused dequant-into-aggregation kernels vs the
-dense fused engine (agg-byte reduction ~4x at qblk=128).
+dense uplink, and the pipeline's int8 loader
+(``aggregation.aggregate(..., enc=)``) vs its dense loader (agg-byte
+reduction ~4x at qblk=128).
 ``population_select/*`` records the O(M) Gumbel-top-d cohort-selection
 engines (kernels/population_select.py) against the dense argsort
 baseline at M up to 1e6 registered clients.
@@ -35,9 +36,7 @@ from repro.core import aggregation
 from repro.kernels import population_select
 from repro.launch.roofline import peaks
 from repro.kernels.flash_attention_ops import flash_attention
-from repro.kernels.robust_agg_ops import robust_aggregate_tree
-from repro.kernels.robust_pipeline import (fused_aggregate_tree,
-                                           fused_aggregate_tree_flat)
+from repro.kernels.robust_pipeline import fused_aggregate_tree
 
 BENCH_JSON = os.environ.get("BENCH_KERNELS_JSON", "BENCH_kernels.json")
 # the modeled t_*_us terms are TPU v5e roofline bounds, not measurements
@@ -88,9 +87,7 @@ def robust_pipeline_roofline(C, N, aggregator):
       krum    +1 blocked pairwise-distance read        1 pass
 
     The leaf-streaming wrappers hit this kernel-contract roofline
-    end-to-end (a reshape view per leaf, no copy); the PR-1 flatten path
-    adds ~2 passes (concatenate write + re-read) for multi-leaf trees,
-    accounted by ``hbm_passes_flatten`` in the leafwise entries.
+    end-to-end (a reshape view per leaf, no copy).
     """
     ref = {"fedavg": 4.0, "median": 6.0, "trimmed_mean": 6.0, "krum": 5.0}
     fused = {"fedavg": 2.0, "median": 2.0, "trimmed_mean": 2.0, "krum": 3.0}
@@ -126,17 +123,6 @@ def run(budget="small"):
                 "wall_s": 0.0,
                 **flash_roofline(1, 524288, 64, 128, 8192)})
 
-    C = 16
-    tree = {"w": jax.random.normal(key, (C, 1 << 14))}
-    mask = jnp.ones((C,))
-    for mode in ["trimmed", "median"]:
-        t = _time(lambda: robust_aggregate_tree(tree, mask, mode=mode,
-                                                interpret=True))
-        n = tree["w"].size
-        out.append({"name": f"robust_agg/{mode}/C{C}/N{n}", "wall_s": t,
-                    "flops": 3.0 * C * C * n / C,
-                    "bytes": 4.0 * n * (C + 1) / C})
-
     # ---- fused Eq.-11 pipeline vs multi-pass XLA reference ----
     C, N = 16, 1 << 16
     ptree = {"w": jax.random.normal(key, (C, N))}
@@ -162,10 +148,9 @@ def run(budget="small"):
         r.update(robust_pipeline_roofline(C, N, agg))
         out.append(r)
 
-    # ---- leaf-streaming (segment-table) engine vs the PR-1 flatten path
-    # multi-leaf tree totalling N=65536 coords: matrix-shaped leaves plus
-    # a ragged one and a tiny bias (the shapes that forced the flatten
-    # path's (C, N) concatenate + unflatten copies)
+    # ---- leaf-streaming (segment-table) engine on a multi-leaf tree
+    # totalling N=65536 coords: matrix-shaped leaves plus a ragged one
+    # and a tiny bias
     sizes = [(1 << 14,), (128, 128), (64, 256), (16_000,), (379,), (5,)]
     ltree = {f"l{j}": jax.random.normal(jax.random.fold_in(key, j),
                                        (C,) + s)
@@ -173,24 +158,10 @@ def run(budget="small"):
     n_tot = sum(int(jnp.prod(jnp.asarray(s))) for s in sizes)
     for agg in aggs:
         cfg = FedConfig(n_clients=C, aggregator=agg)
-        t_flat, t_leaf = float("inf"), float("inf")
-        for _ in range(7):                         # interleaved (see above)
-            # flatten baseline runs at blk=4096 — the default the PR-1
-            # aggregate() hot path actually shipped with
-            t_flat = min(t_flat, _time(
-                lambda: fused_aggregate_tree_flat(ltree, pw, pmask, cfg,
-                                                  blk=4096), reps=1))
-            t_leaf = min(t_leaf, _time(
-                lambda: fused_aggregate_tree(ltree, pw, pmask, cfg),
-                reps=1))
+        t_leaf = _time(lambda: fused_aggregate_tree(ltree, pw, pmask, cfg))
         r = {"name": f"robust_pipeline/leafwise/{agg}/C{C}/N{n_tot}",
-             "wall_s": t_leaf, "wall_s_flatten": t_flat,
-             "flatten_blk": 4096,
-             "speedup_vs_flatten": t_flat / t_leaf}
-        roof = robust_pipeline_roofline(C, n_tot, agg)
-        r.update(roof)
-        # flatten adds one concatenate write + one re-read of (C, N)
-        r["hbm_passes_flatten"] = roof["hbm_passes_fused"] + 2.0
+             "wall_s": t_leaf}
+        r.update(robust_pipeline_roofline(C, n_tot, agg))
         out.append(r)
 
     # ---- mesh-sharded per_client path vs replicated, on whatever devices
@@ -201,8 +172,8 @@ def run(budget="small"):
     mesh = Mesh(np.array(jax.devices()).reshape(D), ("data",))
     for agg in aggs:
         cfg = FedConfig(n_clients=C, aggregator=agg)
-        sh_fn = jax.jit(lambda t, w, m, cfg=cfg: aggregation.aggregate_sharded(
-            t, w, m, cfg, mesh, axes=("data",)))
+        sh_fn = jax.jit(lambda t, w, m, cfg=cfg: aggregation.aggregate(
+            t, w, m, cfg, mesh=mesh, axes=("data",)))
         t_sh, t_rep = float("inf"), float("inf")
         for _ in range(5):
             t_rep = min(t_rep, _time(
@@ -231,7 +202,6 @@ def run(budget="small"):
     # same multi-leaf tree as the leafwise section; bytes are MEASURED
     # from the encoded arrays (codes + scales + indices), not modelled
     from repro.comm import codecs as comm_codecs
-    from repro.comm.kernels import comm_codecs as dq
 
     dense_pc = comm_codecs.dense_bytes_per_client(ltree)
     codec_names = ["int8", "topk"] if budget == "small" else \
@@ -258,13 +228,13 @@ def run(budget="small"):
             "bytes_on_wire_per_round": wire_pc * C,
         })
 
-    # fused dequant-into-aggregation vs the dense fused engine: the
-    # aggregation passes stream int8 codes + scales (~C*N*(1 + 4/qblk)
-    # bytes/pass) instead of C*N*4 — wall measured, bytes analytic
+    # the int8 loader vs the dense loader: the aggregation passes stream
+    # int8 codes + scales (~C*N*(1 + 4/qblk) bytes/pass) instead of
+    # C*N*4 — wall measured, bytes analytic
     for agg in aggs:
         cfg = FedConfig(n_clients=C, aggregator=agg, compress="int8")
-        dq_fn = jax.jit(lambda e, w, m, cfg=cfg: dq.fused_dequant_aggregate_tree(
-            e, w, m, cfg, like=ltree))
+        dq_fn = jax.jit(lambda e, w, m, cfg=cfg: aggregation.aggregate(
+            ltree, w, m, cfg, enc=e))
         t_dense, t_dq = float("inf"), float("inf")
         for _ in range(5):                         # interleaved (see above)
             t_dense = min(t_dense, _time(
@@ -391,11 +361,7 @@ def bench_pod_scan_driver(rounds=8, chunk=4):
 def main(budget="small"):
     results = run(budget)
     for r in results:
-        if "speedup_vs_flatten" in r:
-            extra = (f"speedup_vs_flatten={r['speedup_vs_flatten']:.2f}x "
-                     f"hbm_passes={r['hbm_passes_fused']:.0f}"
-                     f"/{r['hbm_passes_flatten']:.0f}")
-        elif "speedup_vs_replicated" in r:
+        if "speedup_vs_replicated" in r:
             extra = (f"speedup_vs_replicated="
                      f"{r['speedup_vs_replicated']:.2f}x dev={r['devices']} "
                      f"parity={r['parity_max_abs_diff']:.1e}")

@@ -127,8 +127,7 @@ def phase_fl_round(seed, *, rounds=6):
     t0 = time.perf_counter()
     fused, _ = jax.block_until_ready(compiled(state, batch))
     run_s = time.perf_counter() - t0
-    oracle_cfg = dataclasses.replace(cfg, fused_agg=False,
-                                     fused_dequant=False)
+    oracle_cfg = dataclasses.replace(cfg, fused_agg=False)
     oracle, _ = round_jit(oracle_cfg)(state, batch)
     diff = _max_abs_diff(fused.params, oracle.params)
     _check(checks, "round_vs_xla_oracle_max_abs", diff,
@@ -272,8 +271,8 @@ def phase_four_chips(seed, *, arch="granite-moe-1b-a400m", cfg=None,
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
 
-    from repro.comm.kernels import comm_codecs as dq
     from repro.configs.base import FedConfig
+    from repro.core import aggregation
     from repro.configs.registry import get_config
     from repro.models.model import build
 
@@ -305,8 +304,8 @@ def phase_four_chips(seed, *, arch="granite-moe-1b-a400m", cfg=None,
     weights = jnp.ones((clients,), jnp.float32)
     mask = jnp.ones((clients,), jnp.float32)
 
-    agg = jax.jit(lambda e, w, m: dq.fused_dequant_aggregate_sharded(
-        e, w, m, fed, mesh, like=like, axes=("model",)))
+    agg = jax.jit(lambda e, w, m: aggregation.aggregate(
+        like, w, m, fed, enc=e, mesh=mesh, axes=("model",)))
     t0 = time.perf_counter()
     compiled = agg.lower(enc, weights, mask).compile()
     compile_s = time.perf_counter() - t0
@@ -330,8 +329,8 @@ def phase_four_chips(seed, *, arch="granite-moe-1b-a400m", cfg=None,
     out = {i: out[i] for i in subset}            # free the rest
     one = SingleDeviceSharding(devices[0])
     enc_one = [jax.device_put(enc[i], one) for i in subset]
-    single = jax.jit(lambda e, w, m: dq.fused_dequant_aggregate_tree(
-        e, w, m, fed, like=[like[i] for i in subset]))
+    single = jax.jit(lambda e, w, m: aggregation.aggregate(
+        [like[i] for i in subset], w, m, fed, enc=e))
     out_one = single(enc_one, jax.device_put(weights, one),
                      jax.device_put(mask, one))
     names = [jax.tree_util.keystr(p) for p, _ in

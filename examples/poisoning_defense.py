@@ -1,6 +1,6 @@
 """Model-poisoning defense A/B: sign-flip byzantine clients vs the
 aggregator zoo (FedAvg mean, coordinate median, trimmed mean, Krum) and
-the Pallas robust-aggregation kernel on the same updates — plus the
+the fused Pallas Eq.-11 pipeline on the same updates — plus the
 compressed-transport walkthrough: the same attack under the int8 uplink
 codec (repro/comm/), where the server aggregates STRAIGHT from the wire
 codes (fused dequant) and bills the measured encoded bytes.
@@ -13,9 +13,8 @@ import numpy as np
 
 from repro.configs.base import FedConfig
 from repro.configs.registry import ARCHS
-from repro.core import attacks, fedfits
+from repro.core import aggregation, attacks, fedfits
 from repro.data.pipeline import build_federation
-from repro.kernels.robust_agg_ops import robust_aggregate_tree
 from repro.models.model import build
 
 K, ROUNDS, N_MAL = 10, 12, 2
@@ -52,9 +51,10 @@ for agg in ["fedavg", "median", "trimmed_mean", "krum"]:
 # ---- defense under the compressed uplink (repro/comm/) -----------------
 # sign-flip attackers + int8 transport: the trimmed-mean defense must
 # keep working on the WIRE CODES — the cosine gate and rank network run
-# inside the fused dequant kernels, never materialising dense per-client
-# updates on the server.  Bytes below are MEASURED from the encoded
-# arrays (codes + per-block scales), not an analytic 4-bytes-per-param.
+# inside the fused pipeline, whose int8 loader never materialises dense
+# per-client updates on the server.  Bytes below are MEASURED from the
+# encoded arrays (codes + per-block scales), not an analytic
+# 4-bytes-per-param.
 print("\nsign-flip attackers under the compressed uplink "
       "(trimmed_mean defense):")
 for comp in ["none", "int8"]:
@@ -93,13 +93,15 @@ for cell in ["clean_trimmed", "alie_fedavg", "alie_trimmed",
           f"{s['fair_acc_var']:8.4f} {s['fair_part_gini']:5.2f} "
           f"{s['gated_frac_mean']:6.2f}")
 
-# ---- the Pallas kernel on one poisoned round of updates ----------------
+# ---- the fused Pallas pipeline on one poisoned round of updates --------
 key = jax.random.PRNGKey(3)
 honest = {"w": jax.random.normal(key, (K, 512)) * 0.01 + 1.0}
 poisoned = attacks.sign_flip(honest, malicious, scale=10.0)
-for mode in ["trimmed", "median"]:
-    out = robust_aggregate_tree(poisoned, jnp.ones((K,)), mode=mode)
-    print(f"pallas robust_agg[{mode}] mean coordinate "
+for agg in ["trimmed_mean", "median"]:
+    cfg = FedConfig(n_clients=K, aggregator=agg)
+    out = jax.jit(lambda u, w, m, cfg=cfg: aggregation.aggregate(
+        u, w, m, cfg))(poisoned, jnp.ones((K,)), jnp.ones((K,)))
+    print(f"pallas Eq.-11 pipeline [{agg}] mean coordinate "
           f"= {float(np.mean(np.asarray(out['w']))):.3f} "
           f"(honest value 1.0; naive mean "
           f"{float(np.mean(np.asarray(poisoned['w']))):.3f})")

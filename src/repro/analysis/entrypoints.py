@@ -185,8 +185,8 @@ def _build_aggregate_sharded():
     mask = jnp.ones((c,))
 
     def fn(u, ww, m):
-        return aggregation.aggregate_sharded(u, ww, m, cfg, mesh,
-                                             axes=("data",))
+        return aggregation.aggregate(u, ww, m, cfg, mesh=mesh,
+                                     axes=("data",))
 
     # only the (C,) cosine partials + per-leaf scales (and Krum's (C,C)
     # Gram) may cross devices; the per-leaf payload bytes/chip stay far

@@ -22,8 +22,8 @@ All encode/decode paths are jit-able per leaf (static shapes; the only
 data-dependent op is topk's ``lax.top_k``).  Encoded leaves are pytrees
 (NamedTuples), so an encoded tree threads through ``lax.scan`` carries,
 ``shard_map`` and donation like any other state.  The int8 format is
-additionally consumed *without decoding* by the fused dequant-into-
-aggregation Pallas kernels in ``comm/kernels/comm_codecs.py``.
+additionally consumed *without decoding* by the ``Int8`` block loader of
+the fused Eq.-11 pipeline (``kernels/robust_pipeline.py``).
 
 Compression error handling (EF residuals) lives in
 ``comm/error_feedback.py``; this module is purely the wire format.
@@ -86,7 +86,7 @@ def quant_encode(x2, qblk, levels):
     """Blockwise absmax quantization of a (K, n) matrix to
     ``levels``-level symmetric codes: q (K, n) int8 in [-levels, levels],
     s (K, nq) f32 scales.  dec = q * s[block] (the exact multiply the
-    fused dequant kernel replays in VMEM — bit-identical)."""
+    pipeline's int8 loader replays in VMEM — bit-identical)."""
     K, n = x2.shape
     b, nq = _blocks(x2, qblk)
     amax = jnp.max(jnp.abs(b), axis=2)                       # (K, nq)

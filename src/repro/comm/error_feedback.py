@@ -18,9 +18,9 @@ carried through the ``ScanDriver`` donated carry as ``FedState.ef`` /
 
 ``compress`` is the one-call client boundary: EF inject -> encode ->
 decode -> residual update.  The decode it returns is what a dense-path
-server would aggregate; the int8 fused-dequant server path aggregates
-the ENCODED form directly (bit-identical — see
-``comm/kernels/comm_codecs.py``) and still uses the same residual.
+server would aggregate; an int8 server aggregates the ENCODED form
+directly (bit-identical — the ``Int8`` loader of
+``kernels/robust_pipeline.py``) and still uses the same residual.
 """
 from __future__ import annotations
 

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Tuple
 
 
 @dataclass(frozen=True)
@@ -216,11 +216,9 @@ class FedConfig:
     trim_frac: float = 0.2            # trimmed-mean fraction per side
     krum_f: int = 1                   # assumed byzantine count for Krum
     fused_agg: bool = True            # route Eq.-11 through the fused
-                                      # two-pass Pallas pipeline (False ->
-                                      # multi-pass XLA reference)
-    agg_blk: Optional[int] = None     # fused-pipeline streaming block size;
-                                      # None -> autotuned from backend +
-                                      # VMEM budget (robust_pipeline.auto_blk)
+                                      # two-pass Pallas pipeline (int8
+                                      # uplinks straight from the codes;
+                                      # False -> multi-pass XLA reference)
     paper_exact_agg: bool = False     # reproduce Algorithm 1's n_k/|S_t| literal
     # compressed client->server transport (repro/comm/)
     compress: str = "none"            # none|int8|int4|signsgd|topk|randk
@@ -228,10 +226,6 @@ class FedConfig:
     compress_topk_frac: float = 0.05  # top-k kept fraction per leaf
     error_feedback: bool = True       # EF residual (carried in the scan
                                       # carry) re-injects compression error
-    fused_dequant: bool = True        # int8: aggregate straight from the
-                                      # wire codes (dequant in VMEM inside
-                                      # the fused Eq.-11 kernels; False ->
-                                      # decode-then-aggregate oracle)
     # aggregation-boundary guard: NaN/Inf or absurd-norm deliveries are
     # rejected (zeroed + masked out) with a gate-trust penalty instead of
     # entering the global model

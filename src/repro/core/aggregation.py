@@ -12,23 +12,38 @@ and weights (K,) already normalised by the caller.
 plus the trust machinery: EWMA trust decay and gradient-cosine outlier
 gating, and the two-stage slot-internal -> cross-slot combine.
 
-The full Eq.-11 pipeline (median reference -> cosine gate -> aggregator)
-runs by default through the fused two-pass Pallas engine in
-kernels/robust_pipeline.py (``aggregate``/``two_stage`` dispatch on
-cfg.fused_agg); the multi-pass XLA implementations here remain the
-parity oracles (``aggregate_ref``/``two_stage_ref``).  The engine
-streams pytrees leaf-wise (segment-table grid, no flatten concatenate)
-with the block size autotuned unless cfg.agg_blk pins it.  On a mesh,
-``aggregate_sharded`` runs the same pipeline with the flattened param
-axis sharded over devices (shard_map): both passes stream shard-locally
-and only the (C,) cosine partials / Krum Gram matrix cross devices in
-one psum.  The standalone kernel in kernels/robust_agg.py keeps the
-bare masked trimmed-mean / median contract.
+``aggregate`` is the one entry point of the full Eq.-11 pipeline (median
+reference -> cosine gate -> aggregator) and the one place that chooses
+how it runs, from what it can observe:
+
+  cfg.fused_agg False     the multi-pass XLA reference ``aggregate_ref``
+                          on the (decoded) updates — the parity oracle
+  an int8 ``enc`` whose   the fused two-pass Pallas engine of
+  blocks ``fusable``      kernels/robust_pipeline.py with its ``Int8``
+  accepts                 loader: the server aggregates straight from the
+                          wire codes, dequantized in VMEM
+  anything else           the same engine with its ``DENSE`` loader
+  a ``mesh``              either loader under one shard_map: the
+                          flattened param axis (codes with their scale
+                          columns) shards over the mesh, both passes
+                          stream shard-locally, and only the (C,) cosine
+                          partials / Krum Gram matrix cross devices in
+                          one psum
+
+``two_stage`` batches the cohorts of the two-stage scheme on the
+engine's grid (``two_stage_ref`` is its oracle).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.comm import codecs
+from repro.kernels import robust_pipeline as rp
+from repro.sharding import specs as sh
 
 _BIG = 1e30
 
@@ -255,10 +270,10 @@ def update_trust(trust, scores, mask, decay):
 
 def aggregate_ref(updates, weights, mask, cfg):
     """Multi-pass XLA reference for the Eq.-11 pipeline: applies the
-    gradient-cosine outlier gate first (robust pipeline of DESIGN.md §1
-    item 5), then the configured aggregator.  Kept as the parity oracle
-    for the fused Pallas engine (kernels/robust_pipeline.py), which
-    replaces these ~4 sort-based passes with 2 streaming passes.
+    gradient-cosine outlier gate first, then the configured aggregator.
+    Kept as the parity oracle for the fused Pallas engine
+    (kernels/robust_pipeline.py), which replaces these ~4 sort-based
+    passes with 2 streaming passes.
 
     The gate's reference direction is the coordinate MEDIAN, not the mean:
     a mean reference is itself corruptible (large-magnitude poison flips
@@ -282,74 +297,85 @@ def aggregate_ref(updates, weights, mask, cfg):
     raise ValueError(cfg.aggregator)
 
 
-def aggregate(updates, weights, mask, cfg):
-    """Dispatch on cfg.aggregator.  Routes through the fused two-pass
-    Pallas engine (kernels/robust_pipeline.py; interpret mode off-TPU)
-    unless cfg.fused_agg is False, in which case the multi-pass XLA
-    reference runs instead."""
-    if getattr(cfg, "fused_agg", True):
-        from repro.kernels.robust_pipeline import fused_aggregate_tree
-        return fused_aggregate_tree(updates, weights, mask, cfg,
-                                    blk=getattr(cfg, "agg_blk", None))
-    return aggregate_ref(updates, weights, mask, cfg)
+def aggregate(updates, weights, mask, cfg, *, enc=None, mesh=None,
+              axes=None):
+    """Eq.-11 aggregation of a pytree of (C, ...) updates -> a pytree of
+    (...).  ``enc`` is the same updates' wire encoding
+    (``comm/codecs.py``), when the uplink has one; ``updates`` then are
+    its decode and fix the output shapes and dtypes.  ``mesh`` (with
+    ``axes``, default every mesh axis but "pod") shards the streaming
+    passes over devices.  See the module docstring for which path runs."""
+    if not getattr(cfg, "fused_agg", True):
+        return aggregate_ref(updates, weights, mask, cfg)
+    like, treedef = jax.tree_util.tree_flatten(updates)
+    loader, leaves = _loader_leaves(like, enc, cfg)
+    run = functools.partial(
+        rp.fused_pipeline_leafwise, loader=loader,
+        aggregator=cfg.aggregator, trim_frac=cfg.trim_frac,
+        cosine_thresh=cfg.cosine_outlier_thresh, krum_f=cfg.krum_f,
+        out_dtypes=[l.dtype for l in like])
+    if mesh is None:
+        outs = run(leaves, weights[None], mask[None])
+    else:
+        outs = _shard_mapped(run, loader, leaves, weights, mask, mesh, axes)
+    outs = [o.reshape(l.shape[1:]) for o, l in zip(outs, like)]
+    return jax.tree_util.tree_unflatten(treedef, outs)
 
 
-def aggregate_sharded(updates, weights, mask, cfg, mesh, axes=None):
-    """Mesh-sharded Eq.-11 aggregation over a pytree of (C, ...) leaves.
+def _loader_leaves(like, enc, cfg):
+    """The engine's loader and its per-leaf (1, C, n) views (reshapes,
+    no copy): the int8 codes with their scale rows when ``enc`` is int8
+    and every streaming block is tiled by the quant block, else the
+    dense updates."""
+    c = like[0].shape[0]
+    if enc is not None:
+        codes = jax.tree_util.tree_flatten(enc, is_leaf=codecs.is_encoded)[0]
+        qblk = getattr(cfg, "compress_qblk", 128)
+        if (all(isinstance(e, codecs.QuantLeaf) and e.q.dtype == jnp.int8
+                for e in codes)
+                and rp.fusable([e.q.shape[1] for e in codes], c, qblk)):
+            return rp.Int8(qblk), [(e.q.reshape(1, c, -1),
+                                    e.s.reshape(1, c, -1)) for e in codes]
+    return rp.DENSE, [l.reshape(1, c, -1) for l in like]
 
-    Each leaf's flattened parameter axis is sharded over the ``axes``
-    mesh axes (default: every axis except "pod"), so every device streams
-    only its shard through both fused passes; only the (C,) cosine
-    partials — and Krum's (C, C) Gram matrix — cross devices, in one
-    psum.  Leaves whose size does not divide the axis extent stay
-    replicated; a 0/1 per-leaf scale keeps them from being double-counted
-    in the psum.  Semantically equivalent to ``aggregate`` (parity atol
-    ~1e-5 from the shard-local summation order)."""
-    from jax.sharding import PartitionSpec as P
 
-    from repro.kernels import robust_pipeline as rp
-    from repro.sharding import specs as sh
-
+def _shard_mapped(run, loader, leaves, weights, mask, mesh, axes):
+    """``run`` under shard_map: each leaf whose width divides
+    ``extent * loader.align`` shards its last axis — every array of the
+    leaf, so int8 codes keep their own scale columns — and the rest stay
+    replicated, de-duplicated by a 0/1 per-leaf scale before the psum.
+    Parity with the unsharded path is ~1e-5, from the shard-local
+    summation order."""
     if axes is None:
         axes = tuple(a for a in mesh.axis_names if a != "pod")
     axes = tuple(axes)
-    leaves, treedef = jax.tree_util.tree_flatten(updates)
-    C = leaves[0].shape[0]
-    flat = [l.reshape(1, C, -1) for l in leaves]          # views, no copy
-    in_shardings, shard_flags = sh.client_flat_shardings(
-        [f.shape[-1] for f in flat], mesh, axes)
-    in_specs = tuple(s.spec for s in in_shardings)
-    out_specs = tuple(P(None, axes) if f else P(None, None)
-                      for f in shard_flags)
-    # constrain the flat views to the shard_map input layout BEFORE the
-    # boundary: GSPMD then materialises the producer's outputs (e.g. the
-    # vmap'd per-client backward) directly in the (C, shard) layout, so
-    # the shard_map entry is a no-op instead of an all-to-all reshard
-    # (jaxpr-guarded in tests/test_sharded_agg.py)
-    flat = [jax.lax.with_sharding_constraint(f, s)
-            for f, s in zip(flat, in_shardings)]
+    specs, flags = sh.client_flat_specs([loader.width(l) for l in leaves],
+                                        mesh, axes, align=loader.align)
+    flat, struct = jax.tree_util.tree_flatten(leaves)
+    flat_specs = [sp for l, sp in zip(leaves, specs)
+                  for _ in jax.tree_util.tree_leaves(l)]
+    # constrain the views to the shard_map input layout BEFORE the
+    # boundary: GSPMD then materialises the producer's outputs (the
+    # vmap'd per-client backward, the encoder) directly in the
+    # (C, shard) layout, so the shard_map entry is a no-op instead of an
+    # all-to-all reshard (jaxpr-guarded in tests/test_sharded_agg.py)
+    flat = [jax.lax.with_sharding_constraint(f, NamedSharding(mesh, sp))
+            for f, sp in zip(flat, flat_specs)]
 
-    def agg(w, m, *fl):
+    def body(w, m, *arrs):
         own = jnp.float32(1.0)
         for a in axes:                                    # linear-index == 0
             own = own * (jax.lax.axis_index(a) == 0).astype(jnp.float32)
-        scale = jnp.stack([jnp.float32(1.0) if f else own
-                           for f in shard_flags])
-        outs = rp.fused_pipeline_leafwise(
-            list(fl), w[None], m[None],
-            aggregator=cfg.aggregator, trim_frac=cfg.trim_frac,
-            cosine_thresh=cfg.cosine_outlier_thresh, krum_f=cfg.krum_f,
-            blk=getattr(cfg, "agg_blk", None),
-            axis_name=axes, leaf_scale=scale,
-            out_dtypes=[l.dtype for l in leaves])
-        return tuple(outs)
+        scale = jnp.stack([jnp.float32(1.0) if f else own for f in flags])
+        return tuple(run(jax.tree_util.tree_unflatten(struct, arrs),
+                         w[None], m[None], axis_name=axes,
+                         leaf_scale=scale))
 
-    wrapped = jax.shard_map(agg, mesh=mesh,
-                            in_specs=(P(None), P(None)) + tuple(in_specs),
-                            out_specs=out_specs, check_vma=False)
-    outs = wrapped(weights, mask, *flat)
-    outs = [o.reshape(l.shape[1:]) for o, l in zip(outs, leaves)]
-    return jax.tree_util.tree_unflatten(treedef, outs)
+    out_specs = tuple(P(None, axes) if f else P(None, None) for f in flags)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None), P(None)) + tuple(flat_specs),
+                         out_specs=out_specs, check_vma=False)(
+        weights, mask, *flat)
 
 
 def two_stage_ref(slot_updates, slot_weights, slot_masks, cfg):
@@ -374,7 +400,6 @@ def two_stage(slot_updates, slot_weights, slot_masks, cfg):
     cohorts ride the G grid axis of ONE fused ``pallas_call`` when
     cfg.fused_agg (default); the vmapped XLA oracle runs otherwise."""
     if getattr(cfg, "fused_agg", True):
-        from repro.kernels.robust_pipeline import fused_two_stage_tree
-        return fused_two_stage_tree(slot_updates, slot_weights, slot_masks,
-                                    cfg, blk=getattr(cfg, "agg_blk", None))
+        return rp.fused_two_stage_tree(slot_updates, slot_weights,
+                                       slot_masks, cfg)
     return two_stage_ref(slot_updates, slot_weights, slot_masks, cfg)

@@ -16,7 +16,7 @@ bill only the team).
 Transport: with `FedConfig.compress` the client->server boundary runs
 through the comm subsystem (repro/comm/) — updates cross the wire
 encoded (EF residuals in the scan carry), the int8 path aggregates
-straight from the codes (fused dequant kernels), and
+straight from the codes (``aggregation.aggregate(..., enc=)``), and
 `cost_bytes_up/down` bill the MEASURED wire sizes instead of an
 analytic 2*|params|*4 model.
 """
@@ -336,20 +336,12 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
             else:
                 weights = data["n"].astype(jnp.float32) * state.trust \
                     * (delivered + stale)
-                part_mask = (part > 0).astype(jnp.float32)
-                from repro.comm.kernels import comm_codecs as dq
-                if enc is not None and dq.should_fuse(codec, fed_cfg,
-                                                      updates):
-                    # server aggregates STRAIGHT from the int8 wire
-                    # codes: dequant happens in VMEM inside the fused
-                    # Eq.-11 passes (bit-identical to aggregating `dec`;
-                    # ~4x less agg HBM)
-                    agg = dq.fused_dequant_aggregate_tree(
-                        enc, weights, part_mask, fed_cfg, like=updates,
-                        blk=getattr(fed_cfg, "agg_blk", None))
-                else:
-                    agg = aggregation.aggregate(updates, weights,
-                                                part_mask, fed_cfg)
+                # an int8 uplink aggregates STRAIGHT from the wire codes
+                # (dequant in VMEM inside the fused Eq.-11 passes,
+                # bit-identical to aggregating the decoded `updates`)
+                agg = aggregation.aggregate(
+                    updates, weights, (part > 0).astype(jnp.float32),
+                    fed_cfg, enc=enc)
         with obs_annotate("writeback"):
             new_params = jax.tree_util.tree_map(
                 lambda p, u: p + u.astype(p.dtype), state.params, agg)

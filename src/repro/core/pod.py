@@ -19,7 +19,7 @@ coordinate-robust aggregators through the two-pass Pallas engine
 twice instead of sorted ~4 times, leaf-wise (segment-table grid — no
 (C, N_params) flatten concatenate).  With ``agg_mesh`` the flattened
 param axis additionally shards over the mesh
-(aggregation.aggregate_sharded): every device streams only its shard in
+(``aggregation.aggregate(..., mesh=)``): every device streams its shard in
 both passes and only the (C,) cosine partials (+ Krum's Gram matrix)
 cross devices in one psum, so per-device HBM traffic drops by the mesh
 size instead of replicating the whole grad matrix; the grads are
@@ -141,9 +141,9 @@ def make_train_step(model_cfg, fed_cfg, train_cfg, *, robust=None,
 
     agg_mesh / agg_axes: with robust='per_client', shard the robust
     aggregation's flattened param axis over these mesh axes (default:
-    every axis but "pod") via aggregation.aggregate_sharded — both fused
-    passes then stream shard-locally instead of replicating the whole
-    (C, N_params) grad matrix on every device.
+    every axis but "pod") via ``aggregation.aggregate(..., mesh=)`` —
+    both fused passes then stream shard-locally instead of replicating
+    the whole (C, N_params) grad matrix on every device.
     """
     C = fed_cfg.n_clients
     opt_init, opt_update = optimizers.make_optimizer(train_cfg)
@@ -226,22 +226,9 @@ def make_train_step(model_cfg, fed_cfg, train_cfg, *, robust=None,
                     else None)
                 bytes_up_pc = comm_codecs.wire_bytes_per_client(enc)
                 grads_c = dec
-            from repro.comm.kernels import comm_codecs as dq
-            if enc is not None and dq.should_fuse(codec, fed_cfg, grads_c):
-                if agg_mesh is not None:
-                    grads = dq.fused_dequant_aggregate_sharded(
-                        enc, w, fed.team, fed_cfg, agg_mesh, like=grads_c,
-                        axes=agg_axes)
-                else:
-                    grads = dq.fused_dequant_aggregate_tree(
-                        enc, w, fed.team, fed_cfg, like=grads_c,
-                        blk=getattr(fed_cfg, "agg_blk", None))
-            elif agg_mesh is not None and getattr(fed_cfg, "fused_agg",
-                                                  True):
-                grads = aggregation.aggregate_sharded(
-                    grads_c, w, fed.team, fed_cfg, agg_mesh, axes=agg_axes)
-            else:
-                grads = aggregation.aggregate(grads_c, w, fed.team, fed_cfg)
+            grads = aggregation.aggregate(grads_c, w, fed.team, fed_cfg,
+                                          enc=enc, mesh=agg_mesh,
+                                          axes=agg_axes)
         else:
             (_, (loss_c, acc_c)), grads = jax.value_and_grad(
                 weighted_loss, has_aux=True)(state.params, batch, w)

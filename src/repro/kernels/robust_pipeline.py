@@ -2,58 +2,66 @@
 
 The trust-aware robust aggregation of paper Eq. 11 (median reference ->
 gradient-cosine outlier gate -> trimmed-mean / median / weighted-mean /
-Krum) as TWO streaming passes over the cohort-batched client update
-matrix, instead of the ~4+ independent sort-based XLA passes of the
-reference path in ``core/aggregation.py``:
+Krum) as TWO streaming passes over the cohort-batched client updates,
+instead of the ~4+ independent sort-based XLA passes of the reference
+path in ``core/aggregation.py``:
 
   pass 1   streams (C, blk) blocks once.  Per block it computes the
            coordinate-median reference with the O(C^2) stable-rank
-           network (shared with kernels/robust_agg.py) AND accumulates
-           the per-client cosine partials — dot(x_i, ref), ||x_i||^2,
-           ||ref||^2 — into (C,) VMEM accumulators that live across the
-           whole N sweep (init at block 0, revisited every block).  The
-           median itself stays in VMEM: only the O(C) partials reach HBM.
+           network AND accumulates the per-client cosine partials —
+           dot(x_i, ref), ||x_i||^2, ||ref||^2 — into (C,) VMEM
+           accumulators that live across the whole sweep (init at step
+           0, revisited every step).  Only the O(C) partials reach HBM.
 
   gate     resolved on-device between the passes from the (G, C)
-           accumulators: O(G*C) jnp scalars, no host round-trip, no
-           re-read of the update matrix.
+           accumulators: O(G*C) jnp scalars, no host round-trip.
 
   pass 2   streams the blocks once more, applying the gated mask (and
            the caller's trust weights for the mean modes) to emit the
            final aggregated row: trimmed mean / median via the rank
            network, or the normalised weighted mean.
 
-  krum     an extra blocked pairwise-distance kernel accumulates the
-           (C, C) Gram matrix in one more streaming pass; the O(C^2)
-           Krum scoring runs on-device in jnp and the winners are
-           averaged by pass 2 in ``mean`` mode.
+  krum     one more streaming pass accumulates the (C, C) Gram matrix;
+           the O(C^2) Krum scoring runs on-device in jnp and the winners
+           are averaged by pass 2 in ``mean`` mode.
 
 The leading G (cohort) grid axis batches every slot of the two-stage
-scheme in ONE ``pallas_call`` — the reference's per-cohort Python loop
-becomes a grid dimension.
+scheme in ONE ``pallas_call`` per pass.
 
-Leaf streaming (this PR): multi-leaf pytrees no longer flatten through a
-(C, N) ``concatenate``.  A *segment-offset table* (static, derived from
-the leaf sizes and ``blk``) assigns each leaf a contiguous run of grid
-steps; both passes are ONE ``pallas_call`` whose per-leaf BlockSpec
-index maps clamp into the leaf's segment, so each leaf block is DMA'd
-exactly once and the (C,) dot/norm/gate accumulators are SHARED across
-all segments in VMEM.  Leaves stream in place (a reshape view, no copy);
-ragged tails are masked in-kernel, accumulation is fp32 throughout, and
-each leaf is cast back to its own dtype exactly once — by the pass-2
-output write.  The 2-pass HBM roofline is therefore end-to-end: no
-flatten concatenate, no unflatten slice-copy.  (The PR-1 flatten path is
-kept below as ``*_flat`` — the bench baseline and a parity oracle.)
+Leaf streaming: a pytree never flattens through a (C, N) concatenate.  A
+static *segment-offset table* (``make_segments``) assigns each leaf a
+run of grid steps; each pass is ONE ``pallas_call`` whose per-leaf
+BlockSpec index maps clamp into the leaf's segment, so each leaf block
+is DMA'd exactly once and the (C,) accumulators are shared across all
+segments.  Ragged tails are masked in-kernel, accumulation is fp32
+throughout, and each leaf is cast back to its own dtype exactly once —
+by the pass-2 output write.
+
+Block loaders: every pass body reads its (C, blk) fp32 block through a
+static loader, which also supplies the pass's input BlockSpecs and its
+kernel and scope names.
+
+  DENSE       (G, C, n_l) leaves in their own dtype;
+              kernels robust_pass1/2, robust_gram
+  Int8(qblk)  int8 (G, C, n_l) codes + (G, C, nq_l) per-quant-block f32
+              scales of the wire format (``comm/codecs.py``),
+              dequantized in VMEM; kernels dequant_pass1/2, dequant_gram
+
+The int8 loader relays each leaf's (C, nq) scale rows to (nblocks, C,
+blk/qblk) before the passes (``_scale_blocks``, 1/32 of the code bytes),
+so one grid step's scales are a whole trailing tile, and widens them to
+(C, blk) in VMEM through a 0/1 expansion matmul at HIGHEST precision
+(exact).  The multiply ``q_f32 * scale_f32`` is the one
+``codecs.quant_decode`` performs, so aggregating the codes is
+bit-identical to decoding first and running the dense loader.  It needs
+every streaming block tiled by the quant block (``fusable``).  Passes
+read ``C*N*1`` code bytes + ``C*N*4/qblk`` scale bytes in place of the
+dense ``C*N*4``.
 
 Distribution hooks: ``fused_pipeline_leafwise`` takes ``axis_name`` +
-``leaf_scale`` so ``aggregation.aggregate_sharded`` can run the passes
-shard-locally under ``shard_map`` — only the (C,) cosine partials (and
-Krum's Gram matrix) cross devices, in one ``psum``.
-
-HBM traffic: the reference path reads (and for sorts, re-writes) the
-(C, N) matrix >= 4 times; the fused pipeline reads it exactly twice
-(three times for Krum) and writes only the (1, N) output.  See
-``benchmarks/bench_kernels.py::robust_pipeline_roofline``.
+``leaf_scale`` so ``aggregation.aggregate(..., mesh=)`` can run the
+passes shard-locally under ``shard_map`` — only the (C,) cosine partials
+(and Krum's Gram matrix) cross devices, in one ``psum``.
 
 Layout note: the (C,)-shaped accumulators use C as the minor dimension;
 on real TPUs C < 128 relies on Mosaic's small-array padding.  The
@@ -62,6 +70,7 @@ substrate); ``auto_blk`` keeps grids short there and VMEM-sized on TPU.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple
 
@@ -71,7 +80,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import on_tpu, resolve_interpret
-from repro.kernels.robust_agg import _BIG, stable_ranks
+
+_BIG = 1e30
+
+
+def stable_ranks(xm, c):
+    """Per-coordinate stable ranks of an already-masked (C, blk) block:
+    rank_i = #{j: x_j < x_i} + #{j<i: x_j == x_i}. Masked-out rows must
+    arrive pushed to +_BIG so they rank past every real row. O(C^2)
+    elementwise VPU ops — for C <= 64 this beats a data-dependent sort on
+    the TPU vector unit and keeps everything in registers/VMEM."""
+    xi = xm[:, None, :]                           # (C, 1, blk)
+    xj = xm[None, :, :]                           # (1, C, blk)
+    less = (xj < xi).astype(jnp.float32)
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (c, c, 1), 0)
+    row_j = jax.lax.broadcasted_iota(jnp.int32, (c, c, 1), 1)
+    tie = ((xj == xi) & (row_j < row_i)).astype(jnp.float32)
+    return (less + tie).sum(axis=1)               # (C, blk)
 
 
 # ---------------------------------------------------------------------------
@@ -184,19 +209,140 @@ def _foreach_active_leaf(segs, total, i, fn):
                 functools.partial(fn, l, seg))
 
 
-def _leaf_block(x_refs, l, seg, i):
-    """Load leaf ``l``'s current (C, seg.blk) block in fp32 with the
-    ragged tail masked to zero (OOB lanes of the overrunning last block
-    carry unspecified values; ``where`` keeps them out of every
-    accumulator).  ``i`` is the step index, read by the caller at kernel
-    top level — ``pl.program_id`` inside a ``pl.when`` branch is not
-    substituted by the interpreter."""
-    x = x_refs[l][0].astype(jnp.float32)
+# ---------------------------------------------------------------------------
+# block loaders
+# ---------------------------------------------------------------------------
+
+def _mask_tail(x, seg, i):
+    """Zero the ragged tail of leaf block ``x`` at step ``i`` (OOB lanes of
+    the overrunning last block carry unspecified values; ``where`` keeps
+    them out of every accumulator).  ``i`` is the step index, read by the
+    caller at kernel top level — ``pl.program_id`` inside a ``pl.when``
+    branch is not substituted by the interpreter."""
     if seg.n % seg.blk:
         valid = seg.n - (i - seg.start) * seg.blk
         col = jax.lax.broadcasted_iota(jnp.int32, (1, seg.blk), 1)
         x = jnp.where(col < valid, x, 0.0)
     return x
+
+
+@dataclasses.dataclass(frozen=True)
+class Dense:
+    """Loader of (G, C, n_l) leaves in their own dtype.
+
+    A loader is static: ``width`` reads a leaf's streamed length,
+    ``prepare`` lays the leaves out for the passes once, ``operands``
+    flattens them into the ``pallas_call`` inputs that ``in_specs`` block,
+    and ``block`` loads leaf ``l``'s fp32 (C, blk) block at step ``i``
+    inside a pass body.  ``prefix`` names the kernels and ``align`` is
+    the coordinate multiple a mesh shard must keep."""
+    prefix = "robust"
+    align = 1
+
+    def width(self, leaf):
+        return int(leaf.shape[-1])
+
+    def prepare(self, leaves, segs):
+        return list(leaves)
+
+    def operands(self, streams):
+        return list(streams)
+
+    def in_specs(self, segs, c):
+        return [pl.BlockSpec((1, c, seg.blk), _seg_index_map(seg))
+                for seg in segs]
+
+    def block(self, refs, l, seg, i):
+        return _mask_tail(refs[l][0].astype(jnp.float32), seg, i)
+
+
+DENSE = Dense()
+
+
+def _scale_blocks(s, seg, qblk):
+    """(G, C, nq) wire scales -> (G, nblocks, C, blk/qblk): grid step
+    ``j`` of the leaf reads the whole trailing (C, blk/qblk) tile
+    ``[:, j]``.  The padded columns sit past the leaf's last code and
+    only ever scale masked lanes."""
+    G, C, nq = s.shape
+    sb = seg.blk // qblk
+    s = jnp.pad(s, ((0, 0), (0, 0), (0, seg.nblocks * sb - nq)),
+                constant_values=1.0)
+    return s.reshape(G, C, seg.nblocks, sb).transpose(0, 2, 1, 3)
+
+
+def _scale_index_map(seg):
+    """Scale-tile twin of ``_seg_index_map``."""
+    return lambda g, i, *_: (g, jnp.clip(i - seg.start, 0,
+                                         seg.nblocks - 1), 0, 0)
+
+
+def _scale_specs(segs, c, qblk):
+    return [pl.BlockSpec((1, 1, c, seg.blk // qblk), _scale_index_map(seg))
+            for seg in segs]
+
+
+def _widen(s, blk, qblk):
+    """(C, blk/qblk) scales -> (C, blk), each scale repeated over its
+    qblk lanes, by a matmul against the 0/1 block-membership matrix.
+    One nonzero term per output at HIGHEST precision: exact."""
+    sb = s.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (sb, blk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (sb, blk), 1)
+    member = ((lane >= row * qblk) & (lane < (row + 1) * qblk)).astype(
+        jnp.float32)
+    return jax.lax.dot_general(s, member, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+@dataclasses.dataclass(frozen=True)
+class Int8:
+    """Loader of int8 wire leaves: per leaf a pair of (G, C, n_l) codes
+    and (G, C, nq_l) per-quant-block scales, dequantized in VMEM."""
+    qblk: int = 128
+    prefix = "dequant"
+
+    @property
+    def align(self):
+        return self.qblk
+
+    def width(self, leaf):
+        return int(leaf[0].shape[-1])
+
+    def prepare(self, leaves, segs):
+        assert all(seg.blk % self.qblk == 0 for seg in segs), \
+            (self.qblk, [seg.blk for seg in segs])
+        return [(q, _scale_blocks(s, seg, self.qblk))
+                for (q, s), seg in zip(leaves, segs)]
+
+    def operands(self, streams):
+        return [q for q, _ in streams] + [s for _, s in streams]
+
+    def in_specs(self, segs, c):
+        return DENSE.in_specs(segs, c) + _scale_specs(segs, c, self.qblk)
+
+    def block(self, refs, l, seg, i):
+        L = len(refs) // 2
+        q = refs[l][0].astype(jnp.float32)                # (C, blk)
+        s = refs[L + l][0, 0].astype(jnp.float32)         # (C, blk/qblk)
+        return _mask_tail(q * _widen(s, seg.blk, self.qblk), seg, i)
+
+
+def fusable(sizes, c, qblk):
+    """True when every per-leaf streaming block, at the ``auto_blk`` the
+    pipeline runs, is tiled exactly by the quant block, so the ``Int8``
+    loader can stream these leaves."""
+    segs, _ = make_segments(sizes, auto_blk(c, sizes))
+    return all(seg.blk % qblk == 0 for seg in segs)
+
+
+def _streamed(streams, blk, loader):
+    """(operands, segs, total, G, C) of one pass over ``streams``."""
+    ops = loader.operands(streams)
+    segs, total = make_segments(tuple(loader.width(s) for s in streams),
+                                blk)
+    return ops, segs, total, ops[0].shape[0], ops[0].shape[1]
 
 
 # ---------------------------------------------------------------------------
@@ -216,66 +362,10 @@ def _median_block(x, m, n, c):
                   + (x * pick_hi).sum(axis=0, keepdims=True))   # (1, blk)
 
 
-def _pass1_body(n_ref, x_ref, mask_ref, dot_ref, sqn_ref, refsq_ref, *, c):
-    g = pl.program_id(0)
-    i = pl.program_id(1)
-    x = x_ref[0].astype(jnp.float32)              # (C, blk)
-    m = mask_ref[0].astype(jnp.float32)           # (C, 1)
-    n = n_ref[g].astype(jnp.float32)
-    med = _median_block(x, m, n, c)
-
-    @pl.when(i == 0)
-    def _init():
-        dot_ref[...] = jnp.zeros_like(dot_ref)
-        sqn_ref[...] = jnp.zeros_like(sqn_ref)
-        refsq_ref[...] = jnp.zeros_like(refsq_ref)
-
-    dot_ref[...] += (x * med).sum(axis=1)[None, :]
-    sqn_ref[...] += (x * x).sum(axis=1)[None, :]
-    refsq_ref[...] += (med * med).sum(axis=1, keepdims=True)
-
-
-def cosine_gate_partials(x, mask, *, blk=4096, interpret=False):
-    """x: (G, C, N) f32, mask: (G, C) 0/1 ->
-    (dots (G, C), sqnorms (G, C), refsq (G, 1)) — the per-client cosine
-    partials vs the coordinate-median reference, in one streaming read."""
-    G, C, N = x.shape
-    assert N % blk == 0, (N, blk)
-    n_sel = mask.sum(axis=1).astype(jnp.float32)  # (G,)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(G, N // blk),
-        in_specs=[
-            pl.BlockSpec((1, C, blk), lambda g, i, n: (g, 0, i)),
-            pl.BlockSpec((1, C, 1), lambda g, i, n: (g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, C), lambda g, i, n: (g, 0)),
-            pl.BlockSpec((1, C), lambda g, i, n: (g, 0)),
-            pl.BlockSpec((1, 1), lambda g, i, n: (g, 0)),
-        ],
-    )
-    with jax.named_scope("robust_pass1"):
-        dots, sqn, refsq = pl.pallas_call(
-            functools.partial(_pass1_body, c=C),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((G, C), jnp.float32),
-                jax.ShapeDtypeStruct((G, C), jnp.float32),
-                jax.ShapeDtypeStruct((G, 1), jnp.float32),
-            ],
-            name="robust_pass1",
-            interpret=interpret,
-        )(n_sel, x, mask.reshape(G, C, 1))
-    return dots, sqn, refsq
-
-
-def _pass1_leaf_body(n_ref, scale_ref, *refs, segs, total, c):
-    L = len(segs)
-    x_refs = refs[:L]
-    mask_ref = refs[L]
-    dot_ref, sqn_ref, refsq_ref = refs[L + 1:]
+def _pass1_body(n_ref, scale_ref, *refs, loader, n_in, segs, total, c):
+    in_refs = refs[:n_in]
+    mask_ref = refs[n_in]
+    dot_ref, sqn_ref, refsq_ref = refs[n_in + 1:]
     g = pl.program_id(0)
     i = pl.program_id(1)
     m = mask_ref[0].astype(jnp.float32)           # (C, 1)
@@ -288,7 +378,7 @@ def _pass1_leaf_body(n_ref, scale_ref, *refs, segs, total, c):
         refsq_ref[...] = jnp.zeros_like(refsq_ref)
 
     def accumulate(l, seg):
-        x = _leaf_block(x_refs, l, seg, i)
+        x = loader.block(in_refs, l, seg, i)
         med = _median_block(x, m, n, c)
         s = scale_ref[l]
         dot_ref[...] += s * (x * med).sum(axis=1)[None, :]
@@ -298,23 +388,22 @@ def _pass1_leaf_body(n_ref, scale_ref, *refs, segs, total, c):
     _foreach_active_leaf(segs, total, i, accumulate)
 
 
-def cosine_gate_partials_leafwise(leaves, mask, *, blk, leaf_scale,
-                                  interpret=False):
-    """Segment-table pass 1: leaves [(G, C, n_l)] stream through ONE
-    ``pallas_call`` sharing the (C,) accumulators across all segments.
+def cosine_gate_partials(streams, mask, *, blk, leaf_scale, loader=DENSE,
+                         interpret=False):
+    """Pass 1: per-leaf ``streams`` (in ``loader``'s format) go through
+    ONE ``pallas_call`` sharing the (C,) accumulators across all
+    segments -> (dots (G, C), sqnorms (G, C), refsq (G, 1)).
     ``leaf_scale`` (L,) scales each leaf's contribution (1.0 everywhere
     off-mesh; under ``shard_map`` it de-duplicates replicated leaves
     before the cross-device psum)."""
-    G, C = leaves[0].shape[:2]
-    sizes = tuple(int(l.shape[-1]) for l in leaves)
-    segs, total = make_segments(sizes, blk)
+    ops, segs, total, G, C = _streamed(streams, blk, loader)
     n_sel = mask.sum(axis=1).astype(jnp.float32)  # (G,)
+    name = f"{loader.prefix}_pass1"
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(G, total),
-        in_specs=[pl.BlockSpec((1, C, seg.blk), _seg_index_map(seg))
-                  for seg in segs]
+        in_specs=loader.in_specs(segs, C)
         + [pl.BlockSpec((1, C, 1), lambda g, i, *_: (g, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, C), lambda g, i, *_: (g, 0)),
@@ -322,18 +411,19 @@ def cosine_gate_partials_leafwise(leaves, mask, *, blk, leaf_scale,
             pl.BlockSpec((1, 1), lambda g, i, *_: (g, 0)),
         ],
     )
-    with jax.named_scope("robust_pass1"):
+    with jax.named_scope(name):
         dots, sqn, refsq = pl.pallas_call(
-            functools.partial(_pass1_leaf_body, segs=segs, total=total, c=C),
+            functools.partial(_pass1_body, loader=loader, n_in=len(ops),
+                              segs=segs, total=total, c=C),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((G, C), jnp.float32),
                 jax.ShapeDtypeStruct((G, C), jnp.float32),
                 jax.ShapeDtypeStruct((G, 1), jnp.float32),
             ],
-            name="robust_pass1",
+            name=name,
             interpret=interpret,
-        )(n_sel, leaf_scale, *leaves, mask.reshape(G, C, 1))
+        )(n_sel, leaf_scale, *ops, mask.reshape(G, C, 1))
     return dots, sqn, refsq
 
 
@@ -361,51 +451,11 @@ def _combine_block(x, m, w, n, *, c, mode, trim_frac):
                   + (x * pick_hi).sum(axis=0, keepdims=True))
 
 
-def _pass2_body(n_ref, x_ref, m_ref, w_ref, o_ref, *, c, mode, trim_frac):
-    g = pl.program_id(0)
-    x = x_ref[0].astype(jnp.float32)              # (C, blk)
-    m = m_ref[0].astype(jnp.float32)              # (C, 1)
-    w = w_ref[0].astype(jnp.float32)              # (C, 1) pre-normalised
-    n = n_ref[g].astype(jnp.float32)
-    o_ref[0] = _combine_block(x, m, w, n, c=c, mode=mode,
-                              trim_frac=trim_frac).astype(o_ref.dtype)
-
-
-def gated_combine(x, gated_mask, weights, *, mode, trim_frac=0.2, blk=4096,
-                  interpret=False):
-    """x: (G, C, N); gated_mask: (G, C); weights: (G, C) (normalised,
-    ``mean`` mode only) -> (G, N)."""
-    G, C, N = x.shape
-    assert N % blk == 0, (N, blk)
-    n_sel = gated_mask.sum(axis=1).astype(jnp.float32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(G, N // blk),
-        in_specs=[
-            pl.BlockSpec((1, C, blk), lambda g, i, n: (g, 0, i)),
-            pl.BlockSpec((1, C, 1), lambda g, i, n: (g, 0, 0)),
-            pl.BlockSpec((1, C, 1), lambda g, i, n: (g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, blk), lambda g, i, n: (g, 0, i)),
-    )
-    with jax.named_scope("robust_pass2"):
-        out = pl.pallas_call(
-            functools.partial(_pass2_body, c=C, mode=mode,
-                              trim_frac=trim_frac),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((G, 1, N), jnp.float32),
-            name="robust_pass2",
-            interpret=interpret,
-        )(n_sel, x, gated_mask.reshape(G, C, 1), weights.reshape(G, C, 1))
-    return out[:, 0]
-
-
-def _pass2_leaf_body(n_ref, *refs, segs, total, c, mode, trim_frac):
-    L = len(segs)
-    x_refs = refs[:L]
-    m_ref, w_ref = refs[L], refs[L + 1]
-    o_refs = refs[L + 2:]
+def _pass2_body(n_ref, *refs, loader, n_in, segs, total, c, mode,
+                trim_frac):
+    in_refs = refs[:n_in]
+    m_ref, w_ref = refs[n_in], refs[n_in + 1]
+    o_refs = refs[n_in + 2:]
     g = pl.program_id(0)
     i = pl.program_id(1)
     m = m_ref[0].astype(jnp.float32)              # (C, 1)
@@ -413,7 +463,7 @@ def _pass2_leaf_body(n_ref, *refs, segs, total, c, mode, trim_frac):
     n = n_ref[g].astype(jnp.float32)
 
     def emit(l, seg):
-        x = _leaf_block(x_refs, l, seg, i)
+        x = loader.block(in_refs, l, seg, i)
         o_refs[l][0] = _combine_block(
             x, m, w, n, c=c, mode=mode, trim_frac=trim_frac
         ).astype(o_refs[l].dtype)
@@ -421,36 +471,36 @@ def _pass2_leaf_body(n_ref, *refs, segs, total, c, mode, trim_frac):
     _foreach_active_leaf(segs, total, i, emit)
 
 
-def gated_combine_leafwise(leaves, gated_mask, weights, *, mode,
-                           trim_frac=0.2, blk, out_dtypes, interpret=False):
-    """Segment-table pass 2: per-leaf (G, n_l) outputs, each written in its
-    own ``out_dtypes[l]`` — the single fp32->leaf-dtype cast of the whole
-    pipeline happens at this output write."""
-    G, C = leaves[0].shape[:2]
-    sizes = tuple(int(l.shape[-1]) for l in leaves)
-    segs, total = make_segments(sizes, blk)
+def gated_combine(streams, gated_mask, weights, *, mode, trim_frac=0.2,
+                  blk, out_dtypes, loader=DENSE, interpret=False):
+    """Pass 2: per-leaf (G, n_l) outputs, each written in its own
+    ``out_dtypes[l]`` — the single fp32->leaf-dtype cast of the whole
+    pipeline happens at this output write.  ``weights`` (G, C) are
+    normalised and read in ``mean`` mode only."""
+    ops, segs, total, G, C = _streamed(streams, blk, loader)
     n_sel = gated_mask.sum(axis=1).astype(jnp.float32)
+    name = f"{loader.prefix}_pass2"
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(G, total),
-        in_specs=[pl.BlockSpec((1, C, seg.blk), _seg_index_map(seg))
-                  for seg in segs]
+        in_specs=loader.in_specs(segs, C)
         + [pl.BlockSpec((1, C, 1), lambda g, i, *_: (g, 0, 0)),
            pl.BlockSpec((1, C, 1), lambda g, i, *_: (g, 0, 0))],
         out_specs=[pl.BlockSpec((1, 1, seg.blk), _seg_index_map(seg))
                    for seg in segs],
     )
-    with jax.named_scope("robust_pass2"):
+    with jax.named_scope(name):
         outs = pl.pallas_call(
-            functools.partial(_pass2_leaf_body, segs=segs, total=total, c=C,
-                              mode=mode, trim_frac=trim_frac),
+            functools.partial(_pass2_body, loader=loader, n_in=len(ops),
+                              segs=segs, total=total, c=C, mode=mode,
+                              trim_frac=trim_frac),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((G, 1, seg.n), dt)
                        for seg, dt in zip(segs, out_dtypes)],
-            name="robust_pass2",
+            name=name,
             interpret=interpret,
-        )(n_sel, *leaves, gated_mask.reshape(G, C, 1),
+        )(n_sel, *ops, gated_mask.reshape(G, C, 1),
           weights.reshape(G, C, 1))
     return [o[:, 0] for o in outs]
 
@@ -459,51 +509,9 @@ def gated_combine_leafwise(leaves, gated_mask, weights, *, mode,
 # blocked pairwise distances (Krum)
 # ---------------------------------------------------------------------------
 
-def _pairwise_body(x_ref, gram_ref, sqn_ref, *, c):
-    i = pl.program_id(1)
-    x = x_ref[0].astype(jnp.float32)              # (C, blk)
-
-    @pl.when(i == 0)
-    def _init():
-        gram_ref[...] = jnp.zeros_like(gram_ref)
-        sqn_ref[...] = jnp.zeros_like(sqn_ref)
-
-    gram_ref[0] += jax.lax.dot_general(
-        x, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    sqn_ref[...] += (x * x).sum(axis=1)[None, :]
-
-
-def pairwise_sq_dists_blocked(x, mask, *, blk=4096, interpret=False):
-    """Blocked (G, C, C) squared distances: streams N once, accumulating
-    the Gram matrix and row norms; masked-out pairs pushed to +_BIG (same
-    contract as ``aggregation.pairwise_sq_dists``)."""
-    G, C, N = x.shape
-    assert N % blk == 0, (N, blk)
-    with jax.named_scope("robust_gram"):
-        gram, sqn = pl.pallas_call(
-            functools.partial(_pairwise_body, c=C),
-            grid=(G, N // blk),
-            in_specs=[pl.BlockSpec((1, C, blk), lambda g, i: (g, 0, i))],
-            out_specs=[
-                pl.BlockSpec((1, C, C), lambda g, i: (g, 0, 0)),
-                pl.BlockSpec((1, C), lambda g, i: (g, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((G, C, C), jnp.float32),
-                jax.ShapeDtypeStruct((G, C), jnp.float32),
-            ],
-            name="robust_gram",
-            interpret=interpret,
-        )(x)
-    d = sqn[:, :, None] + sqn[:, None, :] - 2.0 * gram
-    big = _BIG * (1.0 - mask[:, :, None] * mask[:, None, :])
-    return jnp.maximum(d, 0.0) + big
-
-
-def _pairwise_leaf_body(scale_ref, *refs, segs, total, c):
-    L = len(segs)
-    x_refs = refs[:L]
-    gram_ref, sqn_ref = refs[L:]
+def _gram_body(scale_ref, *refs, loader, n_in, segs, total, c):
+    in_refs = refs[:n_in]
+    gram_ref, sqn_ref = refs[n_in:]
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -512,7 +520,7 @@ def _pairwise_leaf_body(scale_ref, *refs, segs, total, c):
         sqn_ref[...] = jnp.zeros_like(sqn_ref)
 
     def accumulate(l, seg):
-        x = _leaf_block(x_refs, l, seg, i)
+        x = loader.block(in_refs, l, seg, i)
         s = scale_ref[l]
         gram_ref[0] += s * jax.lax.dot_general(
             x, x, (((1,), (1,)), ((), ())),
@@ -522,37 +530,37 @@ def _pairwise_leaf_body(scale_ref, *refs, segs, total, c):
     _foreach_active_leaf(segs, total, i, accumulate)
 
 
-def pairwise_sq_dists_leafwise(leaves, mask, *, blk, leaf_scale,
-                               interpret=False, axis_name=None):
-    """Segment-table Krum distance pass: Gram + row norms accumulate across
-    all leaf segments in one streaming read; under ``shard_map`` the (C, C)
-    Gram matrix (not the update matrix) is what crosses devices."""
-    G, C = leaves[0].shape[:2]
-    sizes = tuple(int(l.shape[-1]) for l in leaves)
-    segs, total = make_segments(sizes, blk)
+def pairwise_sq_dists(streams, mask, *, blk, leaf_scale, loader=DENSE,
+                      interpret=False, axis_name=None):
+    """Krum distance pass: Gram + row norms accumulate across all leaf
+    segments in one streaming read -> (G, C, C) squared distances with
+    masked-out pairs pushed to +_BIG (the contract of
+    ``aggregation.pairwise_sq_dists``).  Under ``shard_map`` the (C, C)
+    Gram matrix, not the update matrix, is what crosses devices."""
+    ops, segs, total, G, C = _streamed(streams, blk, loader)
+    name = f"{loader.prefix}_gram"
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(G, total),
-        in_specs=[pl.BlockSpec((1, C, seg.blk), _seg_index_map(seg))
-                  for seg in segs],
+        in_specs=loader.in_specs(segs, C),
         out_specs=[
             pl.BlockSpec((1, C, C), lambda g, i, *_: (g, 0, 0)),
             pl.BlockSpec((1, C), lambda g, i, *_: (g, 0)),
         ],
     )
-    with jax.named_scope("robust_gram"):
+    with jax.named_scope(name):
         gram, sqn = pl.pallas_call(
-            functools.partial(_pairwise_leaf_body, segs=segs, total=total,
-                              c=C),
+            functools.partial(_gram_body, loader=loader, n_in=len(ops),
+                              segs=segs, total=total, c=C),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((G, C, C), jnp.float32),
                 jax.ShapeDtypeStruct((G, C), jnp.float32),
             ],
-            name="robust_gram",
+            name=name,
             interpret=interpret,
-        )(leaf_scale, *leaves)
+        )(leaf_scale, *ops)
     if axis_name is not None:
         gram = jax.lax.psum(gram, axis_name)
         sqn = jax.lax.psum(sqn, axis_name)
@@ -584,55 +592,8 @@ def _krum_weights(d, mask, f, multi_m):
 
 
 # ---------------------------------------------------------------------------
-# the fused pipeline — flat (single pre-flattened matrix) and leafwise
+# the fused pipeline
 # ---------------------------------------------------------------------------
-
-def fused_pipeline(x, weights, mask, *, aggregator="trimmed_mean",
-                   trim_frac=0.2, cosine_thresh=-0.5, krum_f=1,
-                   krum_multi_m=1, blk=4096, interpret=None):
-    """Full Eq.-11 pipeline over a cohort batch of ONE pre-flattened
-    matrix.
-
-    x: (G, C, N) f32 flattened client updates; weights, mask: (G, C).
-    Returns the (G, N) aggregated rows.  Semantically equivalent to
-    ``aggregation.aggregate_ref`` vmapped over G (parity-tested)."""
-    G, C, N = x.shape
-    interpret = resolve_interpret(interpret)
-    blk = min(blk, max(128, N))
-    pad = (-N) % blk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-    x = x.astype(jnp.float32)
-    mask = mask.astype(jnp.float32)
-
-    # ---- pass 1: median reference + cosine partials (1 read of x) ----
-    dots, sqn, refsq = cosine_gate_partials(
-        x, mask, blk=blk, interpret=interpret)
-
-    # ---- on-device gate resolution: O(G*C) scalars ----
-    m = _resolve_gate(dots, sqn, refsq, mask, cosine_thresh)
-
-    # ---- pass 2 (+ Krum distance pass): gated combine ----
-    if aggregator == "fedavg":
-        w = weights * m
-        w = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1e-12)
-        out = gated_combine(x, m, w, mode="mean", blk=blk,
-                            interpret=interpret)
-    elif aggregator == "trimmed_mean":
-        out = gated_combine(x, m, m, mode="trimmed", trim_frac=trim_frac,
-                            blk=blk, interpret=interpret)
-    elif aggregator == "median":
-        out = gated_combine(x, m, m, mode="median", blk=blk,
-                            interpret=interpret)
-    elif aggregator == "krum":
-        d = pairwise_sq_dists_blocked(x, m, blk=blk, interpret=interpret)
-        w = _krum_weights(d, m, krum_f, krum_multi_m)
-        out = gated_combine(x, m, w, mode="mean", blk=blk,
-                            interpret=interpret)
-    else:
-        raise ValueError(aggregator)
-    return out[:, :N] if pad else out
-
 
 def _resolve_gate(dots, sqn, refsq, mask, cosine_thresh):
     """Cosine outlier gate from the pass-1 partials; never gates everyone
@@ -646,13 +607,14 @@ def _resolve_gate(dots, sqn, refsq, mask, cosine_thresh):
     return jnp.where(m.sum(axis=1, keepdims=True) > 0, m, mask)
 
 
-def fused_pipeline_leafwise(leaves, weights, mask, *,
+def fused_pipeline_leafwise(leaves, weights, mask, *, loader=DENSE,
                             aggregator="trimmed_mean", trim_frac=0.2,
                             cosine_thresh=-0.5, krum_f=1, krum_multi_m=1,
                             blk=None, interpret=None, axis_name=None,
                             leaf_scale=None, out_dtypes=None):
-    """Full Eq.-11 pipeline over a LIST of (G, C, n_l) leaf matrices —
-    the segment-table passes stream every leaf in place (no concatenate).
+    """Full Eq.-11 pipeline over a LIST of per-leaf inputs in ``loader``'s
+    format — (G, C, n_l) matrices for ``DENSE``, (codes, scales) pairs
+    for ``Int8`` — streamed in place by the segment-table passes.
 
     Returns the per-leaf (G, n_l) aggregated rows in ``out_dtypes``
     (default fp32; pass leaf dtypes for the single end-of-pipe cast).
@@ -661,20 +623,21 @@ def fused_pipeline_leafwise(leaves, weights, mask, *,
     or tuple) so the (C,) cosine partials and Krum's Gram matrix psum
     across devices, and ``leaf_scale`` (L,) with 0/1 entries that keep
     replicated (non-divisible) leaves from being double-counted."""
-    G, C = leaves[0].shape[:2]
-    sizes = tuple(int(l.shape[-1]) for l in leaves)
+    sizes = tuple(loader.width(l) for l in leaves)
     interpret = resolve_interpret(interpret)
     if blk is None:
-        blk = auto_blk(C, sizes)
+        blk = auto_blk(mask.shape[1], sizes)
+    streams = loader.prepare(leaves, make_segments(sizes, blk)[0])
     if leaf_scale is None:
         leaf_scale = jnp.ones((len(leaves),), jnp.float32)
     if out_dtypes is None:
         out_dtypes = [jnp.float32] * len(leaves)
     mask = mask.astype(jnp.float32)
+    run = dict(blk=blk, loader=loader, interpret=interpret)
 
     # ---- pass 1: shared accumulators across all leaf segments ----
-    dots, sqn, refsq = cosine_gate_partials_leafwise(
-        leaves, mask, blk=blk, leaf_scale=leaf_scale, interpret=interpret)
+    dots, sqn, refsq = cosine_gate_partials(
+        streams, mask, leaf_scale=leaf_scale, **run)
     if axis_name is not None:
         dots = jax.lax.psum(dots, axis_name)
         sqn = jax.lax.psum(sqn, axis_name)
@@ -682,8 +645,8 @@ def fused_pipeline_leafwise(leaves, weights, mask, *,
 
     m = _resolve_gate(dots, sqn, refsq, mask, cosine_thresh)
 
-    combine = functools.partial(gated_combine_leafwise, leaves, m, blk=blk,
-                                out_dtypes=out_dtypes, interpret=interpret)
+    combine = functools.partial(gated_combine, streams, m,
+                                out_dtypes=out_dtypes, **run)
     if aggregator == "fedavg":
         w = weights * m
         w = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1e-12)
@@ -693,46 +656,16 @@ def fused_pipeline_leafwise(leaves, weights, mask, *,
     if aggregator == "median":
         return combine(m, mode="median")
     if aggregator == "krum":
-        d = pairwise_sq_dists_leafwise(
-            leaves, m, blk=blk, leaf_scale=leaf_scale, interpret=interpret,
-            axis_name=axis_name)
+        d = pairwise_sq_dists(streams, m, leaf_scale=leaf_scale,
+                              axis_name=axis_name, **run)
         w = _krum_weights(d, m, krum_f, krum_multi_m)
         return combine(w, mode="mean")
     raise ValueError(aggregator)
 
 
 # ---------------------------------------------------------------------------
-# pytree wrappers (the core/aggregation.py hot path)
+# pytree wrappers over dense leaves
 # ---------------------------------------------------------------------------
-
-def _flatten_cohorts(updates, lead):
-    """Flatten a pytree of (*lead, ...) leaves into one (*lead, N) f32
-    matrix; returns (flat, treedef, leaves, sizes).  The PR-1 path — the
-    concatenate is an extra (C, N) HBM copy; kept for the ``*_flat``
-    baseline/oracle only."""
-    leaves, treedef = jax.tree_util.tree_flatten(updates)
-    sizes = [int(l.size // max(1, _prod(l.shape[:lead]))) for l in leaves]
-    flat = jnp.concatenate(
-        [l.reshape(*l.shape[:lead], -1).astype(jnp.float32) for l in leaves],
-        axis=-1)
-    return flat, treedef, leaves, sizes
-
-
-def _prod(shape):
-    out = 1
-    for s in shape:
-        out *= s
-    return out
-
-
-def _unflatten(agg, treedef, leaves, sizes, lead):
-    out, off = [], 0
-    for l, n in zip(leaves, sizes):
-        out.append(agg[..., off:off + n].reshape(l.shape[lead:]).astype(
-            l.dtype))
-        off += n
-    return jax.tree_util.tree_unflatten(treedef, out)
-
 
 def _leaf_views(updates, lead):
     """Reshape-only (no copy) views of the pytree's leaves as a list of
@@ -745,8 +678,8 @@ def _leaf_views(updates, lead):
 @functools.partial(jax.jit, static_argnames=("cfg", "blk", "interpret"))
 def fused_aggregate_tree(updates, weights, mask, cfg, *, blk=None,
                          interpret=None):
-    """Single-cohort Eq.-11 aggregation over a pytree of (C, ...) leaves;
-    drop-in for ``aggregation.aggregate_ref`` (which stays as the parity
+    """Single-cohort Eq.-11 aggregation over a pytree of (C, ...) leaves
+    through the dense loader (``aggregation.aggregate_ref`` is its parity
     oracle).  Leaf-streaming: no concatenate, no unflatten copy — each
     leaf is a reshape view into the segment-table passes and is cast back
     to its dtype once, by the pass-2 output write."""
@@ -779,34 +712,3 @@ def fused_two_stage_tree(slot_updates, slot_weights, slot_masks, cfg, *,
     outs = [jnp.tensordot(cw, p, axes=(0, 0)).reshape(l.shape[2:]).astype(
         l.dtype) for p, l in zip(per, leaves)]
     return jax.tree_util.tree_unflatten(treedef, outs)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "blk", "interpret"))
-def fused_aggregate_tree_flat(updates, weights, mask, cfg, *, blk=4096,
-                              interpret=None):
-    """The PR-1 flatten path (one (C, N) concatenate + unflatten copies).
-    Kept as the leafwise bench baseline and a parity oracle."""
-    flat, treedef, leaves, sizes = _flatten_cohorts(updates, 1)
-    out = fused_pipeline(
-        flat[None], weights[None], mask[None],
-        aggregator=cfg.aggregator, trim_frac=cfg.trim_frac,
-        cosine_thresh=cfg.cosine_outlier_thresh, krum_f=cfg.krum_f,
-        blk=blk, interpret=interpret)[0]
-    return _unflatten(out, treedef, leaves, sizes, 1)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "blk", "interpret"))
-def fused_two_stage_tree_flat(slot_updates, slot_weights, slot_masks, cfg,
-                              *, blk=4096, interpret=None):
-    """PR-1 flatten path of the cohort-batched two-stage scheme (bench
-    baseline / parity oracle)."""
-    flat, treedef, leaves, sizes = _flatten_cohorts(slot_updates, 2)
-    per = fused_pipeline(
-        flat, slot_weights, slot_masks,
-        aggregator=cfg.aggregator, trim_frac=cfg.trim_frac,
-        cosine_thresh=cfg.cosine_outlier_thresh, krum_f=cfg.krum_f,
-        blk=blk, interpret=interpret)                      # (G, N)
-    cw = slot_masks.sum(axis=1).astype(jnp.float32)
-    cw = cw / jnp.maximum(cw.sum(), 1e-12)
-    combined = jnp.tensordot(cw, per, axes=(0, 0))         # (N,)
-    return _unflatten(combined, treedef, leaves, sizes, 2)

@@ -212,13 +212,13 @@ def cache_specs(cache: Any, mesh) -> Any:
 def client_flat_specs(sizes, mesh, axes=("data", "model"), align=1):
     """PartitionSpecs for the (1, C, n_l)-flattened per-client update
     leaves of the sharded robust-aggregation path
-    (``aggregation.aggregate_sharded``): the flattened param axis shards
-    over ``axes`` when its size divides the combined axis extent, else the
-    leaf stays replicated (small norm/bias leaves — the fused pipeline
-    de-duplicates them before its psum).  ``align`` additionally requires
-    every SHARD to be a multiple of that many coordinates — the
-    fused-dequant path passes its quant-block width so each device's code
-    shard carries exactly its own scale columns.  Returns
+    (``aggregation.aggregate(..., mesh=)``): the flattened param axis
+    shards over ``axes`` when its size divides the combined axis extent,
+    else the leaf stays replicated (small norm/bias leaves — the fused
+    pipeline de-duplicates them before its psum).  ``align`` additionally
+    requires every SHARD to be a multiple of that many coordinates — the
+    int8 loader passes its quant-block width so each device's code shard
+    carries exactly its own scale columns.  Returns
     (specs, sharded_flags)."""
     axes = tuple(axes)
     size = _axis_size(mesh, axes)
@@ -254,17 +254,6 @@ def client_store_specs(store, mesh, axes=("data", "model")) -> Any:
         return P(axes, *([None] * (leaf.ndim - 1)))
 
     return jax.tree_util.tree_map(spec_for, store)
-
-
-def client_flat_shardings(sizes, mesh, axes=("data", "model")):
-    """``client_flat_specs`` as concrete ``NamedSharding``s — the layout
-    the sharded robust-aggregation path constrains its *inputs* to
-    (``jax.lax.with_sharding_constraint`` before the shard_map boundary),
-    so the per-client backward emits grads already in the (C, shard)
-    layout and the boundary does no reshard collective.  Returns
-    (shardings, sharded_flags)."""
-    specs, flags = client_flat_specs(sizes, mesh, axes)
-    return tuple(NamedSharding(mesh, s) for s in specs), flags
 
 
 def _dp_axes(mesh):

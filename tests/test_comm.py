@@ -4,18 +4,17 @@ aggregation parity (bit-exact vs decode-then-aggregate, quantization
 error vs the dense fp32 oracle, incl. the mesh-sharded path), empty-
 cohort x compression interaction, and the measured-bytes accounting
 through ``fedfits.run``."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.traversal import all_eqns
 from repro.comm import codecs, error_feedback
-from repro.comm.kernels import comm_codecs as dq
 from repro.configs.base import FedConfig
 from repro.core import aggregation
-from repro.kernels.robust_pipeline import fused_aggregate_tree
+from repro.kernels import robust_pipeline as rp
 
 multidevice = pytest.mark.skipif(
     jax.device_count() < 2,
@@ -191,13 +190,19 @@ def test_ef_disabled_threads_none():
     assert res is None
 
 
-# ------------------------------------------------- fused dequant kernels --
+# ------------------------------------------- the pipeline's int8 loader --
+def _kernels(fn, *args):
+    """Names of the Pallas kernels in ``fn``'s jaxpr."""
+    return {e.params["name"] for _, e in all_eqns(jax.make_jaxpr(fn)(*args))
+            if e.primitive.name == "pallas_call"}
+
+
 @pytest.mark.parametrize("agg", AGGS)
 def test_fused_dequant_bit_exact_vs_decode_then_aggregate(agg):
-    """The kernel's in-VMEM dequant replays quant_decode's exact
-    q_f32 * scale_f32 multiply, so aggregating the wire codes is
-    BIT-IDENTICAL to decoding first and running the dense fused engine
-    at the same block size."""
+    """The int8 loader's in-VMEM dequant replays quant_decode's exact
+    q_f32 * scale_f32 multiply into the same pass bodies as the dense
+    loader, so aggregating the wire codes is BIT-IDENTICAL to decoding
+    first and aggregating the decode."""
     c = 9
     tree = _tree(c)
     mask = jnp.ones((c,)).at[3].set(0.0)
@@ -206,9 +211,14 @@ def test_fused_dequant_bit_exact_vs_decode_then_aggregate(agg):
     codec = codecs.make_codec(cfg)
     enc = codec.encode_tree(tree)
     dec = codec.decode_tree(enc, tree)
-    out = jax.jit(lambda e, ww, m: dq.fused_dequant_aggregate_tree(
-        e, ww, m, cfg, like=tree, blk=128))(enc, w, mask)
-    oracle = fused_aggregate_tree(dec, w, mask, cfg, blk=128)
+
+    def fused(u, e, ww, m):
+        return aggregation.aggregate(u, ww, m, cfg, enc=e)
+
+    assert "dequant_pass1" in _kernels(fused, dec, enc, w, mask)
+    out = jax.jit(fused)(dec, enc, w, mask)
+    oracle = jax.jit(lambda u, ww, m: aggregation.aggregate(u, ww, m, cfg))(
+        dec, w, mask)
     for k in tree:
         np.testing.assert_array_equal(np.asarray(out[k]),
                                       np.asarray(oracle[k]), err_msg=k)
@@ -217,7 +227,7 @@ def test_fused_dequant_bit_exact_vs_decode_then_aggregate(agg):
 @pytest.mark.parametrize("agg", ["trimmed_mean", "median", "krum"])
 @pytest.mark.parametrize("c", [8, 9])                 # even + odd C
 def test_fused_dequant_within_quant_error_of_dense_oracle(agg, c):
-    """Acceptance bound: int8 fused-dequant aggregation within atol 1e-2
+    """Acceptance bound: int8 aggregation from the codes within atol 1e-2
     of the dense fp32 multi-pass XLA oracle on the UNCOMPRESSED tree —
     at realistic update scale (local-lr-sized steps; the rank-based
     aggregators pass single coordinates through, so their error is the
@@ -228,8 +238,8 @@ def test_fused_dequant_within_quant_error_of_dense_oracle(agg, c):
     cfg = FedConfig(n_clients=c, aggregator=agg, compress="int8")
     codec = codecs.make_codec(cfg)
     enc = codec.encode_tree(tree)
-    out = jax.jit(lambda e, ww, m: dq.fused_dequant_aggregate_tree(
-        e, ww, m, cfg, like=tree))(enc, w, mask)
+    out = jax.jit(lambda e, ww, m: aggregation.aggregate(
+        tree, ww, m, cfg, enc=e))(enc, w, mask)
     dense = aggregation.aggregate_ref(tree, w, mask, cfg)
     for k in tree:
         np.testing.assert_allclose(np.asarray(out[k]),
@@ -241,8 +251,7 @@ def test_fused_dequant_within_quant_error_of_dense_oracle(agg, c):
 def test_empty_cohort_times_compression_yields_zero(comp):
     """An all-zero participation mask (a NORMAL slotted-protocol state)
     must produce a ZERO update through every codec path — the decoded
-    tree through the dense engine AND int8 through the fused dequant
-    kernels."""
+    tree through the dense loader AND int8 through the int8 loader."""
     c = 6
     tree = _tree(c)
     w = jnp.ones((c,))
@@ -254,29 +263,31 @@ def test_empty_cohort_times_compression_yields_zero(comp):
     out = aggregation.aggregate(dec, w, zero_mask, cfg)
     assert all(not np.any(np.asarray(l))
                for l in jax.tree_util.tree_leaves(out))
-    if comp == "int8":
-        out = jax.jit(lambda e, ww, m: dq.fused_dequant_aggregate_tree(
-            e, ww, m, cfg, like=tree))(enc, w, zero_mask)
-        assert all(not np.any(np.asarray(l))
-                   for l in jax.tree_util.tree_leaves(out))
+    out = jax.jit(lambda e, ww, m: aggregation.aggregate(
+        dec, ww, m, cfg, enc=e))(enc, w, zero_mask)
+    assert all(not np.any(np.asarray(l))
+               for l in jax.tree_util.tree_leaves(out))
 
 
 def test_unfusable_blk_falls_back_to_decode_path():
-    """An agg_blk that no qblk tiles (e.g. 1000) must route int8 through
-    decode-then-aggregate instead of tripping the kernel's alignment
-    assert — and a full round must still run."""
+    """A quant block that no streaming block tiles (256 against the
+    128-lane block of a leaf narrower than 128) must route int8 through
+    the dense loader on the decode instead of tripping the int8
+    loader's alignment assert — and a full round must still run."""
     from repro.core import fedfits
 
     cfg = FedConfig(n_clients=6, aggregator="trimmed_mean",
-                    compress="int8", agg_blk=1000)
+                    compress="int8", compress_qblk=256)
     codec = codecs.make_codec(cfg)
-    # a leaf WIDER than the pinned blk actually streams at blk=1000,
-    # which no 128-wide quant block tiles (leaves narrower than blk get
-    # their own 128-aligned width and would still fuse)
-    tree = {"w": jax.random.normal(KEY, (6, 4096))}
-    assert not dq.should_fuse(codec, cfg, tree)
-    assert dq.should_fuse(codec, dataclasses.replace(cfg, agg_blk=None),
-                          tree)
+    tree = {"w": jax.random.normal(KEY, (6, 512)),
+            "b": jax.random.normal(jax.random.fold_in(KEY, 1), (6, 22))}
+    enc = codec.encode_tree(tree)
+    dec = codec.decode_tree(enc, tree)
+    assert not rp.fusable([512, 22], 6, 256)
+    assert rp.fusable([512, 22], 6, 128)
+    names = _kernels(lambda u, e, w, m: aggregation.aggregate(
+        u, w, m, cfg, enc=e), dec, enc, jnp.ones((6,)), jnp.ones((6,)))
+    assert names == {"robust_pass1", "robust_pass2"}, names
     model, fed = _sim(6)
     state, _ = fedfits.run(model, cfg, fed.data_fn, 2,
                            jax.random.PRNGKey(7))
@@ -291,16 +302,39 @@ def test_fused_dequant_gate_excises_sign_flipped_clients():
     upd = {"w": honest.at[0].set(-50.0).at[1].set(-50.0)}
     cfg = FedConfig(n_clients=c, aggregator="median", compress="int8")
     enc = codecs.make_codec(cfg).encode_tree(upd)
-    out = jax.jit(lambda e: dq.fused_dequant_aggregate_tree(
-        e, jnp.ones((c,)), jnp.ones((c,)), cfg, like=upd))(enc)
+    out = jax.jit(lambda e: aggregation.aggregate(
+        upd, jnp.ones((c,)), jnp.ones((c,)), cfg, enc=e))(enc)
     assert np.all(np.asarray(out["w"]) > 0.5)
+
+
+@pytest.mark.parametrize("compress,qblk,kernel", [
+    ("int8", 128, "dequant"), ("int8", 256, "robust"),
+    ("none", 128, "robust")])
+def test_round_routes_aggregation_by_wire_format(compress, qblk, kernel):
+    """``make_round`` leaves the choice of loader to
+    ``aggregation.aggregate``: an int8 uplink whose blocks the quant
+    block tiles aggregates from the codes, an untiled one (paper-mlp's
+    22-wide bias at qblk 256) or a dense uplink from the updates."""
+    from repro.core import fedfits
+
+    k = 6
+    model, fed = _sim(k)
+    cfg = FedConfig(n_clients=k, algorithm="fedfits", local_epochs=1,
+                    aggregator="trimmed_mean", compress=compress,
+                    compress_qblk=qblk)
+    state = fedfits.init_state(model.init(KEY), k, cfg, KEY)
+    batch = fed.data_fn(0, KEY)
+    names = _kernels(fedfits.make_round(model, cfg), state, batch)
+    assert f"{kernel}_pass1" in names, names
+    other = "robust" if kernel == "dequant" else "dequant"
+    assert not any(n.startswith(other) for n in names), names
 
 
 # ------------------------------------------------------ sharded dequant --
 @multidevice
 @pytest.mark.parametrize("agg", AGGS)
 def test_sharded_fused_dequant_matches_oracle(agg):
-    """4-device shard_map fused dequant: codes + scales shard together
+    """4-device shard_map int8 loader: codes + scales shard together
     (align=qblk), parity vs decode-then-reference within the shard-local
     summation-order tolerance."""
     from jax.sharding import Mesh
@@ -318,8 +352,13 @@ def test_sharded_fused_dequant_matches_oracle(agg):
     dec = codec.decode_tree(enc, tree)
     mesh = Mesh(np.array(jax.devices()).reshape(jax.device_count()),
                 ("data",))
-    out = jax.jit(lambda e, ww, m: dq.fused_dequant_aggregate_sharded(
-        e, ww, m, cfg, mesh, like=tree, axes=("data",)))(enc, w, mask)
+
+    def fused(u, e, ww, m):
+        return aggregation.aggregate(u, ww, m, cfg, enc=e, mesh=mesh,
+                                     axes=("data",))
+
+    assert "dequant_pass1" in _kernels(fused, dec, enc, w, mask)
+    out = jax.jit(fused)(dec, enc, w, mask)
     ref = aggregation.aggregate_ref(dec, w, mask, cfg)
     for k in tree:
         np.testing.assert_allclose(np.asarray(out[k]), np.asarray(ref[k]),

@@ -1,14 +1,17 @@
 """Per-kernel validation: shape/dtype sweeps, Pallas (interpret) vs the
-pure-jnp ref oracle."""
+pure-jnp ref oracle.  The masked trimmed-mean / median contract runs
+through the fused Eq.-11 pipeline with its cosine gate opened to every
+client (threshold -2 lies below any cosine)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.base import FedConfig
+from repro.core import aggregation
 from repro.kernels.flash_attention_ops import flash_attention
 from repro.kernels.flash_attention_ref import flash_attention_ref
-from repro.kernels.robust_agg_ops import (robust_aggregate_tree,
-                                          robust_aggregate_tree_ref)
+from repro.kernels.robust_pipeline import fused_aggregate_tree
 
 KEY = jax.random.PRNGKey(0)
 
@@ -59,15 +62,31 @@ def test_flash_attention_small_shape_fallback():
     assert out.shape == q.shape and np.isfinite(np.asarray(out)).all()
 
 
+def _robust(tree, mask, mode, trim_frac=0.2):
+    """Ungated masked trimmed mean / median of ``tree`` by the fused
+    pipeline."""
+    cfg = FedConfig(n_clients=mask.shape[0],
+                    aggregator="trimmed_mean" if mode == "trimmed"
+                    else "median",
+                    trim_frac=trim_frac, cosine_outlier_thresh=-2.0)
+    return fused_aggregate_tree(tree, jnp.ones_like(mask), mask, cfg,
+                                blk=128, interpret=True)
+
+
+def _robust_ref(tree, mask, mode, trim_frac=0.2):
+    if mode == "trimmed":
+        return aggregation.trimmed_mean(tree, mask, trim_frac)
+    return aggregation.median(tree, mask)
+
+
 @pytest.mark.parametrize("C", [4, 16, 32])
 @pytest.mark.parametrize("mode", ["trimmed", "median"])
 def test_robust_agg_sweep(C, mode):
     tree = {"a": jax.random.normal(KEY, (C, 13, 7)),
             "b": jax.random.normal(jax.random.fold_in(KEY, 1), (C, 257))}
     mask = jnp.ones((C,)).at[0].set(0.0)
-    out = robust_aggregate_tree(tree, mask, mode=mode, trim_frac=0.2,
-                                interpret=True)
-    ref = robust_aggregate_tree_ref(tree, mask, mode=mode, trim_frac=0.2)
+    out = _robust(tree, mask, mode)
+    ref = _robust_ref(tree, mask, mode)
     for kk in tree:
         np.testing.assert_allclose(np.asarray(out[kk]), np.asarray(ref[kk]),
                                    atol=1e-5)
@@ -78,17 +97,16 @@ def test_robust_agg_defends_poison(mode):
     C = 8
     honest = jnp.ones((C, 64)) + 0.01 * jax.random.normal(KEY, (C, 64))
     poisoned = honest.at[0].set(-1e6)
-    out = robust_aggregate_tree({"w": poisoned}, jnp.ones((C,)), mode=mode,
-                                interpret=True)
+    out = _robust({"w": poisoned}, jnp.ones((C,)), mode)
     assert np.all(np.asarray(out["w"]) > 0.9)
 
 
 def test_robust_agg_dtype_bf16_inputs():
     C = 16
     tree = {"w": jax.random.normal(KEY, (C, 384)).astype(jnp.bfloat16)}
-    out = robust_aggregate_tree(tree, jnp.ones((C,)), mode="median",
-                                interpret=True)
-    ref = robust_aggregate_tree_ref(
-        {"w": tree["w"].astype(jnp.float32)}, jnp.ones((C,)), mode="median")
+    out = _robust(tree, jnp.ones((C,)), "median")
+    assert out["w"].dtype == jnp.bfloat16
+    ref = _robust_ref(
+        {"w": tree["w"].astype(jnp.float32)}, jnp.ones((C,)), "median")
     np.testing.assert_allclose(np.asarray(out["w"], np.float32),
                                np.asarray(ref["w"]), atol=1e-2)

@@ -1,7 +1,7 @@
 """Fused two-pass robust-aggregation pipeline (kernels/robust_pipeline.py)
 vs the multi-pass XLA oracles — leaf-streaming (segment-table) engine,
-the PR-1 flatten baseline, dtype round-trips, and the jaxpr no-copy
-guarantee — plus the scan round-driver equivalence."""
+dtype round-trips, and the jaxpr no-copy guarantee — plus the scan
+round-driver equivalence."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,11 +11,8 @@ from repro.analysis.traversal import all_eqns
 from repro.configs.base import FedConfig
 from repro.core import aggregation
 from repro.kernels.robust_pipeline import (auto_blk, fused_aggregate_tree,
-                                           fused_aggregate_tree_flat,
                                            fused_two_stage_tree,
-                                           fused_two_stage_tree_flat,
-                                           make_segments,
-                                           pairwise_sq_dists_blocked)
+                                           make_segments, pairwise_sq_dists)
 
 KEY = jax.random.PRNGKey(0)
 AGGS = ["fedavg", "median", "trimmed_mean", "krum"]
@@ -80,8 +77,8 @@ def test_two_stage_cohort_batched_matches_ref(agg):
 
 
 def test_two_stage_leafwise_matches_flat():
-    """Cohort-batched leaf-streaming vs the PR-1 flatten path (kept as
-    oracle): same G-grid semantics, no concatenate."""
+    """Cohort-batched leaf-streaming vs the vmapped XLA oracle: the G grid
+    axis carries each cohort's own partials and gate."""
     g, k = 3, 8
     upd = {"w": jax.random.normal(KEY, (g, k, 57)),
            "b": jax.random.normal(jax.random.fold_in(KEY, 3), (g, k, 5, 3))}
@@ -89,7 +86,7 @@ def test_two_stage_leafwise_matches_flat():
     sm = jnp.ones((g, k)).at[1, 2].set(0.0)
     cfg = FedConfig(aggregator="trimmed_mean")
     out = fused_two_stage_tree(upd, sw, sm, cfg, blk=128)
-    ref = fused_two_stage_tree_flat(upd, sw, sm, cfg, blk=128)
+    ref = aggregation.two_stage_ref(upd, sw, sm, cfg)
     _assert_tree_close(out, ref)
 
 
@@ -119,7 +116,7 @@ def _mixed_tree(c, key=KEY):
 
 @pytest.mark.parametrize("agg", AGGS)
 def test_leafwise_matches_flatten_on_mixed_tree(agg):
-    """Leaf-streaming (segment-table) engine vs the PR-1 flatten path on a
+    """Leaf-streaming (segment-table) engine vs the XLA oracle on a
     multi-leaf mixed-dtype/odd-size tree."""
     c = 9
     tree = _mixed_tree(c)
@@ -127,7 +124,7 @@ def test_leafwise_matches_flatten_on_mixed_tree(agg):
     w = jax.random.uniform(jax.random.fold_in(KEY, 5), (c,)) + 0.1
     cfg = FedConfig(n_clients=c, aggregator=agg)
     leaf = fused_aggregate_tree(tree, w, mask, cfg, blk=128)
-    flat = fused_aggregate_tree_flat(tree, w, mask, cfg, blk=128)
+    flat = aggregation.aggregate_ref(tree, w, mask, cfg)
     for k in tree:
         assert leaf[k].dtype == tree[k].dtype
         # half-precision leaves: within one ulp of each other's rounding
@@ -202,12 +199,11 @@ def test_segment_table_and_auto_blk():
 
 
 def test_pairwise_distance_kernel_matches_ref():
-    g, c, n = 2, 9, 300                           # odd C, padded N
+    g, c, n = 2, 9, 300                           # odd C, ragged tail
     x = jax.random.normal(KEY, (g, c, n))
     mask = jnp.ones((g, c)).at[1, 2].set(0.0)
-    pad = (-n) % 128
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-    d = pairwise_sq_dists_blocked(xp, mask, blk=128, interpret=True)
+    d = pairwise_sq_dists([x], mask, blk=128, leaf_scale=jnp.ones((1,)),
+                          interpret=True)
     for gi in range(g):
         ref = aggregation.pairwise_sq_dists(x[gi], mask[gi])
         np.testing.assert_allclose(np.asarray(d[gi]), np.asarray(ref),

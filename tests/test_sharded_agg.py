@@ -1,5 +1,5 @@
-"""Mesh-sharded robust aggregation (aggregation.aggregate_sharded) vs the
-replicated oracles on forced multi-device CPU.
+"""Mesh-sharded robust aggregation (``aggregation.aggregate(..., mesh=)``)
+vs the replicated oracles on forced multi-device CPU.
 
 Run with XLA_FLAGS=--xla_force_host_platform_device_count=4 (the CI
 multi-device job does); on a single-device interpreter these tests skip —
@@ -51,8 +51,8 @@ def test_sharded_matches_ref_all_modes(agg):
     w = jax.random.uniform(jax.random.fold_in(KEY, 4), (c,)) + 0.1
     cfg = FedConfig(n_clients=c, aggregator=agg)
     mesh = _mesh((jax.device_count(),), ("data",))
-    out = aggregation.aggregate_sharded(tree, w, mask, cfg, mesh,
-                                        axes=("data",))
+    out = aggregation.aggregate(tree, w, mask, cfg, mesh=mesh,
+                                axes=("data",))
     ref = aggregation.aggregate_ref(tree, w, mask, cfg)
     for k in ref:
         assert out[k].dtype == tree[k].dtype
@@ -74,7 +74,7 @@ def test_sharded_2d_mesh_and_pod_axis_excluded():
     w = jnp.ones((c,))
     cfg = FedConfig(n_clients=c, aggregator="trimmed_mean")
     mesh = _mesh((2, 2), ("data", "model"))
-    out = aggregation.aggregate_sharded(tree, w, mask, cfg, mesh)
+    out = aggregation.aggregate(tree, w, mask, cfg, mesh=mesh)
     ref = aggregation.aggregate_ref(tree, w, mask, cfg)
     for k in ref:
         atol = 1e-5 if out[k].dtype == jnp.float32 else 2e-2
@@ -92,8 +92,8 @@ def test_sharded_gate_excises_sign_flipped_clients():
     upd = {"w": honest.at[0].set(-50.0).at[1].set(-50.0)}
     cfg = FedConfig(n_clients=c, aggregator="median")
     mesh = _mesh((jax.device_count(),), ("data",))
-    out = aggregation.aggregate_sharded(upd, jnp.ones((c,)), jnp.ones((c,)),
-                                        cfg, mesh, axes=("data",))
+    out = aggregation.aggregate(upd, jnp.ones((c,)), jnp.ones((c,)), cfg,
+                                mesh=mesh, axes=("data",))
     assert np.all(np.asarray(out["w"]) > 0.5)
 
 
@@ -101,7 +101,7 @@ def test_sharded_gate_excises_sign_flipped_clients():
 def test_no_reshard_between_backward_and_shard_map():
     """ROADMAP open item 2: the per-client vmap'd backward's grad outputs
     are constrained to the ``client_flat_specs`` layout, so the
-    ``aggregate_sharded`` shard_map boundary does no reshard.  Guarded at
+    ``aggregate(..., mesh=)`` shard_map boundary does no reshard.  Guarded at
     two levels: (a) in the jaxpr, every tensor operand of the shard_map
     is produced by a ``sharding_constraint`` whose sharding IS the
     boundary's in_spec — GSPMD therefore has nothing to move; (b) the
@@ -135,8 +135,8 @@ def test_no_reshard_between_backward_and_shard_map():
             return g
 
         grads_c = jax.vmap(client_grad)(jnp.arange(C))
-        return aggregation.aggregate_sharded(grads_c, w, team, cfg, mesh,
-                                             axes=("data",))
+        return aggregation.aggregate(grads_c, w, team, cfg, mesh=mesh,
+                                     axes=("data",))
 
     w = jnp.full((C,), 1.0 / C)
     team = jnp.ones((C,))

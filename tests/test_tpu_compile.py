@@ -18,9 +18,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.comm import codecs
-from repro.comm.kernels import comm_codecs as dq
 from repro.configs.base import FedConfig
 from repro.configs.registry import ARCHS, get_config
+from repro.core import aggregation
+from repro.kernels import backend, robust_pipeline
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.paged_decode import paged_flash_decode
 from repro.kernels.population_select import topd_pallas
@@ -80,21 +81,24 @@ def test_fused_aggregation_compiles(one_chip, arch, agg, c):
 
 
 @pytest.mark.parametrize("agg", ["trimmed_mean", "krum"])
-def test_fused_int8_dequant_compiles(one_chip, agg):
+def test_fused_int8_dequant_compiles(one_chip, agg, monkeypatch):
+    """``aggregation.aggregate(..., enc=)`` as the chip builds it: the
+    block size ``auto_blk`` fits to VMEM and compiled (not interpreted)
+    kernels, with the int8 loader chosen."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(robust_pipeline, "on_tpu", lambda: True)
     c = 16
     upd = _update_tree("paper-cnn", c, one_chip)
-    blk = auto_blk(c, _sizes(upd), backend="tpu")
-    cfg = FedConfig(n_clients=c, aggregator=agg, compress="int8",
-                    agg_blk=blk)
+    cfg = FedConfig(n_clients=c, aggregator=agg, compress="int8")
     codec = codecs.make_codec(cfg)
     like = jax.tree_util.tree_map(
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), upd)
-    assert dq.should_fuse(codec, cfg, like)
     enc = jax.tree_util.tree_map(lambda l: _sd(one_chip, l.shape, l.dtype),
                                  jax.eval_shape(codec.encode_tree, like))
-    _compile(lambda e, w, m: dq.fused_dequant_aggregate_tree(
-        e, w, m, cfg, like=like, blk=blk, interpret=False),
-        enc, _sd(one_chip, (c,)), _sd(one_chip, (c,)))
+    text = _compile(lambda u, e, w, m: aggregation.aggregate(
+        u, w, m, cfg, enc=e), upd, enc, _sd(one_chip, (c,)),
+        _sd(one_chip, (c,)))
+    assert "dequant_pass1" in text and "robust_pass1" not in text
 
 
 @pytest.mark.parametrize("int8,slots,maxp,n", [
