@@ -328,7 +328,7 @@ def bench_pod_scan_driver(rounds=8, chunk=4):
     step_jit = jax.jit(step, donate_argnums=(0,))
 
     def run_scan(st):
-        drv.run(st, batch_fn, rounds)
+        drv.run(st, scan_driver.per_step(batch_fn), rounds)
 
     def run_python(st):
         for t in range(rounds):

@@ -12,10 +12,13 @@ drive multi-round training through ONE subsystem:
     per chunk (batch buffers are pure inputs with nothing to alias, so
     they are not donated);
   * chunk batches are double-buffered: while chunk k computes, chunk
-    k+1's batches are built on host and staged with an async
-    ``jax.device_put`` so the host->device transfer overlaps compute;
+    k+1's batches are built and staged (``stage_batches``).  Batches
+    that are already device arrays go to the compiled chunk as a list
+    and are stacked inside it, so staging them dispatches nothing;
+    host-built ones are stacked on host and sent with one async
+    ``jax.device_put`` that overlaps compute;
   * **sharding-aware prefetch**: ``batch_sharding`` (a ``NamedSharding``
-    tree matching ONE batch) makes ``stage_chunk`` put chunk k+1's
+    tree matching ONE batch) makes ``stage_batches`` put chunk k+1's
     stacked batches DIRECTLY onto their pod shards — the stacked
     (chunk, ...) buffers get the same sharding with a leading replicated
     chunk dim (``chunk_sharding``), so a sharded pod step reads its
@@ -60,32 +63,50 @@ def chunk_sharding(batch_sharding):
 
 
 def _stack(*xs):
-    """Stack one leaf across the chunk's batches.  Host-built (numpy)
-    batches stack on HOST so the subsequent sharded ``device_put`` is a
-    single host->shard transfer; device-resident batches stack with
-    ``jnp`` (pulling them back to host would cost a device->host copy)
-    and pay one device->shards redistribution hop instead."""
+    """Stack one leaf across the chunk's batches on the host path.
+    Host-built (numpy) batches stack on HOST so the subsequent
+    ``device_put`` is a single host->device (or host->shard) transfer;
+    device-resident batches headed for a sharding stack with ``jnp``
+    (pulling them back to host would cost a device->host copy) and pay
+    one device->shards redistribution hop instead."""
     if any(isinstance(x, jax.Array) for x in xs):
         return jnp.stack(xs)
     return np.stack(xs)
 
 
-def stage_chunk(batch_fn, ts, batch_sharding=None):
-    """Build the stacked batches for steps ``ts`` and start their
-    host->device transfer (async ``jax.device_put``) — called while the
-    PREVIOUS chunk is still computing, so the upload overlaps compute.
-    With ``batch_sharding`` (the STACKED sharding from
-    ``chunk_sharding``) every batch buffer lands on its pod shards —
-    directly from host memory for host-built batches, via one
-    redistribution hop for already-device-resident ones; otherwise it
-    stages onto the default device."""
-    batches = [dict(batch_fn(t)) for t in ts]
+def per_step(batch_fn):
+    """The chunk feed ``chunk_fn(ts) -> [batch, ...]`` of a per-step
+    ``batch_fn(t)``."""
+    return lambda ts: [batch_fn(t) for t in ts]
+
+
+def stage_batches(batches, batch_sharding=None):
+    """Stage one chunk's batches (one dict per step) for the scan chunk.
+    Returns ``(feed, staged)``:
+
+      * ``("device", batches)`` when every leaf is already a device array
+        and no sharding is asked for: the list goes to the compiled chunk
+        as it is and is stacked there, so staging dispatches nothing;
+      * ``("host", stacked)`` otherwise: the leaves are stacked (on host
+        for numpy, see ``_stack``) and put with one ``jax.device_put`` —
+        onto ``batch_sharding`` (the STACKED sharding from
+        ``chunk_sharding``) when given, else the default device.
+
+    Both give the scan body the same values."""
+    batches = [dict(b) for b in batches]
+    if batch_sharding is None and all(
+            isinstance(x, jax.Array)
+            for x in jax.tree_util.tree_leaves(batches)):
+        return "device", batches
     stacked = jax.tree_util.tree_map(_stack, *batches)
-    if batch_sharding is not None:
-        stacked = jax.device_put(stacked, batch_sharding)
-    else:
-        stacked = jax.device_put(stacked)
-    return jnp.asarray(ts, jnp.int32), stacked
+    return "host", jax.device_put(stacked, batch_sharding)
+
+
+def stage_chunk(batch_fn, ts, batch_sharding=None):
+    """``stage_batches`` over a per-step ``batch_fn(t)``, for steps
+    ``ts``.  Returns ``(ts_dev, staged)``."""
+    _, staged = stage_batches(per_step(batch_fn)(ts), batch_sharding)
+    return jnp.asarray(ts, jnp.int32), staged
 
 
 class ScanDriver:
@@ -101,26 +122,28 @@ class ScanDriver:
         donate_argnums = (0,) if donate else ()
 
         def scan_chunk(st, ts, batches):
+            if isinstance(batches, list):          # the "device" feed
+                batches = jax.tree_util.tree_map(
+                    lambda *x: jnp.stack(x), *batches)
             return jax.lax.scan(body, st, (ts, batches))
 
         self._scan = jax.jit(scan_chunk, donate_argnums=donate_argnums)
 
-    def stage(self, batch_fn, ts):
-        return stage_chunk(batch_fn, ts, self._put_sharding)
-
-    def run(self, state, batch_fn, n_steps, *, t0: int = 0,
+    def run(self, state, chunk_fn, n_steps, *, t0: int = 0,
             index_key: str = "step",
             on_chunk: Optional[Callable[[Any, list], None]] = None,
             telemetry=None):
-        """Drive ``n_steps`` steps starting at ``t0``.  ``batch_fn(t)``
-        is a host callable returning one batch dict.  Returns
+        """Drive ``n_steps`` steps starting at ``t0``.  ``chunk_fn(ts)``
+        is a host callable returning one batch dict per step of ``ts``
+        (``per_step`` builds it from a per-step ``batch_fn(t)``).  Returns
         ``(final_state, history)`` — one row dict per step, each carrying
         its step index under ``index_key``.  ``on_chunk(state, rows)``
         fires after every chunk (logging / checkpoint hook).
 
         Each host phase of a chunk is one ``obs.trace.span``, with the
         chunk's first step as arg ``first``: ``driver.stage`` (the next
-        chunk's ``batch_fn`` calls, stacking and ``device_put``),
+        chunk's ``chunk_fn`` call and ``stage_batches``, with arg ``feed``
+        naming the path it took: ``"device"`` or ``"host"``),
         ``driver.dispatch`` (the scan call), ``driver.drain`` (the one
         ``device_get`` per chunk and the rows) and ``driver.hooks``
         (``telemetry.observe_rows`` and ``on_chunk``).  ``telemetry``
@@ -134,9 +157,12 @@ class ScanDriver:
             return list(range(s0, min(s0 + self.chunk_steps, end)))
 
         def stage(s0):
-            with span("driver.stage", rec, first=s0):
+            with span("driver.stage", rec, first=s0) as sp:
                 ts = steps_of(s0)
-                return (ts, *self.stage(batch_fn, ts))
+                feed, staged = stage_batches(chunk_fn(ts),
+                                             self._put_sharding)
+                sp.set(feed=feed)
+                return ts, jnp.asarray(ts, jnp.int32), staged
 
         history = []
         pending = stage(t0) if n_steps >= 1 else None
@@ -173,5 +199,6 @@ def run_chunked(body, state, batch_fn, n_steps, *, chunk_steps=8, t0=0,
     """One-shot convenience wrapper: build a ``ScanDriver`` and run it."""
     drv = ScanDriver(body, chunk_steps=chunk_steps,
                      batch_sharding=batch_sharding, donate=donate)
-    return drv.run(state, batch_fn, n_steps, t0=t0, index_key=index_key,
-                   on_chunk=on_chunk, telemetry=telemetry)
+    return drv.run(state, per_step(batch_fn), n_steps, t0=t0,
+                   index_key=index_key, on_chunk=on_chunk,
+                   telemetry=telemetry)

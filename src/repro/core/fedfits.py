@@ -26,6 +26,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.comm import codecs as comm_codecs, error_feedback
 from repro.core import aggregation, attacks, clientstore, \
@@ -457,6 +458,14 @@ def make_round(model, fed_cfg, *, data_attack=None, update_attack=None,
     return round_fn
 
 
+@jax.jit
+def round_keys(rng, ts):
+    """``jax.random.fold_in(rng, t)`` for every step ``t`` of a chunk, as
+    a tuple of keys: one program for the chunk's keys in place of one
+    ``fold_in`` per round, bit-identical to them."""
+    return tuple(jax.vmap(jax.random.fold_in, (None, 0))(rng, ts))
+
+
 def scoped_eval(eval_fn):
     """``eval_fn`` with its ops under the device scope ``server_eval``
     (None stays None)."""
@@ -481,10 +490,12 @@ def run(model, fed_cfg, data_fn, n_rounds, rng, *, eval_fn=None,
     driver (core/driver.py — donated carry, on-device metric history,
     double-buffered batch staging; the pod engine drives multi-round
     training through the same subsystem).  data_fn stays a host
-    callable; availability sampling moves inside the scan body (same
-    fold_in streams), so the history is bit-for-bit identical to
-    driver="python", the original per-round jit loop kept for parity
-    testing."""
+    callable, handed the same ``fold_in(rng, t)`` keys, derived for a
+    whole chunk in one program (``round_keys``); device-array batches
+    are stacked inside the compiled chunk.  Availability sampling moves
+    inside the scan body (same fold_in streams), so the history is
+    bit-for-bit identical to driver="python", the original per-round jit
+    loop kept for parity testing."""
     r_init, r_run = jax.random.split(rng)
     params = model.init(r_init)
     eval_fn = scoped_eval(eval_fn)
@@ -546,7 +557,10 @@ def run(model, fed_cfg, data_fn, n_rounds, rng, *, eval_fn=None,
             metrics = {**metrics, **eval_fn(st.params)}
         return st, metrics
 
-    return scan_driver.run_chunked(
-        body, state, lambda t: data_fn(t, jax.random.fold_in(rng, t)),
-        n_rounds, chunk_steps=chunk_rounds, t0=1, index_key="round",
-        telemetry=telemetry)
+    def feed(ts):
+        keys = round_keys(rng, np.asarray(ts, np.uint32))
+        return [data_fn(t, k) for t, k in zip(ts, keys)]
+
+    drv = scan_driver.ScanDriver(body, chunk_steps=chunk_rounds)
+    return drv.run(state, feed, n_rounds, t0=1, index_key="round",
+                   telemetry=telemetry)

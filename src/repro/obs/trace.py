@@ -82,6 +82,12 @@ class span:
             self._t0 = now_us()
         return self
 
+    def set(self, **args):
+        """Add args known only once the span is open (the driver's
+        ``feed``); they land on the profile event and the record."""
+        self._ann.set_metadata(**args)
+        self._args.update(args)
+
     def __exit__(self, *exc):
         if self._rec is not None:
             t1 = now_us()

@@ -133,3 +133,123 @@ def test_stage_chunk_places_batches_on_sharding():
     assert stacked["x"].shape == (3, 2, 3)
     assert stacked["x"].sharding == lifted["x"]
     np.testing.assert_array_equal(np.asarray(ts_dev), [0, 1, 2])
+
+
+class _Spans:
+    """The duck-typed telemetry the driver calls, keeping its spans."""
+
+    def __init__(self):
+        from repro.obs import TraceRecorder
+        self.tracer = TraceRecorder("sync")
+
+    def now_us(self):
+        return 0.0
+
+    def observe_rows(self, rows, w0, dur):
+        pass
+
+    def feeds(self):
+        return [e["args"]["feed"] for e in self.tracer.events
+                if e["name"] == "driver.stage"]
+
+
+def _float_body(st, xs):
+    t, batch = xs
+    h = jnp.tanh(st["w"] @ batch["x"].T + batch["y"].astype(jnp.float32))
+    st = {"w": st["w"] * 0.9 + 0.1 * h.mean(-1, keepdims=True),
+          "acc": st["acc"] + h.sum() * batch["s"]}
+    return st, {"h": h, "t": t}
+
+
+def _float_batches(n):
+    rng = np.random.default_rng(0)
+    return [{"x": rng.standard_normal((5, 3)).astype(np.float32),
+             "y": rng.integers(0, 4, (5,)).astype(np.int32),
+             "s": np.float32(rng.standard_normal())} for _ in range(n)]
+
+
+@pytest.mark.parametrize("feed", ["device", "host"])
+def test_run_chunked_device_and_host_feeds_bitwise(feed):
+    """Device-array batches go to the chunk unstacked and are stacked
+    inside it; numpy batches are stacked on host.  Either feed, with a
+    ragged tail chunk, gives the per-step jitted loop's history and final
+    state bit for bit, and ``driver.stage`` names the feed it took."""
+    n = 7
+    host = _float_batches(n)
+    batches = (host if feed == "host" else
+               [jax.tree_util.tree_map(jnp.asarray, b) for b in host])
+
+    def state0():
+        return {"w": jnp.linspace(-1.0, 1.0, 12, dtype=jnp.float32
+                                  ).reshape(4, 3),
+                "acc": jnp.float32(0.0)}
+
+    step = jax.jit(_float_body)
+    st_ref, h_ref = state0(), []
+    for t in range(n):
+        st_ref, m = step(st_ref, (jnp.int32(t), batches[t]))
+        h_ref.append({**jax.device_get(m), "step": t})
+
+    tele = _Spans()
+    st, hist = driver.run_chunked(_float_body, state0(),
+                                  lambda t: batches[t], n, chunk_steps=3,
+                                  telemetry=tele)
+    assert tele.feeds() == [feed] * 3                # 3 + 3 + ragged 1
+    _assert_history_equal(h_ref, hist)
+    for a, b in zip(jax.tree_util.tree_leaves(st_ref),
+                    jax.tree_util.tree_leaves(st)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_device_feed_stacks_only_inside_the_chunk(monkeypatch):
+    """The device feed issues no host-side ``jnp.stack``: every stack of a
+    run over device-array batches happens while the chunk is traced."""
+    real = jnp.stack
+
+    def traced_only(xs, *a, **k):
+        if not any(isinstance(x, jax.core.Tracer) for x in xs):
+            raise AssertionError("jnp.stack outside the compiled chunk")
+        return real(xs, *a, **k)
+
+    monkeypatch.setattr(driver.jnp, "stack", traced_only)
+    batches = [jax.tree_util.tree_map(jnp.asarray, b)
+               for b in _float_batches(5)]
+    tele = _Spans()
+    _, hist = driver.run_chunked(
+        _float_body, {"w": jnp.ones((4, 3)), "acc": jnp.float32(0.0)},
+        lambda t: batches[t], 5, chunk_steps=2, telemetry=tele)
+    assert tele.feeds() == ["device"] * 3
+    assert [r["step"] for r in hist] == list(range(5))
+
+
+def test_fedfits_chunk_keys_are_fold_in_of_each_round():
+    """``fedfits.run`` hands ``data_fn`` the key ``fold_in(rng, t)`` of
+    every round, bit for bit, though it derives a chunk's keys in one
+    program — the tail chunk's too."""
+    from repro.configs.base import FedConfig
+    from repro.core import fedfits
+    from repro.data.pipeline import build_federation
+    from repro.models.model import build
+
+    model = build(ARCHS["paper-mlp"])
+    fed, _ = build_federation(6, kind="tabular", n=240, n_clients=6,
+                              batch_size=8, n_classes=10)
+    cfg = FedConfig(n_clients=6, algorithm="fedfits", local_epochs=1,
+                    local_lr=0.05)
+    rng = jax.random.PRNGKey(2 ** 31 + 11)
+    seen = []
+
+    def data_fn(t, key):
+        seen.append((t, np.asarray(key)))
+        return fed.data_fn(t, key)
+
+    fedfits.run(model, cfg, data_fn, 5, rng, driver="scan", chunk_rounds=2)
+    assert [t for t, _ in seen] == [1, 2, 3, 4, 5]
+    for t, key in seen:
+        np.testing.assert_array_equal(
+            key, np.asarray(jax.random.fold_in(rng, t)), err_msg=str(t))
+    keys = fedfits.round_keys(rng, np.asarray([6, 7, 2 ** 31 + 3],
+                                              np.uint32))
+    for t, key in zip([6, 7, 2 ** 31 + 3], keys):
+        np.testing.assert_array_equal(
+            np.asarray(key), np.asarray(jax.random.fold_in(rng, t)))

@@ -131,6 +131,9 @@ def test_profiler_trace_holds_driver_spans(tmp_path):
         firsts = sorted(int(st["first"]) for n, _, _, st in evs
                         if n == name)
         assert firsts == [1, 3], (name, firsts)
+    # the federation's sampler returns device arrays: the chunk stacks them
+    assert [st["feed"] for n, _, _, st in evs
+            if n == "driver.stage"] == ["device", "device"]
     # the phases of one chunk follow one another on the host
     dispatch = min(s for n, s, _, _ in evs if n == "driver.dispatch")
     drain = min(s for n, s, _, _ in evs if n == "driver.drain")
